@@ -3,10 +3,15 @@
 Driver config #4 (BASELINE.json): GPT-2 345M under the fleet engine
 (bf16 compute, Adam; single chip fits the model+activations in HBM so
 rematerialization is OFF for the headline number — it trades ~25%
-throughput and is only needed at scale). Runs on whatever
-jax.default_backend() is — one real TPU chip under the driver; falls
-back to a tiny config (with remat, exercising that path) on CPU so the
-script stays runnable anywhere.
+throughput and is only needed at scale). Needs a TPU and fails without
+one; ``--smoke`` runs a tiny configuration (with remat, exercising that
+path) on whatever backend there is and names its output as a smoke run
+on that backend, never as a per-chip number.
+
+``gpt2_345m_config`` / ``build_model`` / ``build_trainer`` /
+``token_batch`` are the one place the benchmarked program is built:
+``chip_smoke.py`` builds its phases from them too, so the smoke and the
+benchmark run the same program.
 
 Baseline: the reference publishes no absolute numbers (BASELINE.md), so
 vs_baseline is measured against the driver's north star — 90% of an
@@ -17,7 +22,7 @@ tokens/sec/chip, 90% of which is 61k.
 from __future__ import annotations
 
 import json
-import os
+import sys
 import time
 
 # the manual LayerNorm VJP (+2.2% on this workload, -24% on BERT-base) is
@@ -30,55 +35,95 @@ import numpy as np
 BASELINE_TOKENS_PER_SEC = 61_000.0
 
 
-def main():
+def require_tpu(who: str) -> None:
+    """A measurement path that finds no chip fails; it never falls back
+    to the CPU under a device metric's name."""
+    if jax.default_backend() != "tpu" or jax.devices()[0].platform != "tpu":
+        raise SystemExit(
+            f"{who}: needs a TPU, found backend {jax.default_backend()!r} "
+            f"({jax.devices()[0].device_kind})")
+
+
+def gpt2_345m_config():
+    from paddle_tpu.text.models.gpt import GPTConfig
+
+    return GPTConfig(hidden_size=1024, num_layers=24, num_heads=16,
+                     max_position_embeddings=1024, hidden_dropout=0.0,
+                     attention_dropout=0.0)
+
+
+def gpt2_tiny_config():
+    from paddle_tpu.text.models.gpt import GPTConfig
+
+    return GPTConfig(vocab_size=1024, hidden_size=128, num_layers=4,
+                     num_heads=4, max_position_embeddings=256,
+                     hidden_dropout=0.0, attention_dropout=0.0,
+                     use_flash_attention=False)
+
+
+def build_model(config, seed=0):
     import paddle_tpu as paddle
-    from jax.sharding import Mesh
+    from paddle_tpu.text.models.gpt import GPTForCausalLM
+
+    paddle.seed(seed)
+    return GPTForCausalLM(config)
+
+
+def build_trainer(config, mesh, zero_stage=0, recompute=False, seed=0):
+    """The benchmarked trainer: ``GPTForCausalLM`` under
+    ``fleet.ParallelTrainStep`` on ``mesh``, bf16 compute with f32 master
+    weights. Call the result as ``step((ids, labels), (labels,))``."""
+    import paddle_tpu as paddle
     from paddle_tpu.distributed.fleet.engine import ParallelTrainStep
-    from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu:
-        config = GPTConfig(hidden_size=1024, num_layers=24, num_heads=16,
-                           max_position_embeddings=1024, hidden_dropout=0.0,
-                           attention_dropout=0.0)
-        # batch 8 fills the MXU; 345M + activations fit HBM without remat
-        # (recompute trades ~25% throughput and is off for the headline run)
-        # 45-step windows: window-edge clock jitter amortizes over more
-        # steps (30-step windows measured a ±0.6% run-to-run spread)
-        batch, seq, iters, reps = 8, 1024, 45, 3
-    else:  # smoke mode off-TPU
-        config = GPTConfig(vocab_size=1024, hidden_size=128, num_layers=4,
-                           num_heads=4, max_position_embeddings=256,
-                           hidden_dropout=0.0, attention_dropout=0.0,
-                           use_flash_attention=False)
-        batch, seq, iters, reps = 4, 128, 3, 1
-
-    paddle.seed(0)
-    model = GPTForCausalLM(config)
+    model = build_model(config, seed)
     # multi_precision (reference AMP-O2 semantics): bf16 resident params
     # + f32 master in optimizer state — kills the per-step f32->bf16 cast
     # pass and halves grad/param traffic outside the Adam update
     opt = paddle.optimizer.Adam(learning_rate=1e-4,
                                 parameters=model.parameters(),
                                 multi_precision=True)
-    mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
     # labels ride as a forward input so GPTForCausalLM computes the loss
-    # inside forward and honors GPTConfig.fused_head_ce (default False —
-    # the split path measured faster on this rig; see r5_gpt.txt). The
-    # forward returns the scalar loss directly, so loss_fn is identity.
-    step = ParallelTrainStep(
+    # inside forward and honors GPTConfig.fused_head_ce (default False).
+    # The forward returns the scalar loss directly, so loss_fn is identity.
+    return ParallelTrainStep(
         model, loss_fn=lambda out, lbl: out, optimizer=opt, mesh=mesh,
-        recompute=not on_tpu, compute_dtype=jnp.bfloat16,
-    )
+        zero_stage=zero_stage, recompute=recompute,
+        compute_dtype=jnp.bfloat16)
 
-    rng = np.random.RandomState(0)
+
+def token_batch(config, batch, seq, seed=0):
+    """One fixed (ids, labels) batch of synthetic token ids from a seed,
+    device-resident: numpy feeds would re-cross the host↔device link every
+    step and measure the link, not the chip (real input pipelines overlap
+    H2D via the double-buffered DataLoader)."""
+    import paddle_tpu as paddle
+
+    rng = np.random.RandomState(seed)
     ids = rng.randint(0, config.vocab_size, (batch, seq)).astype(np.int32)
     labels = np.roll(ids, -1, axis=1).astype(np.int32)
-    # device-resident feeds: numpy feeds would re-cross the host↔device
-    # link every step and measure the link, not the chip (real input
-    # pipelines overlap H2D via the double-buffered DataLoader)
-    ids = paddle.to_tensor(ids)
-    labels = paddle.to_tensor(labels)
+    return paddle.to_tensor(ids), paddle.to_tensor(labels)
+
+
+def main():
+    from jax.sharding import Mesh
+
+    smoke = "--smoke" in sys.argv
+    if smoke:
+        config = gpt2_tiny_config()
+        batch, seq, iters, reps = 4, 128, 3, 1
+    else:
+        require_tpu("bench.py")
+        config = gpt2_345m_config()
+        # batch 8 fills the MXU; 345M + activations fit HBM without remat
+        # (recompute trades ~25% throughput and is off for the headline run)
+        # 45-step windows: window-edge clock jitter amortizes over more
+        # steps (30-step windows measured a ±0.6% run-to-run spread)
+        batch, seq, iters, reps = 8, 1024, 45, 3
+
+    mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
+    step = build_trainer(config, mesh, recompute=smoke)
+    ids, labels = token_batch(config, batch, seq)
 
     loss = step((ids, labels), (labels,))  # compile + warmup
     float(loss.numpy())
@@ -93,13 +138,18 @@ def main():
         dt = time.perf_counter() - t0
         rates.append(batch * seq * iters / dt)
     tokens_per_sec = sorted(rates)[len(rates) // 2]
-    print(json.dumps({
-        "metric": "gpt2_345m_train_tokens_per_sec_per_chip" if on_tpu
-        else "gpt2_tiny_train_tokens_per_sec_cpu_smoke",
-        "value": round(tokens_per_sec, 2),
-        "unit": "tokens/sec/chip",
-        "vs_baseline": round(tokens_per_sec / BASELINE_TOKENS_PER_SEC, 4),
-    }))
+    dev = jax.devices()[0]
+    out = {"value": round(tokens_per_sec, 2),
+           "platform": dev.platform, "device_kind": dev.device_kind}
+    if smoke:
+        out = {"metric": f"gpt2_tiny_train_tokens_per_sec_smoke_{dev.platform}",
+               "unit": "tokens/sec", **out}
+    else:
+        out = {"metric": "gpt2_345m_train_tokens_per_sec_per_chip",
+               "unit": "tokens/sec/chip", **out,
+               "vs_baseline": round(
+                   tokens_per_sec / BASELINE_TOKENS_PER_SEC, 4)}
+    print(json.dumps(out))
 
 
 if __name__ == "__main__":

@@ -66,9 +66,8 @@ def make_finding(rule, ctx: AnalysisContext, instr: Optional[HloInstr],
         line = instr.line
         if context is None:
             context = instr.stem
-        src = instr.source_src()
-        if src != "?":
-            message = f"{message} [{src}]"
+        if instr.src != "?":
+            message = f"{message} [{instr.src}]"
     return HloFinding(
         rule=rule.id, severity=rule.severity, path=ctx.entry, line=line,
         col=0, message=message, hint=rule.hint,

@@ -26,7 +26,7 @@ __all__ = [
     "HloInstr", "HloComputation", "HloModule",
     "iter_instruction_lines", "opcode_of", "opcode_and_type",
     "parse_shapes", "shape_bytes", "parse_group_sets", "parse_pairs",
-    "parse_module",
+    "parse_stack_frames", "source_of", "parse_module",
 ]
 
 # every opcode the collective inventory claims (async halves map to
@@ -71,6 +71,9 @@ _CUSTOM_TARGET_RE = re.compile(r'custom_call_target="([^"]+)"')
 _META_BODY_RE = re.compile(r"metadata=\{([^}]*)\}")
 _SRC_FILE_RE = re.compile(r'source_file="([^"]+)"')
 _SRC_LINE_RE = re.compile(r"source_line=(\d+)")
+_FRAME_ID_RE = re.compile(r"stack_frame_id=(\d+)")
+_TABLE_ROW_RE = re.compile(r"^(\d+)\s+(.*)$")
+_TABLE_NAMES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
 _OP_NAME_RE = re.compile(r'op_name="([^"]+)"')
 _DIMS_ATTR_RE = re.compile(r"\b(\w+_dims|dimensions)=\{([\d,\s]*)\}")
 _COMP_HEADER_RE = re.compile(
@@ -194,6 +197,72 @@ def iter_instruction_lines(text: str) -> Iterator[Tuple[str, str, int]]:
             yield m.group(1), m.group(2), lineno
 
 
+# -- source lines -------------------------------------------------------------
+# XLA writes an op's origin in one of two forms. Older modules (and this
+# repo's recorded fixtures) carry ``source_file="..." source_line=N`` inside
+# each op's ``metadata={...}``. The installed jaxlib writes only
+# ``stack_frame_id=N`` there, and once per module, above the computations,
+# the tables that id points into:
+#
+#     FileNames       1 "/src/model.py"
+#     FileLocations   1 {file_name_id=1 function_name_id=2 line=10 ...}
+#     StackFrames     1 {file_location_id=1 parent_frame_id=1}
+#
+# The frame an op names is its innermost one, so frame -> location ->
+# (file, line) is the op's source line.
+
+def parse_stack_frames(text: str) -> Dict[int, str]:
+    """``{stack_frame_id: "file.py:line"}`` from a module's header tables
+    (empty for a module in the inline form)."""
+    tables: Dict[str, Dict[int, str]] = {n: {} for n in _TABLE_NAMES}
+    current = None
+    for raw in text.splitlines():
+        line = raw.strip()
+        if line in tables:
+            current = tables[line]
+            continue
+        m = _TABLE_ROW_RE.match(line) if current is not None else None
+        if m:
+            current[int(m.group(1))] = m.group(2)
+        elif line:
+            if current is tables["StackFrames"]:
+                break  # past the header: the computations follow
+            current = None
+    frames: Dict[int, str] = {}
+    for fid, row in tables["StackFrames"].items():
+        loc = tables["FileLocations"].get(_row_int(row, "file_location_id"))
+        if loc is None:
+            continue
+        name = tables["FileNames"].get(_row_int(loc, "file_name_id"), '"?"')
+        frames[fid] = (name.strip('"').split("/")[-1] + ":"
+                       + str(_row_int(loc, "line")))
+    return frames
+
+
+def _row_int(row: str, key: str) -> int:
+    m = re.search(rf"\b{key}=(\d+)", row)
+    return int(m.group(1)) if m else 0
+
+
+def source_of(body: str, frames: Dict[int, str]) -> str:
+    """``file.py:123`` (basename) of one instruction body, or "?":
+    through ``frames`` (``parse_stack_frames``) when the metadata names a
+    stack frame, else from inline ``source_file``/``source_line``."""
+    mm = _META_BODY_RE.search(body)
+    if not mm:
+        return "?"
+    md = mm.group(1)
+    fid = _FRAME_ID_RE.search(md)
+    if fid and frames:
+        return frames.get(int(fid.group(1)), "?")
+    f = _SRC_FILE_RE.search(md)
+    ln = _SRC_LINE_RE.search(md)
+    if not f and not ln:
+        return "?"
+    return ((f.group(1).split("/")[-1] if f else "?")
+            + ":" + (ln.group(1) if ln else "?"))
+
+
 # -- the structured view ------------------------------------------------------
 
 @dataclasses.dataclass
@@ -210,6 +279,7 @@ class HloInstr:
     computation: str
     operands: Tuple[str, ...] = ()
     is_root: bool = False
+    src: str = "?"              # "file.py:123" (basename) of the op's origin
 
     @property
     def stem(self) -> str:
@@ -254,19 +324,6 @@ class HloInstr:
 
     def replica_groups(self) -> Optional[List[Tuple[int, ...]]]:
         return parse_group_sets(self.body)
-
-    def source_src(self) -> str:
-        """``file.py:123`` (basename) from the metadata, or "?"."""
-        mm = _META_BODY_RE.search(self.body)
-        if not mm:
-            return "?"
-        md = mm.group(1)
-        f = _SRC_FILE_RE.search(md)
-        ln = _SRC_LINE_RE.search(md)
-        if not f and not ln:
-            return "?"
-        return ((f.group(1).split("/")[-1] if f else "?")
-                + ":" + (ln.group(1) if ln else "?"))
 
     def op_name(self) -> str:
         mm = _META_BODY_RE.search(self.body)
@@ -409,6 +466,7 @@ def parse_module(text: str) -> HloModule:
     comps: Dict[str, HloComputation] = {}
     entry: Optional[str] = None
     current: Optional[HloComputation] = None
+    frames = parse_stack_frames(text)
     for lineno, raw in enumerate(text.splitlines(), 1):
         line = raw.strip()
         if not line:
@@ -440,7 +498,8 @@ def parse_module(text: str) -> HloModule:
                 name=name, opcode=opcode, type_text=type_text, body=body,
                 line=lineno, computation=current.name,
                 operands=_operands_of(body, opcode),
-                is_root=line.startswith("ROOT ")))
+                is_root=line.startswith("ROOT "),
+                src=source_of(body, frames)))
     if entry is None and comps:
         # single-computation dumps without an ENTRY keyword: the last
         # computation is the entry by XLA's printing convention

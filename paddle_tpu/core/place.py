@@ -41,7 +41,12 @@ class Place:
     def jax_device(self) -> jax.Device:
         devs = [d for d in jax.devices() if _kind_of(d) == self._kind]
         if not devs:
-            # graceful fallback: CPU host devices always exist
+            if self._kind == "tpu" and _has_tpu():
+                raise RuntimeError(
+                    f"{self!r}: a TPU backend is present but is not the "
+                    f"default backend ({jax.default_backend()!r})")
+            # parity behaviour for the CPU test mesh: code written against
+            # an accelerator place runs on the host when NO TPU exists
             devs = jax.devices("cpu")
         return devs[min(self._device_id, len(devs) - 1)]
 
@@ -93,8 +98,13 @@ _current_device: str | None = None
 def _has_tpu() -> bool:
     try:
         return len(jax.devices("tpu")) > 0
-    except RuntimeError:
-        return False
+    except RuntimeError as e:
+        # "Unknown backend" = this process has no TPU platform at all (the
+        # CPU test mesh). Anything else is a TPU that is present and failed
+        # to initialise: that must stop the program, not turn into "cpu"
+        if "Unknown backend" in str(e):
+            return False
+        raise
 
 
 def is_compiled_with_tpu() -> bool:  # parity with is_compiled_with_cuda

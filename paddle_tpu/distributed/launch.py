@@ -369,6 +369,14 @@ def launch(training_script: str, script_args: List[str],
     from paddle_tpu.profiler.telemetry import get_telemetry
     from paddle_tpu.resilience.retry import backoff_delays
 
+    if backend == "tpu" and nproc_per_node > 1:
+        # a chip belongs to one process, and nothing here binds a rank to
+        # its own chip: every local rank would open all of them and the
+        # second one hangs. One process drives all chips of a host.
+        raise ValueError(
+            f"--backend tpu with --nproc_per_node {nproc_per_node}: several "
+            "processes on one host would each open every chip; run one "
+            "process per host over a multi-chip mesh")
     ip_list = [s.strip() for s in ips.split(",") if s.strip()]
     node_ip = node_ip or ip_list[0]
     envs, _ = get_cluster_env(node_ip, ip_list, nproc_per_node, base_port)

@@ -24,7 +24,8 @@ and request-level fault injection (``slow_req@`` / ``drop_req@`` /
 """
 from .admission import AdmissionQueue
 from .decode import (DecodeScheduler, GenRequest, TokenServeConfig,
-                     TokenServingEngine, dense_greedy_reference)
+                     TokenServingEngine, dense_greedy_reference,
+                     paged_prefill_logits)
 from .engine import ServeConfig, ServingEngine
 from .kv_cache import KVCacheConfig, KVCachePool
 from .loadgen import (run_generation_streams, run_load, run_streams,
@@ -36,7 +37,7 @@ __all__ = [
     "AdmissionQueue", "BatchScheduler", "DecodeScheduler", "GenRequest",
     "KVCacheConfig", "KVCachePool", "Request", "RequestStatus",
     "ServeConfig", "ServingEngine", "TokenServeConfig",
-    "TokenServingEngine", "dense_greedy_reference",
+    "TokenServingEngine", "dense_greedy_reference", "paged_prefill_logits",
     "run_generation_streams", "run_load", "run_streams", "summarize",
     "summarize_generation",
 ]

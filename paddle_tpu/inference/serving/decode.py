@@ -62,7 +62,8 @@ from .kv_cache import KVCacheConfig, KVCachePool
 from .request import Request, RequestStatus
 
 __all__ = ["TokenServeConfig", "GenRequest", "TokenServingEngine",
-           "DecodeScheduler", "dense_greedy_reference"]
+           "DecodeScheduler", "dense_greedy_reference",
+           "paged_prefill_logits"]
 
 
 class TokenServeConfig(ServeConfig):
@@ -781,6 +782,45 @@ def dense_greedy_reference(model, prompt: Sequence[int], max_new: int,
         if eos_id is not None and t == eos_id:
             break
     return out
+
+
+def paged_prefill_logits(model, prompt: Sequence[int], chunk: int,
+                         block_size: int = 16,
+                         kv_dtype: str = "float32") -> np.ndarray:
+    """Logits ``[len(prompt), vocab]`` of one prompt pushed through the
+    paged path the engine serves with — ``gpt_decode_fns`` over a
+    ``KVCachePool``, ``chunk`` tokens at a time — outside any scheduler.
+    The twin of ``dense_greedy_reference``: parity gates compare this
+    against the eval-mode Layer forward (the paged cache is an
+    optimization, never a numerics fork)."""
+    import jax
+
+    from ...jit.functionalize import get_params
+    from ...text.models.gpt import gpt_decode_fns
+
+    mcfg = model.config
+    prompt = np.asarray(prompt, np.int32)
+    n = len(prompt)
+    width = -(-n // block_size)
+    pool = KVCachePool(KVCacheConfig(
+        mcfg.num_layers, mcfg.num_heads, mcfg.hidden_size // mcfg.num_heads,
+        num_blocks=width + 1, block_size=block_size, dtype=kv_dtype))
+    pool.ensure(0, n)
+    table = jnp.asarray(pool.block_table(0, width)[None])
+    fwd = jax.jit(gpt_decode_fns(mcfg, kv_dtype))  # chunks share one compile
+    params = get_params(model)
+    pages = pool.pages
+    rows = []
+    for c0 in range(0, n, chunk):
+        real = min(chunk, n - c0)
+        toks = np.zeros((1, chunk), np.int32)
+        toks[0, :real] = prompt[c0:c0 + real]
+        qpos = (c0 + np.arange(chunk, dtype=np.int32))[None]
+        lens = np.asarray([c0 + real], np.int32)
+        logits, pages = fwd(params, jnp.asarray(toks), jnp.asarray(qpos),
+                            pages, table, jnp.asarray(lens))
+        rows.append(np.asarray(logits)[0, :real])
+    return np.concatenate(rows, axis=0)
 
 
 class TokenServingEngine(ServingEngine):

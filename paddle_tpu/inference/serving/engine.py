@@ -136,14 +136,11 @@ class ServingEngine:
     def start(self, warmup: bool = True) -> "ServingEngine":
         """Arm the scheduler; with ``warmup`` (default) every bucket's
         executable is compiled before the first request is accepted —
-        with ``PADDLE_TPU_COMPILE_CACHE_DIR`` set these come out of the
-        persistent XLA cache, so a relaunched replica is serving-warm in
-        milliseconds instead of a compile storm under live traffic."""
+        on a relaunch these come out of the persistent XLA cache
+        (``device.configure_compilation_cache``), so a relaunched replica
+        is serving-warm without a compile storm under live traffic."""
         if self._started:
             return self
-        from ...device import configure_compilation_cache
-
-        configure_compilation_cache()  # env-gated no-op when unset
         if self._tel.enabled:
             self._tel.gauge("serve/queue_capacity", self.config.capacity)
             self._tel.gauge("serve/draining", 0)

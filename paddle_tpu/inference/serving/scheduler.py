@@ -85,9 +85,8 @@ class BatchScheduler:
 
     def warmup(self) -> Dict[int, float]:
         """Compile every bucket's executable up front with zero batches
-        (cold-start cost paid before the first real request; with
-        ``PADDLE_TPU_COMPILE_CACHE_DIR`` set, a restarted server replays
-        these from the persistent cache in milliseconds). Returns
+        (cold-start cost paid before the first real request; a restarted
+        server replays these from the persistent compile cache). Returns
         ``{bucket: wall_ms}`` of the compiling call — the engine's load
         calibration reads the LAST (largest, fully warm) entry."""
         out: Dict[int, float] = {}

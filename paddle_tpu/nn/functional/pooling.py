@@ -98,8 +98,8 @@ def _manual_maxpool(window, strides, pads):
     default OFF (opt in via PADDLE_TPU_MANUAL_MAXPOOL=1).
 
     Motivation: XLA differentiates ``reduce_window(max)`` into
-    select-and-scatter — 1.43 ms/step of the ResNet-50 profile
-    (tools/profiles/r4_resnet.txt). This rule instead routes gradients by
+    select-and-scatter — 1.43 ms/step of the round-4 ResNet-50
+    profile. This rule instead routes gradients by
     VALUE EQUALITY: eq_u = (view_u == y) over the prod(window) strided
     views, dx accumulated either by dilated-pad scatter-back or by
     gathering the dilated y/scale grids. Ties split the gradient evenly

@@ -41,12 +41,8 @@ __all__ = [
 # bench, r5) — then the repo's flash_tpu Mosaic kernel for longer causal
 # sequences (the materialized scores exhaust HBM and blockwise is 8-10x
 # slower). 'pallas' (the jax-shipped kernel) and 'flash_tpu' can
-# also be forced explicitly. Rigs whose Mosaic compile service fails —
-# plain XLA needs no such service — would die at jit-compile time on
-# auto's long-sequence route: set PADDLE_TPU_ATTN_NO_MOSAIC=1 to keep
-# auto on the streaming blockwise path instead.
+# also be forced explicitly.
 _IMPL = os.environ.get("PADDLE_TPU_ATTENTION", "auto")
-_NO_MOSAIC = os.environ.get("PADDLE_TPU_ATTN_NO_MOSAIC", "") == "1"
 # beyond these lengths the materialized scores dominate HBM; stream
 # instead. Two thresholds (r5): CAUSAL unbiased attention runs q-chunked
 # (_causal_chunked_fwd_impl — fully-masked blocks never computed, ~0.53·L²
@@ -257,14 +253,11 @@ def _flash_attention_impl(q, k, v, causal, block_q, block_k):
 def jax_flash_attention(q, k, v, causal=False, block_q=None, block_k=None):
     """The jax-shipped Mosaic flash-attention kernel (fwd AND bwd kernels,
     [b, h, l, d]), with block sizes clamped to the shape. Falls back to the
-    local ``flash_attention`` tier (→ blockwise) when the shape doesn't
-    tile, or when TRACING fails (eager x64 issues etc.) — a Mosaic compile
-    SERVICE failure under jit surfaces at jit-compile time instead; use the
-    'auto'/'xla' impl on such rigs."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        BlockSizes, flash_attention as _fa)
+    local ``flash_attention`` tier (→ blockwise), counted, when the shape
+    doesn't tile; a kernel the compiler refuses is an error."""
+    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
 
-    L, d = q.shape[2], q.shape[3]
+    L = q.shape[2]
     bq = min(block_q or 512, L)
     bk = min(block_k or 512, L)
     if L % bq != 0 or L % bk != 0 or k.shape[2] != L:
@@ -279,14 +272,41 @@ def jax_flash_attention(q, k, v, causal=False, block_q=None, block_k=None):
         block_q_major_dkv=bq, block_k_major_dkv=bk, block_k_dkv=bk,
         block_q_dkv=bq, block_k_major_dq=bk, block_k_dq=bk, block_q_dq=bq,
     )
-    # the kernel's index math assumes 32-bit python-int promotion; this repo
-    # enables x64 globally, so scope it off around the trace
-    try:
-        with jax.enable_x64(False):
-            return _fa(q, k, v, causal=causal, block_sizes=bs,
-                       sm_scale=1.0 / math.sqrt(d))
-    except Exception:
-        return flash_attention(q, k, v, causal)
+    return _jax_flash_x32(q, k, v, causal, bs)
+
+
+# The jax-shipped kernel's index math assumes 32-bit python-int promotion
+# and this repo enables x64 globally. Its backward kernels are traced when
+# the cotangent arrives — long after a ``with enable_x64(False)`` around
+# the forward call has exited (on the chip: "lax.select requires arguments
+# to have the same dtypes, got int64, int32") — so forward and backward
+# each get their own 32-bit scope through this custom_vjp.
+def _jax_flash_call(q, k, v, causal, bs):
+    from jax.experimental.pallas.ops.tpu.flash_attention import \
+        flash_attention as _fa
+
+    return _fa(q, k, v, causal=causal, block_sizes=bs,
+               sm_scale=1.0 / math.sqrt(q.shape[3]))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _jax_flash_x32(q, k, v, causal, bs):
+    with jax.enable_x64(False):
+        return _jax_flash_call(q, k, v, causal, bs)
+
+
+def _jax_flash_x32_fwd(q, k, v, causal, bs):
+    with jax.enable_x64(False):
+        return jax.vjp(functools.partial(_jax_flash_call, causal=causal,
+                                         bs=bs), q, k, v)
+
+
+def _jax_flash_x32_bwd(causal, bs, vjp, g):
+    with jax.enable_x64(False):
+        return vjp(g)
+
+
+_jax_flash_x32.defvjp(_jax_flash_x32_fwd, _jax_flash_x32_bwd)
 
 
 def _flash_fwd_rule(q, k, v, causal, block_q, block_k):
@@ -311,30 +331,6 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # ---------------------------------------------------------------------------
 # Ring attention (sequence/context parallelism over a mesh axis)
 # ---------------------------------------------------------------------------
-def _shard_map_fn():
-    """shard_map across jax versions: ``jax.shard_map`` (new API,
-    replication checking keyword ``check_vma``) or
-    ``jax.experimental.shard_map.shard_map`` (0.4.x, ``check_rep``).
-    Returns a ``fn(f, mesh, in_specs, out_specs)`` wrapper with
-    replication checking disabled (ring's psums confuse the checker), or
-    None when neither API exists (callers keep their single-device
-    path)."""
-    sm = getattr(jax, "shard_map", None)
-    kw = "check_vma"
-    if sm is None:
-        try:
-            from jax.experimental.shard_map import shard_map as sm
-            kw = "check_rep"
-        except Exception:
-            return None
-
-    def wrap(f, mesh, in_specs, out_specs):
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **{kw: False})
-
-    return wrap
-
-
 def _ring_pass(q, k, v, axis_name, causal, fn, init):
     """One full rotation of K/V around ``axis_name``: ``fn(carry, kc, vc,
     q_off, kv_off)`` folds the resident shard into the carry, then K/V
@@ -507,9 +503,7 @@ def _ring_auto_ok(L: int, causal: bool, bias) -> bool:
     if forced in ("xla", "blockwise", "flash_tpu", "pallas", "heuristic"):
         return False
     size = mesh.shape[axis]
-    if L % size != 0 or (L < _ring_min_seq() and forced != "ring"):
-        return False
-    return _shard_map_fn() is not None
+    return L % size == 0 and (L >= _ring_min_seq() or forced == "ring")
 
 
 def _ring_unavailable_reason(L: int, causal: bool, bias) -> str:
@@ -531,8 +525,6 @@ def _ring_unavailable_reason(L: int, causal: bool, bias) -> str:
     if L % mesh.shape[axis] != 0:
         return (f"sequence length {L} does not divide the ring size "
                 f"{mesh.shape[axis]}")
-    if _shard_map_fn() is None:
-        return "this jax has no shard_map API"
     return "the ring context was cleared by a later engine"
 
 
@@ -546,7 +538,6 @@ def _ring_sharded(q, k, v, causal, blhd):
 
     mesh, axis = _ring_ctx["mesh"], _ring_ctx["axis"]
     ba = _ring_ctx["batch"]  # keep the engine's dp sharding on the batch dim
-    sm = _shard_map_fn()
     spec = P(ba, axis, None, None) if blhd else P(ba, None, axis, None)
 
     def local(q_, k_, v_):
@@ -556,7 +547,9 @@ def _ring_sharded(q, k, v, causal, blhd):
                                      causal, 512))
         return ring_attention(q_, k_, v_, axis, causal, 512)
 
-    return sm(local, mesh, (spec, spec, spec), spec)(q, k, v)
+    # replication checking off: ring's psums confuse the checker
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -936,7 +929,7 @@ def _causal_chunked_bwd(blhd, res, g):
         # pad-to-L and tree-sum: measured BEST of three accumulation
         # shapes for the ragged dk/dv chunk contributions on v5e (ragged
         # per-block slice+sum+concat re-lowered to 2.8× the
-        # dynamic-update-slice traffic; see r5_gpt.txt)
+        # dynamic-update-slice traffic)
         pad = [(0, 0)] * q.ndim
         pad[axis_l] = (0, Lq - ub)
         dks.append(jnp.pad(jnp.einsum(dk_eq, dS, qi) * scale, pad))
@@ -974,8 +967,7 @@ def xla_attention(q, k, v, causal=False, bias=None, layout="bhld"):
     Lq, Lk = q.shape[axis_l], k.shape[axis_l]
     if (causal and bias is None and Lq == Lk
             and _causal_chunk_size(Lq) is not None):
-        # chunk-count cap keeps the emitted program small (some TPU compile
-        # services reject huge ones)
+        # the chunk-count cap keeps the emitted program small
         if _MANUAL_ATTN_VJP:
             return _causal_chunked(q, k, v, blhd)
         return _causal_chunked_fwd_impl(q, k, v, blhd)[0]
@@ -1114,9 +1106,10 @@ def _select_impl(q, k, bias, use_flash, causal, blhd):
         # materialized O(L²) form — wrong for long L)
         _count_fallback(
             "flash_tpu", q.shape,
-            "shape does not tile onto the flash_tpu kernel (needs "
-            "Lq == Lk, L % 256 == 0, heads*dim % 128 == 0) — streaming "
-            "via blockwise instead, ~8-10x slower at long L")
+            "shape does not fit the flash_tpu kernel (needs Lq == Lk, "
+            "L % 256 == 0, heads*dim % 128 == 0, K and V of one batch "
+            "row within its VMEM budget) — streaming via blockwise "
+            "instead, ~8-10x slower at long L")
         impl = "blockwise"
     return impl
 
@@ -1152,8 +1145,7 @@ def _tier_candidates(q, k, causal, blhd):
     xla_cap = 2 * (_XLA_MAX_SEQ_CAUSAL if causal else _XLA_MAX_SEQ)
     if Lk == L and L <= xla_cap:
         cands.append("xla")
-    if (on_tpu and causal and not _NO_MOSAIC
-            and _flash_tpu_fits(q, k, blhd=blhd)):
+    if on_tpu and causal and _flash_tpu_fits(q, k, blhd=blhd):
         cands.append("flash_tpu")
     # mirror jax_flash_attention's own dispatch gate (L must tile its
     # min(512, L) default blocks) — a candidate the kernel would bounce
@@ -1168,7 +1160,7 @@ def _tier_candidates(q, k, causal, blhd):
 def _flash_tpu_fits(q, k, blhd):
     """Shape gate for routing AUTO dispatch into the flash_tpu kernel:
     self-attention only (Lq == Lk — the kernel reshapes k to q's length)
-    and the kernel's own tiling constraints."""
+    and the kernel's own tiling and VMEM constraints."""
     from .flash_tpu import _fits
 
     if blhd:
@@ -1177,7 +1169,7 @@ def _flash_tpu_fits(q, k, blhd):
     else:
         b, H, L, d = q.shape
         Lk = k.shape[2]
-    return Lk == L and _fits(b, L, H, d, 256)
+    return Lk == L and _fits(b, L, H, d, 256, q.dtype.itemsize)
 
 
 def _resolve_impl(L, bias, use_flash, causal=True):
@@ -1198,9 +1190,7 @@ def _resolve_impl(L, bias, use_flash, causal=True):
     flash kernel (flash_tpu.py) and the rest to the blockwise recurrence
     (the scan path is 8-10x slower — measured L=8192 f+b: 100ms vs 13ms —
     but O(L) in memory). Off-TPU flash_attention safely degrades to
-    blockwise. The kernel tiers gate on SHAPE at trace time; a rig whose
-    Mosaic compile service itself fails surfaces that at jit-compile
-    time — select 'xla'/'blockwise' there."""
+    blockwise. The kernel tiers gate on SHAPE at trace time."""
     on_tpu = jax.default_backend() == "tpu"
     if _IMPL == "flash_tpu":
         return "flash_tpu" if (on_tpu and bias is None and causal) else "xla"
@@ -1219,7 +1209,7 @@ def _resolve_impl(L, bias, use_flash, causal=True):
                    else _XLA_MAX_SEQ)
         if L <= xla_max:
             return "xla"
-        if causal and bias is None and not _NO_MOSAIC:
+        if causal and bias is None:
             return "flash_tpu"
         return "blockwise"
     return "blockwise" if bias is not None else "flash"

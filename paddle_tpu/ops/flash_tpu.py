@@ -159,9 +159,20 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # ---------------------------------------------------------------------------
 # host-side plumbing
 # ---------------------------------------------------------------------------
-def _fits(b, L, H, d, block):
+# Every kernel here keeps two whole [L, H*d] operands of one batch row in
+# VMEM (K and V in fwd/dq, Q and dO in dkv). Measured on the v5e with the
+# compiler's default scoped-VMEM limit (libtpu 0.0.34): 24 MiB of them
+# compile (L=8192, H*d=768, bf16), 48 MiB are refused with "Ran out of
+# memory in memory space vmem" (the same shape in f32, or L=16384). With
+# ``vmem_limit_bytes`` raised, up to 96 MiB compiled (PERF.md, PR 21) —
+# not passed here, so the gate stops at what the default accepts.
+_RESIDENT_VMEM_BYTES = 24 << 20
+
+
+def _fits(b, L, H, d, block, itemsize):
     return (jax.default_backend() == "tpu" and L % block == 0
-            and L // block >= 1 and d % 8 == 0 and (H * d) % 128 == 0)
+            and L // block >= 1 and d % 8 == 0 and (H * d) % 128 == 0
+            and 2 * L * H * d * itemsize <= _RESIDENT_VMEM_BYTES)
 
 
 def _fwd_call(q3, k3, v3, b, L, H, d, block, scale):
@@ -203,7 +214,7 @@ def flash_attention_blhd(q, k, v, causal=True, block=256):
 
 def _flash_fwd(q, k, v, causal, block):
     b, L, H, d = q.shape
-    if not causal or not _fits(b, L, H, d, block):
+    if not causal or not _fits(b, L, H, d, block, q.dtype.itemsize):
         from .attention import _count_fallback, xla_attention
 
         if jax.default_backend() == "tpu":
@@ -213,9 +224,10 @@ def _flash_fwd(q, k, v, causal, block):
             # invisible (off-TPU the XLA path is documented behavior)
             _count_fallback(
                 "flash_tpu", q.shape,
-                f"flash_attention_blhd cannot tile this shape (needs "
-                f"causal, L % {block} == 0, H*d % 128 == 0) — "
-                f"materializing via the XLA tier")
+                f"flash_attention_blhd cannot take this shape (needs "
+                f"causal, L % {block} == 0, H*d % 128 == 0, two "
+                f"[L, H*d] operands within {_RESIDENT_VMEM_BYTES >> 20} "
+                f"MiB) — materializing via the XLA tier")
         return xla_attention(q, k, v, causal=causal, layout="blhd"), None
     scale = 1.0 / math.sqrt(d)
     q3 = q.reshape(b, L, H * d)

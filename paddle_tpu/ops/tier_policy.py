@@ -3,21 +3,24 @@
 ``ops/attention.py`` carries four interchangeable tiers (the materialized
 ``xla`` path, the repo's ``flash_tpu`` Pallas kernel, the jax-shipped
 ``pallas`` kernel, the streaming ``blockwise`` recurrence) whose relative
-speed depends on shape, dtype, AND the rig (r4/r5 bench notes: the same
-L=8192 causal shape measured 46.5k tok/s on the chunked XLA tier vs 27.5k
-on flash_tpu on a rig whose Mosaic compile service is ~7x off the pace —
-a hardcoded threshold is wrong somewhere for someone). This module makes
-``impl='auto'`` consult a *measured* verdict instead:
+speed depends on shape, dtype and toolchain — a hardcoded threshold is
+wrong somewhere for someone. This module makes ``impl='auto'`` consult a
+*measured* verdict instead:
 
 - **One micro-bench per (backend, device_kind, heads, L, d, dtype,
   causal)**: the first trace that dispatches an unseen attention shape
   times every feasible tier — forward+backward, AOT-compiled
   (``jit -> lower -> compile``; the executable call path is immune to
   the ambient trace the selection usually runs under) — and the fastest
-  wins. ``counter/attn/tier_bench`` counts benches run.
+  wins. ``counter/attn/tier_bench`` counts benches run. On TPU a
+  candidate that passed its shape gate and is then refused by the
+  compiler is an ERROR carrying the compiler's message
+  (``TierCompileError``): feasibility is the gate's decision, and a gate
+  that offers what the chip rejects is a bug to fix, not a tier to drop.
 - **Persistent verdicts**: results land in a JSON cache file
-  (``PADDLE_TPU_ATTN_TIER_CACHE``, defaulting next to the persistent XLA
-  compile cache when ``PADDLE_TPU_COMPILE_CACHE_DIR`` is set), committed
+  (``PADDLE_TPU_ATTN_TIER_CACHE``, defaulting to ``attn_tiers.json``
+  inside the persistent XLA compile cache directory in effect —
+  ``device.configure_compilation_cache``), committed
   via ``framework.io.atomic_replace``, so a process restart re-selects
   without re-measuring — the same restart-warm contract as the compile
   cache whose key scheme (backend + device_kind + abstract shape) this
@@ -52,8 +55,15 @@ logger = logging.getLogger("paddle_tpu.ops")
 __all__ = [
     "TIER_IDS", "PAGED_TIERS", "policy_mode", "forced_mode", "cache_path",
     "select", "select_paged", "publish_tier", "registry", "TierRegistry",
-    "reset",
+    "TierCompileError", "reset",
 ]
+
+
+class TierCompileError(RuntimeError):
+    """A tier that passed its shape gate failed to compile or run on the
+    TPU during the micro-bench. Carries the tier's name and the compiler's
+    message; the fix is the gate (or the kernel), never a silent drop."""
+
 
 # stable numeric ids for the gauge/attn/tier.* telemetry (schema: >= 0).
 # paged_gather / paged_scan are the DECODE tiers (attention over the
@@ -113,13 +123,15 @@ def policy_mode() -> str:
 
 
 def cache_path() -> Optional[str]:
-    """Verdict cache file, or None (memory-only). Keyed like the XLA
-    compile cache: ``PADDLE_TPU_ATTN_TIER_CACHE`` wins, else
-    ``<PADDLE_TPU_COMPILE_CACHE_DIR>/attn_tiers.json``."""
+    """Verdict cache file, or None (memory-only):
+    ``PADDLE_TPU_ATTN_TIER_CACHE`` wins, else ``attn_tiers.json`` inside
+    whichever XLA compile cache directory is in effect."""
     p = os.environ.get("PADDLE_TPU_ATTN_TIER_CACHE")
     if p:
         return p
-    d = os.environ.get("PADDLE_TPU_COMPILE_CACHE_DIR")
+    import jax
+
+    d = jax.config.jax_compilation_cache_dir
     return os.path.join(d, "attn_tiers.json") if d else None
 
 
@@ -291,10 +303,24 @@ def _tier_callable(tier: str, causal: bool):
     raise ValueError(f"unknown tier {tier!r}")
 
 
+def _tier_failed(tier: str, what: str, e: Exception) -> None:
+    """A candidate failed to compile or run in the micro-bench. On TPU
+    that is an error (see ``TierCompileError``); elsewhere the tier is
+    dropped from this verdict, as the CPU backend cannot build the
+    kernels at all."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise TierCompileError(
+            f"attention tier {tier!r} passed its shape gate for {what} and "
+            f"then failed on the TPU — {type(e).__name__}: {e}") from e
+    logger.info("tier_policy: tier %r infeasible for %s (%s: %s)",
+                tier, what, type(e).__name__, e)
+
+
 def _time_tier(tier: str, q, k, v, causal: bool) -> Optional[float]:
-    """Median seconds of one fwd+bwd step, or None if the tier fails to
-    compile/run for this shape on this rig (a Mosaic compile-service
-    failure is data, not an error: the verdict routes around it).
+    """Fastest-rep seconds of one fwd+bwd step; None when the tier fails
+    to compile/run off-TPU (on TPU that raises — ``_tier_failed``).
 
     The step is AOT-compiled (``jit -> lower -> compile``) and the
     EXECUTABLE is what the clock times: a selection usually triggered
@@ -327,8 +353,7 @@ def _time_tier(tier: str, q, k, v, causal: bool) -> Optional[float]:
         # closest to the kernel's true cost
         return min(times)
     except Exception as e:
-        logger.info("tier_policy: tier %r infeasible for this shape/rig "
-                    "(%s: %s)", tier, type(e).__name__, e)
+        _tier_failed(tier, f"q{tuple(q.shape)} {q.dtype} causal={causal}", e)
         return None
 
 
@@ -366,6 +391,7 @@ def bench(key: str, h: int, L: int, d: int, dtype, causal: bool,
     best = min(timings, key=timings.get)
     verdict = {
         "tier": best,
+        "candidates": list(candidates),
         "timings_ms": {t: round(s * 1e3, 3) for t, s in timings.items()},
         "ts": time.time(),
     }
@@ -391,10 +417,10 @@ def select(h: int, L: int, d: int, dtype, causal: bool,
     if verdict is None:
         verdict = bench(key, h, L, d, dtype, causal, candidates)
     elif verdict.get("tier") not in candidates:
-        # the cached winner is infeasible for THIS call's candidate set —
-        # which, for an identical key, can only mean an env knob shrank
-        # the set (e.g. PADDLE_TPU_ATTN_NO_MOSAIC). Re-measure for this
-        # process but never overwrite the full-set verdict on disk.
+        # the cached winner is not among THIS call's candidates (a gate
+        # changed since the verdict was written, or a caller restricted
+        # the set). Re-measure for this process but never overwrite the
+        # full-set verdict on disk.
         verdict = bench(key, h, L, d, dtype, causal, candidates,
                         persist=False)
     if verdict is None:
@@ -409,7 +435,7 @@ def select(h: int, L: int, d: int, dtype, causal: bool,
 # context fits comfortably), 'paged_scan' streams page-by-page with
 # online softmax (O(block) live memory — wins for long contexts and is
 # the only safe choice near HBM capacity). Their crossover depends on
-# rig and shape exactly like the training tiers, so the same machinery
+# chip and shape exactly like the training tiers, so the same machinery
 # applies: measure once per shape key, persist the verdict, zero
 # per-step cost (selection happens at trace time of the decode step).
 
@@ -509,12 +535,12 @@ def bench_paged(key: str, t: int, h: int, d: int, m: int, bs: int, dtype,
                 times.append(time.perf_counter() - t0)
             timings[tier] = min(times)  # min: host noise only adds time
         except Exception as e:
-            logger.info("tier_policy: paged tier %r infeasible (%s: %s)",
-                        tier, type(e).__name__, e)
+            _tier_failed(tier, key, e)
     if not timings:
         return None
     best = min(timings, key=timings.get)
     verdict = {"tier": best,
+               "candidates": list(PAGED_TIERS),
                "timings_ms": {k2: round(s * 1e3, 3)
                               for k2, s in timings.items()},
                "ts": time.time()}

@@ -7,7 +7,8 @@ step's device time went* by joining two artifacts the framework already
 produces:
 
 - the **compiled HLO text** of every ``tracked_jit`` entry — op names,
-  ``metadata={op_name=... source_file=... source_line=...}`` — captured
+  ``metadata={op_name=... stack_frame_id=...}`` plus the module's stack
+  frame tables (``analysis.hlo.parsing.parse_stack_frames``) — captured
   at compile time into the :class:`HloRegistry` by ``xla_cost.capture``
   (full mode stores the optimized text the compile already produced; the
   default mode stores the in-hand ``Lowered`` and compiles to text only
@@ -114,12 +115,13 @@ def parse_hlo_text(text: str) -> Dict[str, HloOp]:
     lines without metadata still register (opcode + name only), so trace
     events can at least be categorized and counted."""
     ops: Dict[str, HloOp] = {}
+    frames = _hloparse.parse_stack_frames(text)
     for name, body, _lineno in _hloparse.iter_instruction_lines(text):
         instr = _hloparse.HloInstr(name=name, opcode=_opcode_of(body),
                                    type_text="", body=body, line=_lineno,
                                    computation="")
-        src = instr.source_src()
-        ops[name] = HloOp(name=name, opcode=instr.opcode, src=src,
+        ops[name] = HloOp(name=name, opcode=instr.opcode,
+                          src=_hloparse.source_of(body, frames),
                           op_name=instr.op_name())
     return ops
 

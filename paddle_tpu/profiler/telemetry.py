@@ -575,9 +575,14 @@ def sample_device_memory(telemetry: Optional[Telemetry] = None) -> dict:
     carry the summed total — reading only device 0 under-reported every
     multi-chip process by a factor of the local device count."""
     import jax
+    from jax._src import xla_bridge
 
     tel = telemetry or get_telemetry()
     out = {}
+    if not xla_bridge.backends_are_initialized():
+        # a sampler must never be what opens the device: in a launcher
+        # parent that would take the chip from the children that need it
+        return out
     try:
         out["device/live_bytes"] = float(
             sum(getattr(a, "nbytes", 0) for a in jax.live_arrays()))

@@ -75,12 +75,11 @@ _CHIP_PEAKS = (
     ("v4", (275e12, 1228e9)),
     ("v3", (123e12, 900e9)),
     ("v2", (45e12, 700e9)),
-    # CPU simulation rigs: a nominal per-process peak so MFU math stays
+    # CPU test mesh: a nominal per-process peak so MFU math stays
     # exercised end-to-end off-TPU (absolute value is not meaningful —
     # override with PADDLE_TPU_PEAK_FLOPS for a calibrated host).
     ("cpu", (5e11, 50e9)),
 )
-_FALLBACK_PEAKS = (1e12, 100e9)
 
 # device_kind substring (lowercased) -> HBM capacity in bytes. Same
 # first-match-wins ordering as _CHIP_PEAKS. The CPU entry is a nominal
@@ -94,7 +93,19 @@ _CHIP_HBM = (
     ("v4", 32e9), ("v3", 32e9), ("v2", 16e9),
     ("cpu", 64e9),
 )
-_FALLBACK_HBM = 32e9
+
+
+def _lookup(table, kind: str, what: str):
+    """First row of ``table`` whose key is a substring of ``kind``. A
+    device the table does not know is an error, never a default: a made-up
+    peak would publish a made-up utilization."""
+    for sub, row in table:
+        if sub in kind:
+            return row
+    raise ValueError(
+        f"xla_cost: no {what} on record for device_kind {kind!r} — add a "
+        f"row to the table in {__name__} (with its source)")
+
 
 _peaks_cache = None
 _peaks_lock = threading.Lock()
@@ -111,22 +122,13 @@ def hbm_capacity_bytes() -> float:
             return ov
     except ValueError:
         pass
-    kind = "unknown"
-    try:
-        import jax
+    import jax
 
-        dev = jax.local_devices()[0]
-        kind = str(dev.device_kind).lower()
-        stats = dev.memory_stats()
-        limit = (stats or {}).get("bytes_limit", 0)
-        if limit and limit > 0:
-            return float(limit)
-    except Exception:
-        pass
-    for sub, cap in _CHIP_HBM:
-        if sub in kind:
-            return cap
-    return _FALLBACK_HBM
+    dev = jax.local_devices()[0]
+    limit = (dev.memory_stats() or {}).get("bytes_limit", 0)
+    if limit and limit > 0:
+        return float(limit)
+    return _lookup(_CHIP_HBM, str(dev.device_kind).lower(), "HBM capacity")
 
 
 def cost_analysis_mode() -> str:
@@ -149,18 +151,10 @@ def chip_peaks() -> Dict[str, float]:
     with _peaks_lock:
         if _peaks_cache is not None:
             return _peaks_cache
-        kind = "unknown"
-        try:
-            import jax
+        import jax
 
-            kind = str(jax.devices()[0].device_kind).lower()
-        except Exception:
-            pass
-        flops, bps = _FALLBACK_PEAKS
-        for sub, (f, b) in _CHIP_PEAKS:
-            if sub in kind:
-                flops, bps = f, b
-                break
+        kind = str(jax.devices()[0].device_kind).lower()
+        flops, bps = _lookup(_CHIP_PEAKS, kind, "peak FLOP/s")
         # non-positive overrides are rejected (kept at the registry
         # default): a zero would turn every MFU division into a crash,
         # and "0 to disable" belongs to PADDLE_TPU_COST_ANALYSIS
@@ -565,12 +559,18 @@ def publish_mfu(telemetry: Optional[Telemetry] = None) -> Dict[str, dict]:
     stores — ``Telemetry.to_jsonl`` calls it so every exported record
     carries a fresh MFU."""
     tel = telemetry or get_telemetry()
+    latest = _registry.latest()
+    if not latest:
+        # nothing compiled here (e.g. a launcher parent flushing its
+        # telemetry): do not look up peaks, which would open a device
+        # this process never used and its children need
+        return {}
     peaks = chip_peaks()
     if peaks["flops"] <= 0:
         return {}  # no peak to normalize against — publish nothing
     out: Dict[str, dict] = {}
     headline_entry = _registry.last_entry()
-    for entry, rec in _registry.latest().items():
+    for entry, rec in latest.items():
         hist = step_hist_for(entry)
         if hist is None:
             continue

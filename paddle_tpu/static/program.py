@@ -227,28 +227,11 @@ def program_guard(main_program, startup_program=None):
     # install the recorder hook into the eager op layer
     prev_rec = tensor_mod._op_recorder
     tensor_mod._op_recorder = main_program.record_op
-    # record-time eager ops run on the HOST CPU: their values are throwaway
-    # (batch-1 placeholders) except parameter inits, and each distinct op
-    # shape would otherwise trigger an accelerator compile — on rigs with a
-    # remote compile service, recording ResNet-50 measured ~188 s on-device
-    # vs seconds on CPU. Replay jits on the real backend; params transfer
-    # on first run.
-    cpu_ctx = None
-    try:
-        import jax
-
-        if jax.default_backend() != "cpu":
-            cpu_ctx = jax.default_device(jax.devices("cpu")[0])
-            cpu_ctx.__enter__()
-    except Exception:
-        cpu_ctx = None
     try:
         yield
     finally:
         _state.main, _state.startup = prev_m, prev_s
         tensor_mod._op_recorder = prev_rec
-        if cpu_ctx is not None:
-            cpu_ctx.__exit__(None, None, None)
 
 
 @contextlib.contextmanager

@@ -63,8 +63,7 @@ def qr(x, mode="reduced", name=None):
 
 def svd(x, full_matrices=False, name=None):
     # SVD-family lowerings are LAPACK-style iterations XLA:TPU handles
-    # poorly (and some TPU compile services reject the custom-call
-    # outright) — concrete eager calls on TPU route to the host CPU
+    # poorly — concrete eager calls on TPU route to the host CPU
     # backend like ``eig`` below.
     # The eager-TPU host fallback routes THROUGH apply_op (not around it,
     # which returned grad-less, unrecorded results): the op function

@@ -99,8 +99,8 @@ class ResNet(nn.Layer):
         space-to-depth reformulation (vision.ops.space_to_depth_stem_conv
         — C_in=3 under-fills the MXU; s2d quadruples the contraction).
         Default OFF: measured ~5% SLOWER end-to-end on v5e (1492 vs 1564
-        samples/s, b=64 bf16) — this rig's XLA already handles the stem
-        well and the pad/regroup reshapes cost more than the conv saves;
+        samples/s, b=64 bf16) — XLA already handles the stem well and
+        the pad/regroup reshapes cost more than the conv saves;
         the classic trick is kept as a knob for topologies where it pays."""
         import os
 

@@ -159,9 +159,9 @@ def test_auto_long_sequence_resolves_to_flash_kernel(monkeypatch):
 
 
 def test_auto_long_nonfitting_falls_back_to_blockwise(monkeypatch):
-    """Shapes the kernel can't tile (L % 256, Lq != Lk) must stream via
-    blockwise, not materialize O(L^2) through the kernel's internal
-    fallback."""
+    """Shapes the kernel can't take (L % 256, Lq != Lk, operands past its
+    VMEM budget) must stream via blockwise, not materialize O(L^2) through
+    the kernel's internal fallback."""
     import jax.numpy as jnp
 
     monkeypatch.setattr(att.jax, "default_backend", lambda: "tpu")
@@ -171,3 +171,10 @@ def test_auto_long_nonfitting_falls_back_to_blockwise(monkeypatch):
     q2 = jnp.zeros((1, 8192, 4, 64), jnp.float32)
     assert not att._flash_tpu_fits(q2, k, blhd=True)
     assert att._flash_tpu_fits(q2, q2, blhd=True)
+    # the kernels hold two whole [L, H*d] operands in VMEM: the longctx
+    # bench shape compiled on the v5e in bf16 (24 MiB) and was refused in
+    # f32 (48 MiB), so the gate must not offer the latter
+    wide = jnp.zeros((1, 8192, 12, 64), jnp.bfloat16)
+    assert att._flash_tpu_fits(wide, wide, blhd=True)
+    assert not att._flash_tpu_fits(wide.astype(jnp.float32),
+                                   wide.astype(jnp.float32), blhd=True)

@@ -25,9 +25,9 @@ from paddle_tpu.ops import attention as att
 from paddle_tpu.ops import remat_policy, tier_policy
 from paddle_tpu.profiler.telemetry import get_telemetry
 
-_sm = att._shard_map_fn()
-needs_shard_map = pytest.mark.skipif(
-    _sm is None, reason="no shard_map API in this jax")
+def _sm(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @pytest.fixture(autouse=True)
@@ -135,9 +135,9 @@ class TestTierCache:
 
     def test_restricted_candidates_never_clobber_disk_verdict(
             self, monkeypatch, tmp_path):
-        """An env-restricted candidate set (e.g. PADDLE_TPU_ATTN_NO_MOSAIC
-        dropping the fast tier) re-measures for its own process but must
-        not overwrite the full-set verdict on disk."""
+        """A restricted candidate set (a gate that changed since the
+        verdict was written) re-measures for its own process but must not
+        overwrite the full-set verdict on disk."""
         cache = tmp_path / "tiers.json"
         monkeypatch.setenv("PADDLE_TPU_ATTN_POLICY", "bench")
         monkeypatch.setenv("PADDLE_TPU_ATTN_TIER_CACHE", str(cache))
@@ -146,7 +146,7 @@ class TestTierCache:
         assert tier_policy.select(
             4, 128, 32, jnp.float32, True,
             ["flash_tpu", "xla", "blockwise"]) == "flash_tpu"
-        # "restart" into a process whose env knocked flash_tpu out
+        # "restart" into a process whose gate knocked flash_tpu out
         tier_policy.reset()
         assert tier_policy.select(4, 128, 32, jnp.float32, True,
                                   ["xla", "blockwise"]) == "xla"
@@ -174,7 +174,6 @@ class TestTierCache:
             self, monkeypatch, rng):
         monkeypatch.setenv("PADDLE_TPU_ATTN_POLICY", "bench")
         monkeypatch.delenv("PADDLE_TPU_ATTN_TIER_CACHE", raising=False)
-        monkeypatch.delenv("PADDLE_TPU_COMPILE_CACHE_DIR", raising=False)
         _stub_times(monkeypatch, {"xla": 1.0, "blockwise": 2.0})
         tel = get_telemetry()
         before = tel.counter_value("attn/tier_bench")
@@ -236,7 +235,6 @@ class TestFallbackAccounting:
 # ---------------------------------------------------------------------------
 # ring attention: gradients + auto promotion
 # ---------------------------------------------------------------------------
-@needs_shard_map
 class TestRingAttentionGrad:
     def _ring(self, causal):
         mesh = Mesh(np.array(jax.devices()[:4]), ("sp",))
@@ -270,7 +268,6 @@ class TestRingAttentionGrad:
                                    rtol=2e-4, atol=2e-5)
 
 
-@needs_shard_map
 class TestRingAutoPromotion:
     def test_auto_promotes_on_registered_mesh(self, monkeypatch, rng):
         monkeypatch.setenv("PADDLE_TPU_ATTN_RING_MIN_SEQ", "64")
@@ -366,7 +363,6 @@ class TestRingAutoPromotion:
                               sp_axis="seq")
 
 
-@needs_shard_map
 class TestFleetSequenceParallel:
     def _build(self, sp):
         import paddle_tpu as paddle

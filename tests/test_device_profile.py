@@ -82,6 +82,18 @@ class TestParseHlo:
         # ROOT-prefixed and computation-internal instructions register too
         assert "add.8" in ops and "reduce.10" in ops
 
+    def test_header_table_form_resolves_the_same_sources(self):
+        # the installed jaxlib writes stack_frame_id=N per op plus
+        # FileNames/FileLocations/StackFrames tables at the top of the
+        # module; the recorded fixture keeps the older inline form
+        with open(os.path.join(FIXTURES, "golden_hlo_frames.txt")) as f:
+            framed = hlo_attrib.parse_hlo_text(f.read())
+        inline = hlo_attrib.parse_hlo_text(_golden_hlo())
+        assert "stack_frame_id" not in _golden_hlo()
+        assert {n: (o.opcode, o.src, o.op_name) for n, o in framed.items()} \
+            == {n: (o.opcode, o.src, o.op_name) for n, o in inline.items()}
+        assert framed["all-reduce.5"].src == "grad.py:20"
+
     def test_categories(self):
         ops = hlo_attrib.parse_hlo_text(_golden_hlo())
         assert ops["dot.3"].category == "compute"
@@ -548,37 +560,36 @@ class TestBenchTrajectoryGate:
             capture_output=True, text=True)
         return r.returncode, r.stdout + r.stderr
 
-    def test_committed_history_passes(self):
-        rc, out = self._run("--root", REPO, "--tol-override",
-                            "lenet_mnist_dygraph_samples_per_sec=0.25")
-        assert rc == 0, out
-        assert out.startswith("bench trajectory: OK")
+    # every history below is synthetic: the gate's behaviour is under
+    # test, not any recorded number
+    _METRIC = "gpt_small_L8192_longctx_train_tokens_per_sec"
 
     def _synth(self, tmp_path, regress=True):
-        import shutil
+        for n, v in ((1, 100.0), (2, 104.0)):
+            (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(
+                {"parsed": {"metric": "headline_tokens_per_sec",
+                            "value": v}}))
+        base = {"metric": self._METRIC, "value": 60000.0,
+                "mfu_measured_pct": 41.0,
+                "attribution_entry": "fleet.train_step",
+                "profile_host_gap_frac": 0.10}
+        other = {"metric": "lenet_mnist_dygraph_samples_per_sec",
+                 "value": 18000.0}
+        (tmp_path / "BENCH_extra.prev.json").write_text(
+            json.dumps([other, base]))
+        cand = dict(base)
+        if regress:
+            cand["value"] *= 0.7
+            cand["profile_host_gap_frac"] = 0.62
+        (tmp_path / "BENCH_extra.json").write_text(
+            json.dumps([other, cand]))
+        return self._METRIC
 
-        for f in ("BENCH_r01.json", "BENCH_r05.json"):
-            shutil.copy(os.path.join(REPO, f), tmp_path / f)
-        metric = "gpt_small_L8192_longctx_train_tokens_per_sec"
-        prev = json.load(open(os.path.join(REPO, "BENCH_extra.prev.json")))
-        for r in prev:
-            if r["metric"] == metric:
-                r["mfu_measured_pct"] = 41.0
-                r["attribution_entry"] = "fleet.train_step"
-                r["profile_host_gap_frac"] = 0.10
-        (tmp_path / "BENCH_extra.prev.json").write_text(json.dumps(prev))
-        cand = json.load(open(os.path.join(REPO, "BENCH_extra.json")))
-        out = []
-        for r in cand:
-            r = dict(r)
-            if r["metric"] == metric and regress:
-                r["value"] *= 0.7
-                r["mfu_measured_pct"] = 41.0
-                r["attribution_entry"] = "fleet.train_step"
-                r["profile_host_gap_frac"] = 0.62
-            out.append(r)
-        (tmp_path / "BENCH_extra.json").write_text(json.dumps(out))
-        return metric
+    def test_flat_history_passes(self, tmp_path):
+        self._synth(tmp_path, regress=False)
+        rc, out = self._run("--root", str(tmp_path))
+        assert rc == 0, out
+        assert out.startswith("bench trajectory: OK")
 
     def test_synthetic_regression_names_metric_and_suspect(self, tmp_path):
         metric = self._synth(tmp_path)

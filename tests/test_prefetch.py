@@ -1,7 +1,7 @@
 """Device-resident input pipeline: DevicePrefetcher lifecycle, shape
-bucketing + retrace bounds, the persistent compilation cache hook, the
-single-pytree executor feed path, persistent DataLoader workers, and the
-retrace-budget CI gate."""
+bucketing + retrace bounds, the single-pytree executor feed path,
+persistent DataLoader workers, and the retrace-budget CI gate. (Where the
+persistent compilation cache lives is tests/test_chip_smoke.py's.)"""
 import json
 import os
 import threading
@@ -296,35 +296,6 @@ class TestHapiFitPrefetch:
         for k in plain:
             np.testing.assert_allclose(plain[k], pre[k], rtol=1e-6)
         assert not _prefetch_threads()  # fit closed its epoch pipelines
-
-
-class TestCompilationCache:
-    def test_env_gated_configuration(self, tmp_path, monkeypatch):
-        from paddle_tpu.device import configure_compilation_cache
-
-        cache = str(tmp_path / "xla_cache")
-        monkeypatch.setenv("PADDLE_TPU_COMPILE_CACHE_DIR", cache)
-        assert configure_compilation_cache() == cache
-        assert jax.config.jax_compilation_cache_dir == cache
-        # thresholds dropped so EVERY program persists
-        assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
-        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
-        monkeypatch.delenv("PADDLE_TPU_COMPILE_CACHE_DIR")
-        jax.config.update("jax_compilation_cache_dir", None)
-
-    def test_disabled_without_env(self, monkeypatch):
-        from paddle_tpu.device import configure_compilation_cache
-
-        monkeypatch.delenv("PADDLE_TPU_COMPILE_CACHE_DIR", raising=False)
-        assert configure_compilation_cache() is None
-
-    def test_explicit_dir_wins(self, tmp_path):
-        from paddle_tpu.device import configure_compilation_cache
-
-        cache = str(tmp_path / "explicit")
-        assert configure_compilation_cache(cache) == cache
-        assert jax.config.jax_compilation_cache_dir == cache
-        jax.config.update("jax_compilation_cache_dir", None)
 
 
 class TestExecutorPipelineWiring:

@@ -67,16 +67,12 @@ def _tiny_models():
 def check_parity():
     """Phase 1: paged == dense, tokens exactly, logits within tolerance.
     Returns (ok, detail)."""
-    import jax
-    import jax.numpy as jnp
     import numpy as np
     import paddle_tpu
-    from paddle_tpu.inference.serving import (KVCacheConfig, KVCachePool,
-                                              TokenServeConfig,
+    from paddle_tpu.inference.serving import (TokenServeConfig,
                                               TokenServingEngine,
-                                              dense_greedy_reference)
-    from paddle_tpu.jit.functionalize import get_params
-    from paddle_tpu.text.models.gpt import gpt_decode_fns
+                                              dense_greedy_reference,
+                                              paged_prefill_logits)
 
     model, draft = _tiny_models()
     rng = np.random.RandomState(11)
@@ -84,30 +80,9 @@ def check_parity():
                for n in (4, 9, 21, 33)]
 
     # logit parity: chunked paged prefill vs the Layer model's forward
-    mcfg = model.config
-    fwd = gpt_decode_fns(mcfg)
-    pool = KVCachePool(KVCacheConfig(mcfg.num_layers, mcfg.num_heads,
-                                     mcfg.hidden_size // mcfg.num_heads,
-                                     num_blocks=16, block_size=8))
     prompt = prompts[3]
-    n = len(prompt)
-    pool.ensure(1, n)
-    table = jnp.asarray(pool.block_table(1, 8)[None])
-    pages = pool.pages
-    C = 8
-    chunks = []
-    jfwd = jax.jit(fwd)  # one wrapper: every chunk shares the compile
-    params = get_params(model)
-    for c0 in range(0, n, C):
-        part = prompt[c0:c0 + C]
-        pad = C - len(part)
-        toks = np.concatenate([part, np.zeros(pad, np.int32)])[None]
-        qpos = (c0 + np.arange(C, dtype=np.int32))[None]
-        lens = np.asarray([min(c0 + C, n)], np.int32)
-        logits, pages = jfwd(params, jnp.asarray(toks), jnp.asarray(qpos),
-                             pages, table, jnp.asarray(lens))
-        chunks.append(np.asarray(logits)[0, :C - pad if pad else C])
-    paged_logits = np.concatenate(chunks, axis=0)
+    paged_logits = paged_prefill_logits(model, prompt, chunk=8,
+                                        block_size=8)
     ref_logits = np.asarray(model(
         paddle_tpu.Tensor(prompt[None].astype(np.int64))).numpy())[0]
     max_diff = float(np.max(np.abs(paged_logits - ref_logits)))
