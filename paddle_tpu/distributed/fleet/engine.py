@@ -139,7 +139,17 @@ def apply_optimizer_update(opt, named_params, params, grads, opt_state, lr,
     (``_grouped_adam_update``) — pass ``group_small=False`` when optimizer
     state is dim-sharded (ZeRO): concatenating sharded moments would make
     GSPMD gather/rescatter them every step.
+
+    Everything here (clip, decay, update, master -> resident cast) carries
+    the ``optimizer`` scope in the compiled step.
     """
+    with jax.named_scope("optimizer"):
+        return _optimizer_update(opt, named_params, params, grads, opt_state,
+                                 lr, group_small)
+
+
+def _optimizer_update(opt, named_params, params, grads, opt_state, lr,
+                      group_small):
     if opt._grad_clip is not None:
         from paddle_tpu.nn.clip import ClipGradByGlobalNorm, clip_grads_global_norm_raw
 
@@ -483,8 +493,16 @@ class ParallelTrainStep:
             """The 5-arg CORE step (scan body for run_steps). The
             per-step jitted entry wraps it with the traced
             fingerprint-due argument when fingerprinting is on
-            (``_wrap_fp``)."""
-            def step_core(params, buffers, opt_state, lr, batch):
+            (``_wrap_fp``).
+
+            The function's name is the compiled module's
+            (``jit_train_step``), and the module's name is part of the
+            persistent compile cache's key while ``jax.named_scope``
+            names are not (the key is taken with debug info stripped).
+            It was ``step_core`` before the scopes came: under the old
+            name a cache that held the scopeless program would go on
+            serving it, and a trace of it names no scope."""
+            def train_step(params, buffers, opt_state, lr, batch):
                 inputs, labels = batch
                 (loss, new_buffers), grads = jax.value_and_grad(
                     fwd, has_aux=True)(params, buffers, inputs, labels)
@@ -500,7 +518,7 @@ class ParallelTrainStep:
                         (params, buffers, opt_state))
                 return new_params, new_buffers, new_opt, loss, flags
 
-            return step_core
+            return train_step
 
         self._with_fingerprint = _with_fingerprint
 

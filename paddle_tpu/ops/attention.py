@@ -1020,7 +1020,16 @@ def dot_product_attention(q, k, v, causal=False, bias=None, sp_axis=None,
     and flash_tpu paths (no transpose copies); impls that need
     [b, h, l, d] get a transposed view and transpose back. All selection
     happens at TRACE time: the chosen tier is baked into the compiled
-    program (zero per-step work, zero extra retraces)."""
+    program (zero per-step work, zero extra retraces).
+
+    Whatever tier runs, its operations carry the ``attention`` scope:
+    score space only (QK, mask or bias, softmax, PV), which a trace
+    reduction tells from the projections around it."""
+    with jax.named_scope("attention"):
+        return _dispatch(q, k, v, causal, bias, sp_axis, use_flash, layout)
+
+
+def _dispatch(q, k, v, causal, bias, sp_axis, use_flash, layout):
     from ..profiler.telemetry import get_telemetry
     from . import tier_policy
 

@@ -4,8 +4,9 @@
 by-hand ritual: arm a capture (env knob, ops-server ``POST
 /debug/profile``, or :func:`request_capture`), and the next N step
 boundaries of whatever engine is running are traced with
-``jax.profiler.start_trace``, parsed, joined against the compiled HLO
-already held by ``xla_cost``/``hlo_attrib``, and published as
+``jax.profiler.start_trace``, read from the ``.xplane.pb`` the profiler
+writes, joined against the compiled HLO already held by
+``xla_cost``/``hlo_attrib``, and published as
 
 - ``gauge/profile/{compute,collective,transfer,host_gap}_frac.<entry>``
   — the per-entry step-time decomposition (fractions of window wall,
@@ -13,7 +14,9 @@ already held by ``xla_cost``/``hlo_attrib``, and published as
 - ``gauge/profile/device_total_ms`` / ``gauge/profile/wall_ms`` and
   ``counter/profile/captures``,
 - a structured report (:func:`last_report`) carrying the per-op /
-  per-source-line top-K tables — merged into every ``to_jsonl`` record
+  per-source-line top-K tables and the per-scope table (device time
+  under each ``jax.named_scope`` of the compiled step,
+  ``hlo_attrib.SCOPES``) — merged into every ``to_jsonl`` record
   as a top-level ``"profile"`` object and into the chrome export as
   device-op slices realigned with the PR 5 host spans,
 - ``gauge/bottleneck/<entry>`` verdicts (via ``profiler.bottleneck``).
@@ -246,6 +249,21 @@ def _arm_from_env_locked() -> None:
     _hot = True
 
 
+def _drain_devices() -> None:
+    """Wait for everything dispatched so far. Steps are dispatched ahead
+    of the device and an operation is written to the trace when it ends:
+    without the wait at its start a window holds the tail of the step
+    before it, and without the wait at its end the trace is cut while the
+    window's last steps still run (on XLA:CPU a tiny step's whole window:
+    the capture then holds no operation at all)."""
+    import jax
+
+    try:
+        jax.block_until_ready(jax.live_arrays())
+    except Exception:  # noqa: BLE001 — a failed step is the training
+        pass           # loop's to raise, not the profiler's
+
+
 def _start_locked(entry: str) -> None:
     """Begin the armed capture at this boundary (lock held)."""
     global _armed, _active
@@ -264,6 +282,7 @@ def _start_locked(entry: str) -> None:
         import jax
 
         os.makedirs(cap.logdir, exist_ok=True)
+        _drain_devices()
         jax.profiler.start_trace(cap.logdir)
     except Exception as e:  # noqa: BLE001 — profiling never kills a run
         release_device_trace("device_profile")
@@ -290,11 +309,12 @@ def _stop_locked(cap: _Capture) -> None:
     publish (lock held — boundary calls are engine-loop serialized, and
     parsing one small windowed trace is an explicitly requested cost)."""
     global _active
-    wall_ms = (time.perf_counter() - cap.t_start) * 1e3
     tel = get_telemetry()
     try:
         import jax
 
+        _drain_devices()
+        wall_ms = (time.perf_counter() - cap.t_start) * 1e3
         jax.profiler.stop_trace()
     except Exception as e:  # noqa: BLE001
         tel.counter("profile/capture_failed")

@@ -13,14 +13,18 @@ produces:
   (full mode stores the optimized text the compile already produced; the
   default mode stores the in-hand ``Lowered`` and compiles to text only
   when a profile actually asks — never a second lowering);
-- a **jax.profiler trace** (``.trace.json.gz``) covering a window of
-  steps — per-op device durations in the "XLA Ops" lanes on TPU, or the
-  thunk-executor per-op events the CPU runtime emits (names match the
-  optimized HLO either way).
+- a **jax.profiler trace** covering a window of steps: the
+  ``.xplane.pb`` this jaxlib writes, read through
+  ``jax.profiler.ProfileData`` (per-op device durations in the "XLA Ops"
+  lines of the ``/device:TPU:<n>`` planes, or the thunk-executor per-op
+  events of the CPU runtime, which carry the instruction's name as their
+  ``hlo_op`` stat), or a ``.trace.json.gz`` for post-hoc use.
 
 ``attribute_trace`` joins them into an :class:`AttributionReport`:
 per-op and per-source-line tables, per-category totals (compute /
-collective / h2d-d2h transfer), the host gap (wall time the device sat
+collective / h2d-d2h transfer), per-scope totals (the ``jax.named_scope``
+names of the compiled step, :data:`SCOPES`, read from each instruction's
+``op_name``), the host gap (wall time the device sat
 idle inside the window), and per-entry fractions whose sum is ≤ 1 by
 construction. ``device_profile`` drives it live; the CLI wrapper keeps
 the old script's interface for post-hoc use.
@@ -48,7 +52,7 @@ __all__ = [
     "HloOp", "parse_hlo_text", "categorize_opcode",
     "AttributionReport", "EntryAttribution", "attribute_trace",
     "load_trace", "newest_trace_path", "device_events",
-    "HloRegistry", "hlo_registry", "CATEGORIES",
+    "HloRegistry", "hlo_registry", "CATEGORIES", "SCOPES", "scope_of",
 ]
 
 logger = logging.getLogger("paddle_tpu.profiler")
@@ -56,6 +60,38 @@ logger = logging.getLogger("paddle_tpu.profiler")
 # the closed category vocabulary of the device-side decomposition; the
 # host gap (wall - device busy) is the fourth, computed, category
 CATEGORIES = ("compute", "collective", "transfer")
+
+# the closed vocabulary of ``jax.named_scope`` names inside a compiled
+# train step (text/models/*, ops/attention.py, fleet/engine.py); device
+# time under none of them is booked to ``unscoped``
+SCOPES = ("embed", "self_attn", "attention", "mlp", "head_loss", "optimizer")
+UNSCOPED = "unscoped"
+# what JAX wraps around a scope's name when it transforms the function
+_TRANSFORMS = frozenset(("jvp", "transpose", "vmap", "checkpoint"))
+
+
+def scope_of(op_name: str) -> str:
+    """The innermost :data:`SCOPES` name in an HLO ``op_name`` path.
+
+    A name counts only as a whole component of the path, bare or inside
+    JAX's transform wrappers: ``jit(f)/transpose(jvp(self_attn))/attention/
+    dot_general`` and ``jit(f)/jvp(self_attn/attention)/dot_general`` are
+    both ``attention``; ``jit(attention)`` and ``dot_product_attention``
+    are functions, not scopes."""
+    found, heads, token = UNSCOPED, [], ""
+    for ch in (op_name or "") + "/":
+        if ch not in "()/":
+            token += ch
+            continue
+        if ch == "(":
+            heads.append(token)
+        elif token in SCOPES and all(h in _TRANSFORMS for h in heads):
+            found = token
+        if ch == ")" and heads:
+            heads.pop()
+        token = ""
+    return found
+
 
 _COLLECTIVE_OPCODES = {
     "all-reduce", "all-gather", "all-to-all", "reduce-scatter",
@@ -129,22 +165,72 @@ def parse_hlo_text(text: str) -> Dict[str, HloOp]:
 # -- trace loading ------------------------------------------------------------
 
 def newest_trace_path(logdir: str) -> Optional[str]:
-    paths = sorted(glob.glob(
-        os.path.join(logdir, "plugins", "profile", "*", "*.trace.json.gz")))
-    return paths[-1] if paths else None
+    """The newest capture under ``logdir``: its ``.xplane.pb`` (what the
+    profiler itself writes, every event with its stats), else its
+    ``.trace.json.gz``."""
+    for pattern in ("*.xplane.pb", "*.trace.json.gz"):
+        paths = sorted(glob.glob(
+            os.path.join(logdir, "plugins", "profile", "*", pattern)))
+        if paths:
+            return paths[-1]
+    return None
+
+
+_HLO_LINE_NAME = re.compile(r"^%(\S+) = ")
+
+
+def _load_xplane(path: str) -> dict:
+    """An ``.xplane.pb`` as the ``traceEvents`` dict the JSON loader
+    gives: a process a plane, a thread a line, one complete event for
+    every operation. An operation is named by its HLO instruction: the
+    event's ``hlo_op`` stat where the runtime sets it (XLA:CPU's thunk
+    events), else the head of the HLO line a TPU op event is named by
+    (``%fusion.5 = bf16[...] fusion(...)``). Of the host's planes only
+    operations are kept: the Python tracer's events join nothing."""
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(path)
+    events: List[dict] = []
+    for pid, plane in enumerate(data.planes, start=1):
+        on_device = plane.name.startswith("/device:")
+        events.append({"ph": "M", "name": "process_name", "pid": pid,
+                       "args": {"name": plane.name}})
+        for tid, line in enumerate(plane.lines, start=1):
+            events.append({"ph": "M", "name": "thread_name", "pid": pid,
+                           "tid": tid, "args": {"name": line.name}})
+            for e in line.events:
+                if not on_device and e.name.startswith("$"):
+                    continue  # the Python tracer's frames, the bulk
+                stats = dict(e.stats)
+                op = stats.get("hlo_op")
+                if op is None:
+                    if not on_device:
+                        continue
+                    m = _HLO_LINE_NAME.match(e.name)
+                    op = m.group(1) if m else e.name
+                args = {"hlo_op": str(op)}
+                if "hlo_module" in stats:
+                    args["hlo_module"] = str(stats["hlo_module"])
+                events.append({"ph": "X", "name": str(op), "pid": pid,
+                               "tid": tid, "ts": e.start_ns / 1e3,
+                               "dur": e.duration_ns / 1e3, "args": args})
+    return {"traceEvents": events}
 
 
 def load_trace(path_or_logdir: str) -> Optional[dict]:
-    """The parsed trace JSON, or None (with a warning) on any failure —
-    missing file, truncated gzip, malformed JSON."""
+    """The trace as a ``traceEvents`` dict, or None (with a warning) on
+    any failure — missing file, truncated gzip, malformed JSON or proto."""
     path = path_or_logdir
     if os.path.isdir(path_or_logdir):
         path = newest_trace_path(path_or_logdir)
         if path is None:
-            logger.warning("hlo_attrib: no .trace.json.gz under %s — "
-                           "profiler produced no trace", path_or_logdir)
+            logger.warning("hlo_attrib: no .xplane.pb or .trace.json.gz "
+                           "under %s — profiler produced no trace",
+                           path_or_logdir)
             return None
     try:
+        if path.endswith(".xplane.pb"):
+            return _load_xplane(path)
         opener = gzip.open if path.endswith(".gz") else open
         with opener(path, "rb") as f:
             trace = json.load(f)
@@ -165,7 +251,8 @@ def device_events(trace: dict,
     runtime threads instead), fall back to events whose name matches a
     known HLO instruction name — lane membership wins when lanes exist,
     so a host-side event that happens to shadow an HLO name can never
-    pollute a real device timeline."""
+    pollute a real device timeline. Events read from an ``.xplane.pb``
+    say themselves that they are operations (``args.hlo_op``)."""
     events = trace.get("traceEvents") or []
     procs: Dict[int, str] = {}
     op_lanes = set()
@@ -174,9 +261,12 @@ def device_events(trace: dict,
             continue
         if e.get("name") == "process_name":
             procs[e["pid"]] = str(e.get("args", {}).get("name", ""))
-        elif (e.get("name") == "thread_name"
-              and "XLA Ops" in str(e.get("args", {}).get("name", ""))):
-            op_lanes.add((e["pid"], e.get("tid")))
+        elif e.get("name") == "thread_name":
+            lane = str(e.get("args", {}).get("name", ""))
+            # not "Async XLA Ops": its events span a copy from start to
+            # done, beside the operations that run meanwhile
+            if "XLA Ops" in lane and "Async" not in lane:
+                op_lanes.add((e["pid"], e.get("tid")))
     device_pids = {p for p, n in procs.items()
                    if "TPU" in n or "xla" in n.lower()
                    or "/device" in n.lower()}
@@ -188,7 +278,8 @@ def device_events(trace: dict,
         if lanes:
             if (e.get("pid"), e.get("tid")) in lanes:
                 out.append(e)
-        elif known_names and e.get("name") in known_names:
+        elif "hlo_op" in (e.get("args") or {}) or (
+                known_names and e.get("name") in known_names):
             out.append(e)
     return out
 
@@ -207,6 +298,8 @@ class EntryAttribution:
         default_factory=lambda: {c: 0.0 for c in CATEGORIES})
     by_op: Dict[str, float] = dataclasses.field(default_factory=dict)
     by_line: Dict[str, float] = dataclasses.field(default_factory=dict)
+    scope_ms: Dict[str, float] = dataclasses.field(
+        default_factory=lambda: {s: 0.0 for s in SCOPES + (UNSCOPED,)})
     op_meta: Dict[str, Tuple[str, str, str]] = dataclasses.field(
         default_factory=dict)  # op -> (src, op_name, category)
 
@@ -216,7 +309,19 @@ class EntryAttribution:
         self.category_ms[category] = self.category_ms.get(category, 0.0) + ms
         self.by_op[op] = self.by_op.get(op, 0.0) + ms
         self.by_line[src] = self.by_line.get(src, 0.0) + ms
+        # a fusion is one instruction with one op_name, its root's: its
+        # whole time goes where that says, and is not split
+        self.scope_ms[scope_of(op_name)] += ms
         self.op_meta.setdefault(op, (src, op_name, category))
+
+    def scopes(self) -> List[dict]:
+        """Device time by scope, every scope of the vocabulary and
+        ``unscoped``: the rows sum to ``device_ms``."""
+        denom = max(self.device_ms, 1e-12)
+        return [{"scope": s, "entry": self.entry, "ms": round(ms, 6),
+                 "ms_per_step": round(ms / max(self.steps, 1), 6),
+                 "frac": min(round(ms / denom, 6), 1.0)}
+                for s, ms in self.scope_ms.items()]
 
     def top_ops(self, k: int = 10) -> List[dict]:
         rows = sorted(self.by_op.items(), key=lambda kv: -kv[1])[:k]
@@ -327,6 +432,8 @@ class AttributionReport:
                                     for c, v in a.category_ms.items()},
                     "fractions": self.fractions(e)}
                 for e, a in self.entries.items()},
+            "scopes": [r for a in self.entries.values()
+                       for r in a.scopes()],
             "top_ops": self.top_ops(top_k),
             "top_lines": sorted(
                 (r for a in self.entries.values()

@@ -176,9 +176,12 @@ def tracked_jit(fn=None, *, name: Optional[str] = None,
         # goodput: the same region compile_ms times — an unseen
         # signature's triggering call is (re)trace + XLA compile, badput
         # the wall-clock ledger must own (nested under the step's claim)
-        from . import goodput
+        from . import goodput, spans
 
-        with goodput.activity("compile"):
+        # the span puts ``pt.compile`` on a profiler trace: a device gap
+        # under a retrace reads as one
+        with goodput.activity("compile"), \
+                spans.span("compile", cat="compile"):
             out = jitted(*args, **kwargs)  # raises ⇒ signature NOT committed
         tracker.commit(sig)
         # the triggering call's wall time ≈ trace+compile (+1 run):

@@ -26,6 +26,16 @@ and carried no hierarchy. This module is the structured replacement:
   with the event history explaining what the process was *doing*, not
   just where its threads were parked.
 
+- **Profiler annotations** — every span also opens a
+  ``jax.profiler.TraceAnnotation`` named ``pt.<span name>`` (``pt.step``,
+  ``pt.h2d``, ``pt.compute``, ``pt.compile``, the serve engines' spans
+  too), so a trace taken by ``jax.profiler`` shows what the program's
+  host code was doing on the device trace's own clock, and an idle gap of
+  the device can be put down to the batch's ``device_put`` or to the
+  jitted call. A span constructed with a step number hands it to the
+  annotation as ``step=``. With no trace running an annotation is a flag
+  test. This class is the only place a ``pt.*`` annotation is opened.
+
 Span enter/exit must stay OUTSIDE compiled regions (host code only):
 under a jit trace a span would measure trace time once and then vanish
 from the compiled program — the same class of mistake tpu-lint R8 flags
@@ -39,6 +49,8 @@ import threading
 import time
 from collections import deque
 from typing import List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Span", "span", "current_span", "FlightRecorder", "flight_recorder",
@@ -265,7 +277,7 @@ class Span:
     """
 
     __slots__ = ("name", "cat", "step", "span_id", "parent_id", "tid",
-                 "ts_us", "dur_us", "_t0")
+                 "ts_us", "dur_us", "_t0", "_annotation")
 
     def __init__(self, name: str, cat: str = "host",
                  step: Optional[int] = None):
@@ -277,6 +289,10 @@ class Span:
         self.tid = None
         self.ts_us = None
         self.dur_us = None
+        # its own step only: an inherited one would stamp every h2d and
+        # compute with the number their step span already carries
+        self._annotation = (TraceAnnotation("pt." + name) if step is None
+                            else TraceAnnotation("pt." + name, step=step))
 
     def __enter__(self) -> "Span":
         st = _stack()
@@ -291,9 +307,11 @@ class Span:
         self.ts_us = self._t0 * 1e6
         _flight.record("B", self.name, self.cat, self.ts_us, 0.0, self.tid,
                        self.span_id, self.parent_id, self.step)
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        self._annotation.__exit__(*exc)
         t1 = time.perf_counter()
         self.dur_us = (t1 - self._t0) * 1e6
         st = _stack()

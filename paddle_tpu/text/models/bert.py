@@ -102,9 +102,11 @@ class BertLayer(nn.Layer):
         self.dropout = nn.Dropout(config.hidden_dropout)
 
     def forward(self, x, attn_bias=None):
-        x = self.ln1(x + self.attn(x, attn_bias))
-        y = self.fc2(F.gelu(self.fc1(x), approximate=True))
-        return self.ln2(x + self.dropout(y))
+        with jax.named_scope("self_attn"):
+            x = self.ln1(x + self.attn(x, attn_bias))
+        with jax.named_scope("mlp"):
+            y = self.fc2(F.gelu(self.fc1(x), approximate=True))
+            return self.ln2(x + self.dropout(y))
 
 
 class BertEmbeddings(nn.Layer):
@@ -127,12 +129,13 @@ class BertEmbeddings(nn.Layer):
         from paddle_tpu.tensor import arange, zeros_like
 
         b, l = input_ids.shape
-        pos = arange(l, dtype="int64")
-        if token_type_ids is None:
-            token_type_ids = zeros_like(input_ids)
-        x = self.word(input_ids) + self.position(pos) + \
-            self.token_type(token_type_ids)
-        return self.dropout(self.ln(x))
+        with jax.named_scope("embed"):
+            pos = arange(l, dtype="int64")
+            if token_type_ids is None:
+                token_type_ids = zeros_like(input_ids)
+            x = self.word(input_ids) + self.position(pos) + \
+                self.token_type(token_type_ids)
+            return self.dropout(self.ln(x))
 
 
 class BertModel(nn.Layer):
@@ -175,10 +178,12 @@ class BertForPretraining(nn.Layer):
 
     def forward(self, input_ids, token_type_ids=None, attention_mask=None):
         seq, pooled = self.bert(input_ids, token_type_ids, attention_mask)
-        x = self.mlm_ln(F.gelu(self.mlm_transform(seq), approximate=True))
-        # decoder tied to word embeddings
-        logits = F.linear(x, apply_op(lambda w: w.T, self.bert.embeddings.word.weight))
-        nsp_logits = self.nsp(pooled)
+        with jax.named_scope("head_loss"):
+            x = self.mlm_ln(F.gelu(self.mlm_transform(seq), approximate=True))
+            # decoder tied to word embeddings
+            logits = F.linear(x, apply_op(
+                lambda w: w.T, self.bert.embeddings.word.weight))
+            nsp_logits = self.nsp(pooled)
         return logits, nsp_logits
 
     def loss_fn(self, outputs, mlm_labels, nsp_labels=None):
@@ -195,9 +200,10 @@ class BertForPretraining(nn.Layer):
             picked = jnp.take_along_axis(logp, lab_safe[:, None], axis=-1)[:, 0]
             return -(picked * valid).sum() / jnp.maximum(valid.sum(), 1)
 
-        loss = apply_op(masked_ce, logits, mlm_labels)
-        if nsp_labels is not None:
-            loss = loss + F.cross_entropy(nsp_logits, nsp_labels)
+        with jax.named_scope("head_loss"):
+            loss = apply_op(masked_ce, logits, mlm_labels)
+            if nsp_labels is not None:
+                loss = loss + F.cross_entropy(nsp_logits, nsp_labels)
         return loss
 
 

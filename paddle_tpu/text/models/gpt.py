@@ -134,8 +134,10 @@ class GPTBlock(nn.Layer):
         self.mlp = GPTMLP(config)
 
     def forward(self, x, attn_mask=None):
-        x = x + self.attn(self.ln_1(x), attn_mask)
-        x = x + self.mlp(self.ln_2(x))
+        with jax.named_scope("self_attn"):
+            x = x + self.attn(self.ln_1(x), attn_mask)
+        with jax.named_scope("mlp"):
+            x = x + self.mlp(self.ln_2(x))
         return x
 
 
@@ -160,12 +162,16 @@ class GPT(nn.Layer):
         from paddle_tpu.tensor import arange
 
         with manual_ln_scope(self.config.manual_layer_norm):
-            pos = arange(l, dtype="int64")
-            x = self.wte(input_ids) + self.wpe(pos)
-            x = self.drop(x)
+            with jax.named_scope("embed"):
+                pos = arange(l, dtype="int64")
+                x = self.wte(input_ids) + self.wpe(pos)
+                x = self.drop(x)
             for block in self.h:
                 x = block(x, attn_mask)
-            return self.ln_f(x)
+            # the final LayerNorm belongs to the head: its backward is
+            # fused with the head's dh matmul
+            with jax.named_scope("head_loss"):
+                return self.ln_f(x)
 
 
 class GPTForCausalLM(nn.Layer):
@@ -178,6 +184,10 @@ class GPTForCausalLM(nn.Layer):
 
     def forward(self, input_ids, labels=None):
         h = self.gpt(input_ids)
+        with jax.named_scope("head_loss"):
+            return self._head(h, labels)
+
+    def _head(self, h, labels):
         if labels is not None and self.config.fused_head_ce:
             from paddle_tpu.nn.functional.loss import fused_linear_hard_ce
 
@@ -204,9 +214,10 @@ class GPTForCausalLM(nn.Layer):
         return logits
 
     def loss_fn(self, logits, labels):
-        return F.cross_entropy(
-            logits.reshape([-1, self.config.vocab_size]), labels.reshape([-1])
-        )
+        with jax.named_scope("head_loss"):
+            return F.cross_entropy(
+                logits.reshape([-1, self.config.vocab_size]),
+                labels.reshape([-1]))
 
 
 def _transposed(w: Tensor) -> Tensor:
