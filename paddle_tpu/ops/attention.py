@@ -45,10 +45,11 @@ __all__ = [
 _IMPL = os.environ.get("PADDLE_TPU_ATTENTION", "auto")
 # beyond these lengths the materialized scores dominate HBM; stream
 # instead. Two thresholds (r5): CAUSAL unbiased attention runs q-chunked
-# (_causal_chunked_fwd_impl — fully-masked blocks never computed, ~0.53·L²
-# footprint) and measured 46.5k tok/s at GPT-small L=8192 b=1 vs 27.5k on
-# flash_tpu + recompute, so its auto threshold is 8192; everything else
-# materializes the full [b,h,L,L] scores and keeps the stricter 4096.
+# (_q_chunks — fully-masked blocks never computed, ~0.53·L² footprint) and
+# measured 46.5k tok/s at GPT-small L=8192 b=1 vs 27.5k on flash_tpu +
+# recompute, so its auto threshold is 8192; everything else computes (and
+# saves for its backward) the full [b,h,L,L] scores, chunk by chunk, and
+# keeps the stricter 4096.
 _XLA_MAX_SEQ = int(os.environ.get("PADDLE_TPU_ATTENTION_MAX_SEQ", "4096"))
 _XLA_MAX_SEQ_CAUSAL = int(os.environ.get(
     "PADDLE_TPU_ATTENTION_MAX_SEQ_CAUSAL", "8192"))
@@ -696,9 +697,16 @@ def paged_attention(q, k_pages, v_pages, block_tables, q_positions,
 # ---------------------------------------------------------------------------
 # Materialized XLA attention (TPU fast path for moderate sequence lengths)
 # ---------------------------------------------------------------------------
-# minimum causal q-chunk rows (sweepable; 128 measured optimum on v5e)
+# One chunk body serves every call, causal or not, biased or not: a call
+# is cut into q-chunks by `_q_chunks`; a chunk is query rows [lo, hi)
+# against keys [0, ub), an optional static tril mask and an optional bias
+# slice (`_chunk_logits`), then exp, row sum, PV and the divide on the
+# output (`_weights_pv`; inline with bf16 row statistics for the causal
+# unbiased call, `_bf16_row_stats`).
+#
+# minimum q-chunk rows (sweepable; 128 measured optimum on v5e)
 _CAUSAL_CHUNK = int(os.environ.get("PADDLE_TPU_ATTN_MIN_CHUNK", "128"))
-# max causal q-chunks (sweepable: more chunks skip more upper-triangle work
+# max q-chunks (sweepable: more causal chunks skip more upper-triangle work
 # but emit more ops). Together with the 128-row minimum the default of 32
 # gives the measured v5e optima at both ends: L=1024 -> c=128 (8 chunks;
 # c=256 measured -6%) and L=8192 -> c=256 (32 chunks; +27% over the old
@@ -721,8 +729,9 @@ _SCORE_BF16 = os.environ.get("PADDLE_TPU_ATTN_SCORE_BF16", "1") == "1"
 # same ~43 TFLOP/s emitter ceiling either way (every orientation rewrite —
 # 'bhdk' outputs, pre-transposed operands, optimization barriers — was
 # canonicalized by XLA to the identical dot and measured identical).
-# Kept as an opt-in: it halves residual memory bookkeeping for long-L
-# sweeps and documents the measured negative result.
+# Kept as an opt-in for the causal unbiased call: it halves residual
+# memory bookkeeping for long-L sweeps and documents the measured negative
+# result.
 _MANUAL_ATTN_VJP = os.environ.get("PADDLE_TPU_ATTN_MANUAL_VJP", "0") == "1"
 
 
@@ -731,40 +740,27 @@ def _einsum_eqs(blhd: bool):
             else ("bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd"))
 
 
-def _attention_core(q, k, v, mask, bias=None, blhd=False):
-    """One materialized softmax(QKᵀ)V block.
-
-    ``blhd``: q/k/v are [b, l, h, d] (einsum contracts without pre-transposed
-    operands — the [b,h,l,d] transposes are real HBM copies the model can
-    skip); otherwise [b, h, l, d]. ``mask`` is [Lq, Lk] bool or None. For
-    bf16/f16 inputs the centered logits and probabilities round-trip through
-    the input dtype — the exp input IS materialized, and halving that O(L²)
-    tensor's bytes is a real HBM saving (see xla_attention docstring)."""
-    d = q.shape[-1]
-    eq = _einsum_eqs(blhd)
-    s = jnp.einsum(eq[0], q, k,
-                   preferred_element_type=jnp.float32) * (1.0 / math.sqrt(d))
-    if bias is not None:
-        s = s + bias
-    if mask is not None:
-        s = jnp.where(mask, s, _NEG_INF)
-    m = jax.lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True))
-    if jnp.issubdtype(q.dtype, jnp.floating) and q.dtype != jnp.float32:
-        e = jnp.exp((s - m).astype(q.dtype).astype(jnp.float32))
-    else:
-        e = jnp.exp(s - m)
-    p = (e / jnp.maximum(e.sum(axis=-1, keepdims=True), 1e-30)).astype(q.dtype)
-    return jnp.einsum(eq[1], p, v)
-
-
-def _causal_chunk_size(Lq: int):
-    """Chunk size for causal q-chunking, or None when no exact chunking
-    exists (c must divide Lq — a truncated concat would silently drop query
-    rows)."""
+def _q_chunk_size(Lq: int):
+    """Rows of a q-chunk, or None when no exact chunking exists (c must
+    divide Lq — a truncated concat would silently drop query rows)."""
     c = max(_CAUSAL_CHUNK, Lq // max(_CAUSAL_MAX_CHUNKS, 1))
     if Lq % c != 0 or Lq // c < 2:
         return None
     return c
+
+
+def _q_chunks(Lq: int, Lk: int, causal: bool):
+    """The call's chunks as (lo, hi, ub): query rows [lo, hi) against keys
+    [0, ub). Self-attention of a length `_q_chunk_size` divides is cut
+    into chunks of that many rows: a causal chunk stops at its diagonal
+    (ub = hi: the fully-masked upper-triangle blocks are never computed),
+    a non-causal one sees every key. Everything else (Lq != Lk, the unit
+    tests' L = 16) is one chunk over the whole score rectangle."""
+    c = _q_chunk_size(Lq) if Lq == Lk else None
+    if c is None:
+        return ((0, Lq, Lk),)
+    return tuple((lo, lo + c, lo + c if causal else Lk)
+                 for lo in range(0, Lq, c))
 
 
 # backward einsum equations per layout: dP ('dO,V->P-shape'), dq
@@ -783,12 +779,21 @@ def _inv_rows(inv, blhd):
     return inv.transpose(0, 2, 1)[..., None] if blhd else inv[..., None]
 
 
-def _chunk_e(q, k, i, c, blhd, m=None):
-    """exp weights of causal chunk i: e = exp(s − max(s)), s = scaled QKᵀ
-    under the chunk's static tril mask. Shared by forward and (remat mode)
-    backward — with the saved per-chunk max passed as ``m`` the recomputed
-    values are BITWISE the forward's (same ops, same operands). Returns
-    (e, m, used_sdt)."""
+def _bf16_row_stats(causal: bool, bias) -> bool:
+    """The causal unbiased call keeps autodiff's backward of the chunk's
+    tail, with the weights' cotangent and 1/l rounded to the input dtype:
+    the program GPT-2 345M was tuned and accepted with, pinned by
+    tests/attention_fixtures. Every other call takes `_weights_pv`."""
+    return causal and bias is None
+
+
+def _chunk_logits(q, k, chunk, blhd, causal, bias=None, m=None):
+    """Centred logits of one chunk, as f32: x = s − max(s), s = scaled QKᵀ
+    (+ bias) under the chunk's static tril mask when causal. With the
+    saved per-chunk max passed as ``m`` the values are BITWISE the
+    forward's (same ops, same operands). ``bias`` broadcasts against
+    [b, h, Lq, Lk] and is sliced to the chunk here. Returns (x, m)."""
+    lo, hi, ub = chunk
     axis_l = 1 if blhd else 2
     sl = functools.partial(jax.lax.slice_in_dim, axis=axis_l)
     d = q.shape[-1]
@@ -797,27 +802,43 @@ def _chunk_e(q, k, i, c, blhd, m=None):
     bf = (jnp.issubdtype(q.dtype, jnp.floating) and q.dtype != jnp.float32)
     sdt = q.dtype if (_SCORE_BF16 and bf) else jnp.float32
     neg = jnp.asarray(_NEG_INF if sdt == jnp.float32 else -3e38, sdt)
-    ub = (i + 1) * c
-    qi = sl(q, i * c, ub) * jnp.asarray(scale, q.dtype)
+    qi = sl(q, lo, hi) * jnp.asarray(scale, q.dtype)
     ki = sl(k, 0, ub)
-    s = jnp.einsum(eq[0], qi, ki, preferred_element_type=sdt)
-    mask = jnp.tril(jnp.ones((c, ub), bool), k=ub - c)
-    s = jnp.where(mask, s, neg)
+    if bias is None:
+        s = jnp.einsum(eq[0], qi, ki, preferred_element_type=sdt)
+    else:
+        # the bias joins the f32-accumulated scores inside the QK fusion,
+        # before they are rounded to their storage dtype; the row maximum
+        # below is taken after it (a −1e9 padding column never sets it)
+        bias = bias.reshape((1,) * (4 - bias.ndim) + bias.shape)
+        if bias.shape[2] != 1:
+            bias = jax.lax.slice_in_dim(bias, lo, hi, axis=2)
+        if bias.shape[3] != 1:
+            bias = jax.lax.slice_in_dim(bias, 0, ub, axis=3)
+        s = (jnp.einsum(eq[0], qi, ki, preferred_element_type=jnp.float32)
+             + bias).astype(sdt)
+    if causal:
+        # top-left aligned (k_pos <= q_pos), matching blockwise/flash so
+        # the dispatch tiers agree for Lq != Lk
+        mask = jnp.tril(jnp.ones((hi - lo, ub), bool), k=lo)
+        s = jnp.where(mask, s, neg)
     if m is None:
         m = jax.lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True))
-    # the UNNORMALIZED probabilities are MATERIALIZED in the input dtype
-    # (exp computed in f32 per-element, rounded on store): for bf16
-    # models this halves the O(L²) exp tensor's bytes in fwd AND in the
-    # saved residual the backward re-reads — values in (0, 1], safe in
-    # bf16, and the f32-accumulated row sum below normalizes the same
-    # bf16 weights the PV einsum consumes (profiled: the f32 exp store
-    # was 25 ms/step of divide_subtract fusions)
     if sdt != jnp.float32:  # honors the PADDLE_TPU_ATTN_SCORE_BF16 opt-out
-        e = jnp.exp((s - m).astype(q.dtype).astype(jnp.float32)
-                    ).astype(q.dtype)
-    else:
-        e = jnp.exp(s - m)
-    return e, m
+        return (s - m).astype(q.dtype).astype(jnp.float32), m
+    return s - m, m
+
+
+def _chunk_e(q, k, chunk, blhd, causal, m=None):
+    """exp weights of one unbiased chunk, UNNORMALIZED and MATERIALIZED in
+    the input dtype (exp computed in f32 per-element, rounded on store):
+    for bf16 models this halves the O(L²) exp tensor's bytes in fwd AND in
+    the saved residual the backward re-reads — values in (0, 1], safe in
+    bf16, and the f32-accumulated row sum normalizes the same bf16 weights
+    the PV einsum consumes (profiled: the f32 exp store was 25 ms/step of
+    divide_subtract fusions). Returns (e, m)."""
+    x, m = _chunk_logits(q, k, chunk, blhd, causal, m=m)
+    return jnp.exp(x).astype(q.dtype), m
 
 
 def _remat_e() -> bool:
@@ -831,69 +852,163 @@ def _remat_e() -> bool:
     return os.environ.get("PADDLE_TPU_ATTN_REMAT_E", "1") == "1"
 
 
-def _causal_chunked_fwd_impl(q, k, v, blhd: bool):
-    """Forward pass; returns (out, residuals per chunk). Residual slot 4
-    holds the exp weights (save-e mode) or their per-chunk row maxima
-    (remat mode, `_remat_e`)."""
+def _weights_pv_impl(x, v, blhd, dtype):
+    e = jnp.exp(x).astype(dtype)
+    inv = 1.0 / jnp.maximum(e.sum(axis=-1, dtype=jnp.float32), 1e-30)
+    o = jnp.einsum(_einsum_eqs(blhd)[1], e, v,
+                   preferred_element_type=jnp.float32)
+    return o * _inv_rows(inv, blhd), (e, v, o, inv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _weights_pv(x, v, blhd, dtype):
+    """A chunk's rows of softmax(x)·v, in f32, from its centred logits
+    ``x`` and the (centred, `_centred`) values: the UNNORMALIZED weights
+    e = exp(x), rounded to ``dtype``, feed the PV matmul, their f32 row
+    sum l is taken over the rounded values, and the 1/l multiply runs on
+    the [.., c, d] output.
+
+    The backward is written out: dS = e ⊙ (dP − c), with dO rounded once
+    for both the dP matmul and c, and c taken from the forward's own f32
+    PV sums, so that a row of dS sums to zero (why that matters, and why
+    the values come centred: `_centred`)."""
+    return _weights_pv_impl(x, v, blhd, dtype)[0]
+
+
+def _weights_pv_fwd(x, v, blhd, dtype):
+    return _weights_pv_impl(x, v, blhd, dtype)
+
+
+def _weights_pv_bwd(blhd, dtype, res, g):
+    e, v, o, inv = res
+    dP_eq, _, _, dv_eq, _ = _BWD_EQS[blhd]
+    rows = lambda t: t.transpose(0, 2, 1) if blhd else t  # -> [b, h, q]
+    dO = (g * _inv_rows(inv, blhd)).astype(dtype)
+    dP = jnp.einsum(dP_eq, dO, v, preferred_element_type=jnp.float32)
+    c = rows((dO.astype(jnp.float32) * o).sum(axis=-1)) * inv
+    # masked positions need no re-masking: e is exactly 0 there
+    return (e.astype(jnp.float32) * (dP - c[..., None]),
+            jnp.einsum(dv_eq, e, dO))
+
+
+_weights_pv.defvjp(_weights_pv_fwd, _weights_pv_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _centred(v, axis):
+    """(v − v̄, v̄): the values as their distance from their mean over the
+    keys, rounded to their own dtype, and that mean in f32. Attention's
+    output is v̄ + Σ softmax·(v − v̄), the same number, and its gradient
+    for the values is the plain one (the two ways v̄ enters cancel), which
+    is what the backward hands on.
+
+    Why: dS = e ⊙ (dP − c), where c is the row's weighted mean of dP, and
+    a row of dS sums to zero. Where a row's values are alike (BERT's deep
+    post-LN layers at initialisation: near-uniform attention has averaged
+    the tokens together) dP = dO·v is nearly constant along the row and
+    dS is what is left of a cancellation. Whatever a one-pass backward
+    takes c from (g·o in autodiff, dO·o in `_weights_pv`) has to agree
+    with the dP matmul to far better than a bf16 rounding of the whole of
+    dP, and on the v5e it does not: the rows stop summing to zero, the key
+    bias's gradient, zero in exact arithmetic, carries 15 times the noise
+    it has behind a softmax normalised in score space, and Adam turns
+    that into whole steps (BERT-large: `change_norm_gap` 0.11-0.18 on
+    `qkv_b[21..23]` against 0.006; PERF.md section 6 "PR 29"). With the
+    mean taken off the values first, dP and c are both small where the
+    values are alike, and nothing large has to cancel."""
+    v32 = v.astype(jnp.float32)
+    vbar = v32.mean(axis=axis, keepdims=True)
+    return (v32 - vbar).astype(v.dtype), vbar
+
+
+def _centred_fwd(v, axis):
+    return _centred(v, axis), None
+
+
+def _centred_bwd(axis, _, ct):
+    return (ct[0],)
+
+
+_centred.defvjp(_centred_fwd, _centred_bwd)
+
+
+def _chunk_attend(q, k, vc, vbar, bias, chunk, blhd, causal):
+    """One chunk's rows of the output, from the centred values
+    (`_centred`): the 1/sqrt(d) scale folds into the [.., c, d] query
+    chunk, not the score tensor, and the normalisation runs on the
+    [.., c, d] output instead of the [.., c, L] scores — one full O(L²)
+    elementwise pass (read + write) removed per chunk (flash's trick,
+    expressed at the XLA level)."""
     axis_l = 1 if blhd else 2
-    Lq = q.shape[axis_l]
-    c = _causal_chunk_size(Lq)
-    n = Lq // c
+    x, _ = _chunk_logits(q, k, chunk, blhd, causal, bias)
+    vi = jax.lax.slice_in_dim(vc, 0, chunk[2], axis=axis_l)
+    return (_weights_pv(x, vi, blhd, q.dtype) + vbar).astype(q.dtype)
+
+
+# the chunks of one call, and of every layer after the first, are traced
+# once a shape: an unrolled BERT-large (96 chunks, forward and backward)
+# cost 3.9 s of set-up in tracing and lowering, 1.9 s with this (PERF.md
+# section 6 "PR 29")
+_chunk_attend_jit = jax.jit(_chunk_attend,
+                            static_argnames=("chunk", "blhd", "causal"))
+
+
+def _chunked_fwd_impl(q, k, v, blhd: bool, causal: bool, bias=None):
+    """Forward pass; returns (out, residuals per chunk, for
+    `_causal_chunked_bwd`, where `_bf16_row_stats`). Residual slot 4 holds
+    the exp weights (save-e mode) or their per-chunk row maxima (remat
+    mode, `_remat_e`)."""
+    from ..profiler.telemetry import get_telemetry
+
+    axis_l = 1 if blhd else 2
+    chunks = _q_chunks(q.shape[axis_l], k.shape[axis_l], causal)
+    # trace-time fact, like attn/calls: the chunks this call emits
+    get_telemetry().counter(
+        "attn/xla_chunks." + ("c" if causal else "f"), len(chunks))
+    join = lambda outs: (jnp.concatenate(outs, axis=axis_l)
+                         if len(outs) > 1 else outs[0])
+    if not _bf16_row_stats(causal, bias):
+        vc, vbar = _centred(v, axis_l)
+        return join([_chunk_attend_jit(q, k, vc, vbar, bias, chunk=chunk,
+                                       blhd=blhd, causal=causal)
+                     for chunk in chunks]), None
     sl = functools.partial(jax.lax.slice_in_dim, axis=axis_l)
     eq = _einsum_eqs(blhd)
     remat = _remat_e()
     outs, aux, invs = [], [], []
-    for i in range(n):
-        e, m = _chunk_e(q, k, i, c, blhd)
-        vi = sl(v, 0, (i + 1) * c)
+    for chunk in chunks:
+        e, m = _chunk_e(q, k, chunk, blhd, causal)
+        vi = sl(v, 0, chunk[2])
         l_sum = jnp.maximum(e.sum(axis=-1, dtype=jnp.float32), 1e-30)
         o = jnp.einsum(eq[1], e.astype(q.dtype), vi)
         inv = (1.0 / l_sum).astype(q.dtype)
         outs.append(o * _inv_rows(inv, blhd))
         aux.append(m if remat else e)
         invs.append(inv)
-    out = jnp.concatenate(outs, axis=axis_l)
+    out = join(outs)
     return out, (q, k, v, out, tuple(aux), tuple(invs))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _causal_chunked(q, k, v, blhd: bool):
-    """Causal self-attention, q-chunked: chunk i attends to keys [0, (i+1)·c)
-    under a static top-left tril mask — upper-triangle blocks are never
-    computed (~45% of attention compute+bandwidth at 8 chunks).
-
-    TPU-first structure (profile-driven, v5e):
-    - the softmax NORMALIZATION is deferred until after the PV matmul: the
-      unnormalized exp weights feed the MXU and the divide runs on the
-      [.., c, d] output instead of the [.., c, L] score tensor — one full
-      O(L²) elementwise pass (read+write) removed per chunk (flash's trick,
-      expressed at the XLA level);
-    - the 1/sqrt(d) scale folds into the [.., c, d] query chunk, not the
-      score tensor;
-    - einsums contract the native [b, l, h, d] layout directly (blhd=True):
-      no [b,h,l,d] transpose copies;
-    - the BACKWARD is hand-written (custom_vjp, `_causal_chunked_bwd`):
-      autodiff's transposed einsums pick degenerate per-head layouts on TPU
-      (profiled 18 ms/step of ~1%-MFU dots + 13 ms of relayout copies at
-      GPT-2 345M). The manual rule keeps every backward contraction in the
-      same layout family as the forward and folds the 1/l normalization
-      into the [.., c, d] dO chunk (flash's backward trick at the XLA
-      level), so no O(L²) divide pass exists in either direction.
-    """
-    out, _ = _causal_chunked_fwd_impl(q, k, v, blhd)
+    """Causal unbiased self-attention through `_chunked_fwd_impl` with a
+    hand-written backward (`_causal_chunked_bwd`), the opt-in of
+    ``PADDLE_TPU_ATTN_MANUAL_VJP``: every backward contraction stays in
+    the forward's layout family and the 1/l normalization folds into the
+    [.., c, d] dO chunk (flash's backward trick at the XLA level), so no
+    O(L²) divide pass exists in either direction."""
+    out, _ = _chunked_fwd_impl(q, k, v, blhd, True)
     return out
 
 
 def _causal_chunked_fwd(q, k, v, blhd):
-    return _causal_chunked_fwd_impl(q, k, v, blhd)
+    return _chunked_fwd_impl(q, k, v, blhd, True)
 
 
 def _causal_chunked_bwd(blhd, res, g):
     q, k, v, out, aux, invs = res
     axis_l = 1 if blhd else 2
     Lq = q.shape[axis_l]
-    c = _causal_chunk_size(Lq)
-    n = Lq // c
     sl = functools.partial(jax.lax.slice_in_dim, axis=axis_l)
     d = q.shape[-1]
     scale = jnp.asarray(1.0 / math.sqrt(d), q.dtype)
@@ -901,14 +1016,14 @@ def _causal_chunked_bwd(blhd, res, g):
     remat = _remat_e()
 
     dqs, dks, dvs = [], [], []
-    for i in range(n):
-        ub = (i + 1) * c
-        qi = sl(q, i * c, ub)
+    for i, chunk in enumerate(_q_chunks(Lq, Lq, True)):
+        lo, hi, ub = chunk
+        qi = sl(q, lo, hi)
         ki, vi = sl(k, 0, ub), sl(v, 0, ub)
-        gi = sl(g, i * c, ub)
-        oi = sl(out, i * c, ub)
+        gi = sl(g, lo, hi)
+        oi = sl(out, lo, hi)
         if remat:  # aux holds the chunk maxima; e recomputed bitwise
-            e, _ = _chunk_e(q, k, i, c, blhd, m=aux[i])
+            e, _ = _chunk_e(q, k, chunk, blhd, True, m=aux[i])
         else:
             e = aux[i]
         inv = invs[i]
@@ -944,39 +1059,42 @@ _causal_chunked.defvjp(_causal_chunked_fwd, _causal_chunked_bwd)
 
 
 def xla_attention(q, k, v, causal=False, bias=None, layout="bhld"):
-    """softmax(QKᵀ)V with the [Lq, Lk] scores materialized (XLA-level).
+    """softmax(QKᵀ + bias)V with the [Lq, Lk] scores materialized
+    (XLA-level), one q-chunked body for every call (`_chunked_fwd_impl`).
 
-    TPU-first details (profile-driven on v5e / GPT-2 345M, 12.9k→53k
-    tok/s/chip end-to-end vs the scan-based blockwise path):
+    TPU-first details (profile-driven on v5e: GPT-2 345M 12.9k→53k
+    tok/s/chip end-to-end vs the scan-based blockwise path; BERT-large's
+    biased non-causal call, PERF.md section 6 "PR 29"):
     - scores ACCUMULATE in f32 on the MXU regardless of storage dtype; for
       bf16/f16 inputs the stored scores, centered logits, and unnormalized
       probabilities round-trip through the input dtype by default
       (``PADDLE_TPU_ATTN_SCORE_BF16=0`` opts back into f32 storage) —
       softmax cancels the max shift exactly, so this is numerically ~1 ulp
       of bf16 either way while halving the O(L²) HBM bytes;
-    - **causal** self-attention runs q-chunked (``_causal_chunked``): chunk
-      i only matmuls keys ≤ its diagonal, skipping the fully-masked
-      upper-triangle blocks (~45% of attention compute/bandwidth at 8
-      chunks), and softmax normalization is deferred until after the PV
-      matmul;
+    - self-attention runs q-chunked (`_q_chunks`): a **causal** chunk only
+      matmuls keys ≤ its diagonal, skipping the fully-masked upper-triangle
+      blocks (~45% of attention compute/bandwidth at 8 chunks); a
+      non-causal chunk sees every key; cross-attention and lengths with no
+      exact chunking run the same body as one chunk;
+    - ``bias`` (anything that broadcasts against [b, h, Lq, Lk], under
+      either layout) is added to the f32-accumulated scores inside the
+      chunk, and the row maximum is taken after it;
+    - softmax normalization is deferred until after the PV matmul: the
+      divide runs on the [.., c, d] output, never in score space;
     - ``layout='blhd'`` contracts [b, l, h, d] operands directly, letting
       the model skip the four [b,h,l,d] transpose copies per layer.
+    The causal unbiased call's backward is autodiff of that forward (or
+    `_causal_chunked_bwd` under ``PADDLE_TPU_ATTN_MANUAL_VJP=1``); every
+    other call sees its values centred on their mean over the keys
+    (`_centred`) and takes a hand-written rule for the chunk's tail
+    (`_weights_pv`), so that the rows of dS sum to zero in bf16 too.
     """
     blhd = layout == "blhd"
     axis_l = 1 if blhd else 2
-    Lq, Lk = q.shape[axis_l], k.shape[axis_l]
-    if (causal and bias is None and Lq == Lk
-            and _causal_chunk_size(Lq) is not None):
-        # the chunk-count cap keeps the emitted program small
-        if _MANUAL_ATTN_VJP:
-            return _causal_chunked(q, k, v, blhd)
-        return _causal_chunked_fwd_impl(q, k, v, blhd)[0]
-    mask = jnp.tril(jnp.ones((Lq, Lk), bool)) if causal else None
-    # causal mask is top-left aligned (k_pos <= q_pos), matching
-    # blockwise/flash so the dispatch tiers agree for Lq != Lk
-    if blhd and bias is not None:
-        raise NotImplementedError("bias requires layout='bhld'")
-    return _attention_core(q, k, v, mask, bias, blhd)
+    if (_MANUAL_ATTN_VJP and causal and bias is None
+            and len(_q_chunks(q.shape[axis_l], k.shape[axis_l], True)) > 1):
+        return _causal_chunked(q, k, v, blhd)
+    return _chunked_fwd_impl(q, k, v, blhd, causal, bias)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -1017,10 +1135,12 @@ def dot_product_attention(q, k, v, causal=False, bias=None, sp_axis=None,
     heuristic > blockwise fallback.
 
     ``layout='blhd'`` passes [b, l, h, d] operands straight into the XLA
-    and flash_tpu paths (no transpose copies); impls that need
-    [b, h, l, d] get a transposed view and transpose back. All selection
-    happens at TRACE time: the chosen tier is baked into the compiled
-    program (zero per-step work, zero extra retraces).
+    path (causal or not, with or without a ``bias``) and the flash_tpu
+    path (no transpose copies); impls that need [b, h, l, d] get a
+    transposed view and transpose back. ``bias`` broadcasts against
+    [b, h, Lq, Lk] under either layout. All selection happens at TRACE
+    time: the chosen tier is baked into the compiled program (zero
+    per-step work, zero extra retraces).
 
     Whatever tier runs, its operations carry the ``attention`` scope:
     score space only (QK, mask or bias, softmax, PV), which a trace
@@ -1061,8 +1181,9 @@ def _dispatch(q, k, v, causal, bias, sp_axis, use_flash, layout):
                 from .flash_tpu import flash_attention_blhd
 
                 return flash_attention_blhd(q, k, v, causal)
-            if impl == "xla" and bias is None:
-                return xla_attention(q, k, v, causal=causal, layout="blhd")
+            if impl == "xla":
+                return xla_attention(q, k, v, causal=causal, bias=bias,
+                                     layout="blhd")
         return tr(_apply_impl(impl, tr(q), tr(k), tr(v), causal, bias))
     return _apply_impl(impl, q, k, v, causal, bias)
 

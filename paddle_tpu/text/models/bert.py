@@ -68,13 +68,14 @@ class BertSelfAttention(nn.Layer):
         qkv = self.qkv(x)
 
         def attend(qkv_raw, bias):
-            q, k, v = jnp.split(qkv_raw, 3, axis=-1)
-            q = q.reshape(b, l, self.num_heads, self.head_dim).transpose(0, 2, 1, 3)
-            k = k.reshape(b, l, self.num_heads, self.head_dim).transpose(0, 2, 1, 3)
-            v = v.reshape(b, l, self.num_heads, self.head_dim).transpose(0, 2, 1, 3)
+            # [b, l, heads, d] is the layout the projection gives and the
+            # attention einsums contract: no [b, h, l, d] transpose copies
+            q, k, v = (t.reshape(b, l, self.num_heads, self.head_dim)
+                       for t in jnp.split(qkv_raw, 3, axis=-1))
             o = dot_product_attention(q, k, v, causal=False, bias=bias,
-                                      use_flash=self.use_flash)
-            return o.transpose(0, 2, 1, 3).reshape(b, l, h)
+                                      use_flash=self.use_flash,
+                                      layout="blhd")
+            return o.reshape(b, l, h)
 
         if attn_bias is not None:
             o = apply_op(attend, qkv, attn_bias)

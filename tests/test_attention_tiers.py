@@ -248,9 +248,9 @@ class TestRingAttentionGrad:
         q, k, v = _qkv(rng, b=2, h=2, L=64, d=8)
         cot = jnp.asarray(rng.randn(*q.shape), jnp.float32)
         out_r, vjp_r = jax.vjp(self._ring(causal), q, k, v)
-        mask = jnp.tril(jnp.ones((64, 64), bool)) if causal else None
         out_c, vjp_c = jax.vjp(
-            lambda a, b, c: att._attention_core(a, b, c, mask), q, k, v)
+            lambda a, b, c: att.xla_attention(a, b, c, causal=causal),
+            q, k, v)
         np.testing.assert_allclose(np.asarray(out_r), np.asarray(out_c),
                                    rtol=2e-5, atol=2e-5)
         for gr, gc, name in zip(vjp_r(cot), vjp_c(cot), "qkv"):

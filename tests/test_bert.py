@@ -45,6 +45,54 @@ class TestBertForward:
         np.testing.assert_allclose(seq1.numpy()[0, :6], seq2.numpy()[0, :6],
                                    atol=1e-5)
 
+    @pytest.mark.parametrize("dtype,tol", [("float32", 2e-5),
+                                           ("bfloat16", 0.02)])
+    def test_xla_tier_matches_the_plain_softmax_path(self, monkeypatch,
+                                                     dtype, tol):
+        """With an explicit mask the XLA tier ([b, l, h, d] into the
+        chunk body, bias inside the chunk, divide after PV) gives the
+        logits of the path it replaced: [b, h, l, d] transposes around
+        one plain softmax over the whole biased square."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops import attention as att
+        from paddle_tpu.text.models import bert as bert_mod
+
+        paddle.seed(5)
+        model = BertForPretraining(bert_tiny(use_flash_attention=True))
+        model.eval()
+        if dtype == "bfloat16":
+            model.bfloat16()
+        rng = np.random.RandomState(5)
+        ids = paddle.to_tensor(rng.randint(0, 1024, (2, 128)).astype("int64"))
+        mask = np.ones((2, 128), "float32")
+        mask[0, 100:] = 0.0
+        mask[1, 77:] = 0.0
+        mask = paddle.to_tensor(mask)
+
+        def plain(q, k, v, causal=False, bias=None, use_flash=True,
+                  layout="bhld"):
+            assert layout == "blhd" and not causal
+            tr = lambda t: t.transpose(0, 2, 1, 3)
+            s = jnp.einsum("bhqd,bhkd->bhqk", tr(q), tr(k),
+                           preferred_element_type=jnp.float32)
+            s = s * q.shape[-1] ** -0.5 + bias
+            p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+            return tr(jnp.einsum("bhqk,bhkd->bhqd", p, tr(v)))
+
+        att.set_attention_impl("xla")
+        try:
+            logits, nsp = model(ids, None, mask)
+        finally:
+            att.set_attention_impl("auto")
+        monkeypatch.setattr(bert_mod, "dot_product_attention", plain)
+        ref_logits, ref_nsp = model(ids, None, mask)
+        f32 = lambda t: np.asarray(t.numpy(), np.float32)
+        assert np.isfinite(f32(logits)).all()
+        np.testing.assert_allclose(f32(logits), f32(ref_logits),
+                                   rtol=tol, atol=tol)
+        np.testing.assert_allclose(f32(nsp), f32(ref_nsp), rtol=tol, atol=tol)
+
     def test_token_type_changes_output(self, config):
         paddle.seed(2)
         model = BertModel(config)
