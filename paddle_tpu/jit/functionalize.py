@@ -20,7 +20,7 @@ from ..core.tensor import Parameter, Tensor, no_grad
 from ..nn.layer_base import Layer
 
 __all__ = ["functionalize", "get_params", "get_buffers", "set_params",
-           "cast_floats", "TracedLayer"]
+           "cast_floats", "checkpointed_call", "TracedLayer"]
 
 
 def cast_floats(tree, dtype):
@@ -113,6 +113,44 @@ def functionalize(layer: Layer, with_buffers: bool = True, training: bool | None
         return _unwrap_tree(out), new_buffers
 
     return apply
+
+
+def checkpointed_call(fn, layers, x):
+    """``fn(x)``, and under ``remat='layer'`` (``ops.remat_policy``) the
+    same under ``jax.checkpoint``: ``fn`` (a bound method, a sub-layer)
+    as a pure function of ``x`` and of the parameters and buffers of
+    ``layers``, which are all it reads; its backward makes its activations
+    again. What a model calls on each part of a block. Buffers that ``fn``
+    writes (a counter a layer carries through the step) are written back.
+    """
+    from ..core.tensor import apply_op
+    from ..ops import remat_policy
+
+    if not remat_policy.layers_checkpointed():
+        return fn(x)
+    params = [p for layer in layers for _, p in layer.named_parameters()]
+    buffers = [b for layer in layers for _, b in layer.named_buffers()]
+
+    @jax.checkpoint
+    def pure(x_raw, buffer_values, *param_values):
+        saved = [t._value for t in params + buffers]
+        try:
+            for t, v in zip(params + buffers,
+                            tuple(param_values) + tuple(buffer_values)):
+                t._value = v
+            with no_grad():
+                out = fn(Tensor(x_raw))
+            return (out._value,) + tuple(b._value for b in buffers)
+        finally:
+            for t, v in zip(params + buffers, saved):
+                t._value = v
+
+    out, *new_values = apply_op(
+        pure, x, tuple(b._value for b in buffers), *params,
+        multi_out=True, op_name="checkpointed")
+    for b, new in zip(buffers, new_values):
+        b._value = new._value
+    return out
 
 
 def _unwrap_tree(out):

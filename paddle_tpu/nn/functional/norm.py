@@ -14,8 +14,8 @@ import numpy as np
 
 from ...core.tensor import Tensor, apply_op, to_tensor
 
-__all__ = ["batch_norm", "layer_norm", "instance_norm", "group_norm",
-           "local_response_norm", "manual_ln_scope"]
+__all__ = ["batch_norm", "layer_norm", "rms_norm", "instance_norm",
+           "group_norm", "local_response_norm", "manual_ln_scope"]
 
 # The manual-LN VJP is a PER-WORKLOAD knob (+2.2% on GPT-2 345M, -24% on
 # BERT-base under the fleet engine — the custom_vjp blocks a fusion BERT's
@@ -263,6 +263,30 @@ def _ln_manual_bwd(epsilon, res, dy):
 
 
 _ln_manual.defvjp(_ln_manual_fwd, _ln_manual_bwd)
+
+
+def rms_norm(x, weight=None, epsilon=1e-05, gate=None, name=None):
+    """x / rms(x) * weight over the last axis (as many trailing elements
+    as ``weight`` has; all of them without one), the statistic in float32.
+    With ``gate`` (``x``'s shape, or its last axes flattened) the result
+    is multiplied by sigmoid(gate): the gated, head-wise form a linear
+    attention layer puts on its output."""
+
+    def f(a, *rest):
+        rest = list(rest)
+        w = rest.pop(0) if weight is not None else None
+        a32 = a.astype(jnp.float32)
+        out = a32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(a32), axis=-1, keepdims=True) + epsilon)
+        if w is not None:
+            out = out * w.astype(jnp.float32)
+        if gate is not None:
+            out = out * jax.nn.sigmoid(
+                rest[0].astype(jnp.float32).reshape(a.shape))
+        return out.astype(a.dtype)
+
+    args = [_t(x)] + [t for t in (weight, gate) if t is not None]
+    return apply_op(f, *args)
 
 
 def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05, name=None):

@@ -10,7 +10,7 @@ from .. import functional as F
 from ..layer_base import Layer
 
 __all__ = [
-    "Linear", "Embedding", "Dropout", "Dropout2D", "Dropout3D", "AlphaDropout",
+    "Linear", "SwiGLU", "Embedding", "Dropout", "Dropout2D", "Dropout3D", "AlphaDropout",
     "Flatten", "Upsample", "UpsamplingBilinear2D", "UpsamplingNearest2D",
     "Pad1D", "Pad2D", "Pad3D", "ZeroPad2D", "CosineSimilarity", "Bilinear",
     "Identity", "Unfold", "Fold", "PixelShuffle", "PixelUnshuffle", "ChannelShuffle",
@@ -41,6 +41,29 @@ class Linear(Layer):
 
     def extra_repr(self):
         return f"in_features={self._in_features}, out_features={self._out_features}"
+
+
+class SwiGLU(Layer):
+    """The gated MLP: down(silu(gate(x)) * up(x)), three matrices and no
+    bias. ``weight_attr`` initialises all three."""
+
+    def __init__(self, hidden_size, intermediate_size, weight_attr=None,
+                 name=None):
+        super().__init__()
+        self.gate_proj = Linear(hidden_size, intermediate_size, weight_attr,
+                                bias_attr=False)
+        self.up_proj = Linear(hidden_size, intermediate_size, weight_attr,
+                              bias_attr=False)
+        self.down_proj = Linear(intermediate_size, hidden_size, weight_attr,
+                                bias_attr=False)
+        # TP: gate and up column-parallel, down row-parallel
+        self.gate_proj.weight.tp_spec = (None, "mp")
+        self.up_proj.weight.tp_spec = (None, "mp")
+        self.down_proj.weight.tp_spec = ("mp", None)
+
+    def forward(self, input):
+        return self.down_proj(F.silu(self.gate_proj(input))
+                              * self.up_proj(input))
 
 
 class Embedding(Layer):

@@ -18,7 +18,7 @@ from ..layer_base import Layer
 
 __all__ = [
     "BatchNorm", "BatchNorm1D", "BatchNorm2D", "BatchNorm3D", "SyncBatchNorm",
-    "LayerNorm", "GroupNorm", "InstanceNorm1D", "InstanceNorm2D",
+    "LayerNorm", "RMSNorm", "GatedRMSNorm", "GroupNorm", "InstanceNorm1D", "InstanceNorm2D",
     "InstanceNorm3D", "LocalResponseNorm", "SpectralNorm",
 ]
 
@@ -131,6 +131,33 @@ class LayerNorm(Layer):
 
     def extra_repr(self):
         return f"normalized_shape={self._normalized_shape}, epsilon={self._epsilon}"
+
+
+class RMSNorm(Layer):
+    """x / rms(x) * weight over the last axis, no mean and no bias."""
+
+    def __init__(self, hidden_size, epsilon=1e-05, weight_attr=None,
+                 name=None):
+        super().__init__()
+        self._epsilon = epsilon
+        self.weight = self.create_parameter(
+            [int(hidden_size)], attr=weight_attr,
+            default_initializer=I.Constant(1.0))
+
+    def forward(self, input):
+        return F.rms_norm(input, self.weight, self._epsilon)
+
+    def extra_repr(self):
+        return f"{self.weight.shape[0]}, epsilon={self._epsilon}"
+
+
+class GatedRMSNorm(RMSNorm):
+    """RMSNorm(x) * sigmoid(gate), the norm over the last axis of ``x``
+    (a head's width, one learned weight shared by the heads); ``gate`` may
+    come with the heads flattened."""
+
+    def forward(self, input, gate):
+        return F.rms_norm(input, self.weight, self._epsilon, gate=gate)
 
 
 class GroupNorm(Layer):

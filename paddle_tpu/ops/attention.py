@@ -740,23 +740,43 @@ def _einsum_eqs(blhd: bool):
             else ("bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd"))
 
 
-def _q_chunk_size(Lq: int):
+def _q_chunk_size(Lq: int, max_chunks: int = None):
     """Rows of a q-chunk, or None when no exact chunking exists (c must
     divide Lq — a truncated concat would silently drop query rows)."""
-    c = max(_CAUSAL_CHUNK, Lq // max(_CAUSAL_MAX_CHUNKS, 1))
+    c = max(_CAUSAL_CHUNK, Lq // max(max_chunks or _CAUSAL_MAX_CHUNKS, 1))
     if Lq % c != 0 or Lq // c < 2:
         return None
     return c
 
 
-def _q_chunks(Lq: int, Lk: int, causal: bool):
+# a latent call (values narrower than the keys) has many wide heads: at
+# L = 8192 its 32 unrolled chunks, forward and backward, were 48 MB of
+# compiled code and 25 s of the v5e compiler's time for one layer; 16
+# chunks of 512 rows halve both, for 3% more of the masked triangle
+_LATENT_MAX_CHUNKS = 16
+
+
+def _latent(q, v) -> bool:
+    """Values narrower (or wider) than the keys: latent attention."""
+    return v.shape[-1] != q.shape[-1]
+
+
+def _call_chunks(q, k, v, blhd: bool, causal: bool):
+    """The chunks of this call (`_q_chunks`), by its operands alone, so
+    that a backward cuts as its forward did."""
+    axis_l = 1 if blhd else 2
+    return _q_chunks(q.shape[axis_l], k.shape[axis_l], causal,
+                     _LATENT_MAX_CHUNKS if _latent(q, v) else None)
+
+
+def _q_chunks(Lq: int, Lk: int, causal: bool, max_chunks: int = None):
     """The call's chunks as (lo, hi, ub): query rows [lo, hi) against keys
     [0, ub). Self-attention of a length `_q_chunk_size` divides is cut
     into chunks of that many rows: a causal chunk stops at its diagonal
     (ub = hi: the fully-masked upper-triangle blocks are never computed),
     a non-causal one sees every key. Everything else (Lq != Lk, the unit
     tests' L = 16) is one chunk over the whole score rectangle."""
-    c = _q_chunk_size(Lq) if Lq == Lk else None
+    c = _q_chunk_size(Lq, max_chunks) if Lq == Lk else None
     if c is None:
         return ((0, Lq, Lk),)
     return tuple((lo, lo + c, lo + c if causal else Lk)
@@ -961,7 +981,7 @@ def _chunked_fwd_impl(q, k, v, blhd: bool, causal: bool, bias=None):
     from ..profiler.telemetry import get_telemetry
 
     axis_l = 1 if blhd else 2
-    chunks = _q_chunks(q.shape[axis_l], k.shape[axis_l], causal)
+    chunks = _call_chunks(q, k, v, blhd, causal)
     # trace-time fact, like attn/calls: the chunks this call emits
     get_telemetry().counter(
         "attn/xla_chunks." + ("c" if causal else "f"), len(chunks))
@@ -1016,7 +1036,7 @@ def _causal_chunked_bwd(blhd, res, g):
     remat = _remat_e()
 
     dqs, dks, dvs = [], [], []
-    for i, chunk in enumerate(_q_chunks(Lq, Lq, True)):
+    for i, chunk in enumerate(_call_chunks(q, k, v, blhd, True)):
         lo, hi, ub = chunk
         qi = sl(q, lo, hi)
         ki, vi = sl(k, 0, ub), sl(v, 0, ub)
@@ -1090,9 +1110,12 @@ def xla_attention(q, k, v, causal=False, bias=None, layout="bhld"):
     (`_weights_pv`), so that the rows of dS sum to zero in bf16 too.
     """
     blhd = layout == "blhd"
-    axis_l = 1 if blhd else 2
-    if (_MANUAL_ATTN_VJP and causal and bias is None
-            and len(_q_chunks(q.shape[axis_l], k.shape[axis_l], True)) > 1):
+    # the hand-written backward keeps a chunk's row maxima and makes its
+    # exp weights again: a latent call takes it by rule, because at 32
+    # heads of 8192 keys autodiff's saved weights are 2.3 GB a layer and
+    # were the step's peak
+    if ((_MANUAL_ATTN_VJP or _latent(q, v)) and causal and bias is None
+            and len(_call_chunks(q, k, v, blhd, True)) > 1):
         return _causal_chunked(q, k, v, blhd)
     return _chunked_fwd_impl(q, k, v, blhd, causal, bias)[0]
 
@@ -1137,7 +1160,8 @@ def dot_product_attention(q, k, v, causal=False, bias=None, sp_axis=None,
     ``layout='blhd'`` passes [b, l, h, d] operands straight into the XLA
     path (causal or not, with or without a ``bias``) and the flash_tpu
     path (no transpose copies); impls that need [b, h, l, d] get a
-    transposed view and transpose back. ``bias`` broadcasts against
+    transposed view and transpose back. ``v`` may be narrower or wider
+    than ``q`` and ``k``: such a call takes the XLA path by rule. ``bias`` broadcasts against
     [b, h, Lq, Lk] under either layout. All selection happens at TRACE
     time: the chosen tier is baked into the compiled program (zero
     per-step work, zero extra retraces).
@@ -1173,7 +1197,14 @@ def _dispatch(q, k, v, causal, bias, sp_axis, use_flash, layout):
     if _IMPL == "auto" and _ring_auto_ok(L, causal, bias):
         tier_policy.publish_tier(L, d, causal, "ring")
         return _ring_sharded(q, k, v, causal, blhd)
-    impl = _select_impl(q, k, bias, use_flash, causal, blhd)
+    if v.shape[-1] != d:
+        # values narrower than the keys (latent attention: 192 against
+        # 128): the XLA chunk body by rule, as a biased call takes it; the
+        # kernel tiers assume one head width, and a race would time them
+        # on other shapes than the call's
+        impl = "xla"
+    else:
+        impl = _select_impl(q, k, bias, use_flash, causal, blhd)
     tier_policy.publish_tier(L, d, causal, _TIER_OF_IMPL.get(impl, impl))
     if blhd:
         if not _FORCE_BHLD:

@@ -1,0 +1,224 @@
+"""Linear attention with a recurrent state: the gated delta rule with a
+per-channel decay (Kimi Delta Attention), and the short causal convolution
+that feeds it. Plain XLA: batched matmuls inside chunks, one ``lax.scan``
+over the chunks for the state.
+
+The recurrence, per head, with S in R^{dk x dv} and S_0 = 0:
+
+    S~  = Diag(exp(g_t)) S_{t-1}
+    S_t = S~ + beta_t k_t (v_t - S~^T k_t)^T
+    o_t = S_t^T q_t
+
+``chunk_kda`` computes it a chunk of C tokens at a time. With G the
+chunk's running sum of g and u_t = v_t - S~_t^T k_t (what token t writes):
+
+    (I + A Diag(beta)) U = V - (K exp(G)) S_0,
+        A[r, i] = sum_c k_r[c] k_i[c] exp(G_r[c] - G_i[c])   for i < r
+    O   = (Q exp(G)) S_0 + B (beta U),
+        B[r, i] = sum_c q_r[c] k_i[c] exp(G_r[c] - G_i[c])   for i <= r
+    S_C = Diag(exp(G_C)) S_0 + (K exp(G_C - G))^T (beta U)
+
+so that a chunk costs a unit lower-triangular inverse (the WY form) and a
+few matmuls, and only S crosses chunks. Every exponent that is taken is
+<= 0: A and B are built by sub-blocks, an off-diagonal sub-block through
+the decays up to and from the start of its row block, a diagonal one from
+the pairwise differences themselves, so that no ``exp(-cumsum g)`` is ever
+formed, however fast a channel decays. The state, the decays, A, B and the
+inverse are float32; the chunk's matmuls take operands in the inputs'
+dtype and accumulate in float32.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+__all__ = ["short_conv", "chunk_kda"]
+
+F32 = jnp.float32
+_HI = jax.lax.Precision.HIGHEST
+_SUB = 16  # rows of a sub-block of A and B, and of the inverse's base case
+
+
+def short_conv(x, w):
+    """Depthwise causal convolution along the sequence: ``x`` [b, l, c],
+    ``w`` [c, k]; y_t = sum_j w[:, j] x_{t-(k-1)+j}, zeros before the
+    start (a ``Conv1d(c, c, k, groups=c, padding=k-1)`` cut to l)."""
+    k = w.shape[-1]
+    x32, w32 = x.astype(F32), w.astype(F32)
+    y = x32 * w32[:, k - 1]
+    for back in range(1, k):
+        shifted = jnp.pad(x32, ((0, 0), (back, 0), (0, 0)))[:, :x.shape[1]]
+        y = y + shifted * w32[:, k - 1 - back]
+    return y.astype(x.dtype)
+
+
+def _mm(eq, a, b, dtype):
+    """einsum on operands of ``dtype``, accumulated and returned in f32."""
+    return jnp.einsum(eq, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=F32,
+                      precision=_HI if dtype == F32 else None)
+
+
+def _inv_rows(n):
+    """(I + n)^-1 of strictly lower ``n`` [..., s, s] by forward
+    substitution, a row at a time: row r = e_r - sum_{i<r} n[r, i] row i
+    (the rows not yet made are still the identity's, and n[r, i] = 0
+    there)."""
+    s = n.shape[-1]
+    eye = jnp.broadcast_to(jnp.eye(s, dtype=F32), n.shape)
+
+    def row(inv, r):
+        n_r = jax.lax.dynamic_index_in_dim(n, r, axis=-2, keepdims=True)
+        e_r = jax.lax.dynamic_index_in_dim(eye, r, axis=-2, keepdims=True)
+        new = e_r - jnp.matmul(n_r, inv, precision=_HI)
+        return jax.lax.dynamic_update_index_in_dim(inv, new, r, axis=-2), None
+
+    return jax.lax.scan(row, eye, jnp.arange(1, s, dtype=jnp.int32))[0]
+
+
+def _inv_unit_lower(n, sub):
+    """(I + n)^-1 of strictly lower ``n`` [..., c, c], c = sub * 2^j: the
+    diagonal blocks by substitution, then pairs of blocks merged,
+    [[A, 0], [C, B]]^-1 = [[A^-1, 0], [-B^-1 C A^-1, B^-1]]."""
+    lead, c = n.shape[:-2], n.shape[-1]
+    blocks = c // sub
+    if blocks * sub != c or blocks & (blocks - 1):
+        raise ValueError(f"chunk {c} is not sub-block {sub} times a power "
+                         "of two")
+    tiles = n.reshape(lead + (blocks, sub, blocks, sub))
+    inv = _inv_rows(jnp.stack([tiles[..., a, :, a, :]
+                               for a in range(blocks)], axis=-3))
+    size = sub
+    while size < c:
+        pairs = c // (2 * size)
+        inv = inv.reshape(lead + (pairs, 2, size, size))
+        top, bottom = inv[..., 0, :, :], inv[..., 1, :, :]
+        tiles = n.reshape(lead + (pairs, 2, size, pairs, 2, size))
+        below = jnp.stack([tiles[..., p, 1, :, p, 0, :]
+                           for p in range(pairs)], axis=-3)
+        corner = -jnp.matmul(jnp.matmul(bottom, below, precision=_HI), top,
+                             precision=_HI)
+        inv = jnp.concatenate([
+            jnp.concatenate([top, jnp.zeros_like(top)], axis=-1),
+            jnp.concatenate([corner, bottom], axis=-1)], axis=-2)
+        size *= 2
+    return inv[..., 0, :, :]
+
+
+def _intra(q, k, G, sub):
+    """A (strictly lower) and B (lower, with the diagonal) of every chunk,
+    [b, n, h, c, c] in f32, from f32 ``q``, ``k`` and the chunk's running
+    sum ``G``, all [b, n, h, c, d]."""
+    b, n, h, c, d = k.shape
+    blocks = c // sub
+    lower = jnp.tril(jnp.ones((sub, sub), bool))[..., None]
+    strict = jnp.tril(jnp.ones((sub, sub), bool), k=-1)
+
+    @jax.checkpoint
+    def diagonal(head):
+        """The diagonal sub-blocks of one head, [b, n, blocks, r, i]: exp
+        of the pairwise differences themselves. The [.., r, i, d] tensors
+        are sub times the inputs' size, so they are made a head at a time
+        and made again in the backward."""
+        qb, kb, Gb = head
+        diff = Gb[..., :, None, :] - Gb[..., None, :, :]
+        decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
+        kd = kb[..., None, :, :] * decay                 # k_i exp(G_r - G_i)
+        return (jnp.where(strict, jnp.sum(kb[..., :, None, :] * kd, -1), 0.0),
+                jnp.sum(qb[..., :, None, :] * kd, -1))
+
+    heads_first = lambda t: jnp.moveaxis(  # noqa: E731
+        t.reshape(b, n, h, blocks, sub, d), 2, 0)
+    diag_a, diag_b = (jnp.moveaxis(t, 0, 2) for t in jax.lax.map(
+        diagonal, (heads_first(q), heads_first(k), heads_first(G))))
+    rows_a, rows_b = [], []
+    for a in range(blocks):
+        lo, hi = a * sub, (a + 1) * sub
+        parts_a, parts_b = [diag_a[:, :, :, a]], [diag_b[:, :, :, a]]
+        if a:
+            # through the start of row block a: both exponents are <= 0
+            ref = G[:, :, :, lo - 1:lo]                  # [b, n, h, 1, d]
+            into = jnp.exp(G[:, :, :, lo:hi] - ref)
+            upto = k[:, :, :, :lo] * jnp.exp(ref - G[:, :, :, :lo])
+            eq = "bnhrd,bnhid->bnhri"
+            parts_a.insert(0, _mm(eq, k[:, :, :, lo:hi] * into, upto, F32))
+            parts_b.insert(0, _mm(eq, q[:, :, :, lo:hi] * into, upto, F32))
+        if hi < c:
+            zeros = jnp.zeros((b, n, h, sub, c - hi), F32)
+            parts_a.append(zeros)
+            parts_b.append(zeros)
+        rows_a.append(jnp.concatenate(parts_a, axis=-1))
+        rows_b.append(jnp.concatenate(parts_b, axis=-1))
+    return jnp.concatenate(rows_a, axis=-2), jnp.concatenate(rows_b, axis=-2)
+
+
+def _chunk_kda(q, k, v, g, beta, chunk, sub):
+    b, l, h, dk = k.shape
+    dtype = q.dtype
+    pad = -l % chunk
+    if pad:
+        # a padded token writes nothing (k = 0, beta = 0) and decays
+        # nothing (g = 0): the state passes it unchanged
+        q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, pad))
+                                    + ((0, 0),) * (t.ndim - 2))
+                            for t in (q, k, v, g, beta))
+    n = (l + pad) // chunk
+    # [b, n, h, c, d]: the batch axes of every product lead
+    q, k, v, g = (t.reshape(b, n, chunk, h, -1).transpose(0, 1, 3, 2, 4)
+                  for t in (q, k, v, g))
+    beta = beta.astype(F32).reshape(b, n, chunk, h).transpose(0, 1, 3, 2)
+    q32, k32 = q.astype(F32), k.astype(F32)
+    G = jnp.cumsum(g.astype(F32), axis=3)
+    A, B = _intra(q32, k32, G, sub)
+    T = _inv_unit_lower(A * beta[:, :, :, None, :], sub)  # [b, n, h, c, c]
+    decayed = jnp.exp(G)
+    last = G[:, :, :, -1:]
+    # chunk-local: what the tokens would write into an empty state, and
+    # what of the incoming state they take back
+    fresh = _mm("bnhri,bnhiv->bnhrv", T, v, dtype)
+    taken = _mm("bnhri,bnhik->bnhrk", T, k32 * decayed, dtype)
+    k_end = k32 * jnp.exp(last - G)
+    keep = jnp.exp(last[:, :, :, 0])                     # [b, n, h, dk]
+
+    def step(S, xs):
+        taken_n, fresh_n, k_end_n, keep_n, beta_n = xs
+        u = fresh_n - _mm("bhrk,bhkv->bhrv", taken_n, S, dtype)
+        wrote = _mm("bhrk,bhrv->bhkv", k_end_n, beta_n[..., None] * u, dtype)
+        return keep_n[..., None] * S + wrote, (S, u)
+
+    chunks_first = lambda t: jnp.moveaxis(t, 1, 0)  # noqa: E731
+    _, (S, U) = jax.lax.scan(
+        step, jnp.zeros((b, h, dk, v.shape[-1]), F32),
+        tuple(chunks_first(t) for t in (taken, fresh, k_end, keep, beta)))
+    S, U = jnp.moveaxis(S, 0, 1), jnp.moveaxis(U, 0, 1)
+    out = (_mm("bnhrk,bnhkv->bnhrv", q32 * decayed, S, dtype)
+           + _mm("bnhri,bnhiv->bnhrv", B, beta[..., None] * U, dtype))
+    out = out.transpose(0, 1, 3, 2, 4).reshape(b, l + pad, h, -1)
+    return out[:, :l].astype(dtype)
+
+
+def chunk_kda(q, k, v, g, beta, chunk=64, checkpoint=True):
+    """The gated delta rule with a per-channel decay, chunked.
+
+    ``q``, ``k`` [b, l, h, dk] (as the layer hands them: normalised, q
+    scaled), ``v`` [b, l, h, dv], ``g`` [b, l, h, dk] the log of the decay
+    (<= 0), ``beta`` [b, l, h] in (0, 1). Returns o [b, l, h, dv] in the
+    inputs' dtype; the state starts at zero. Any length: the tail is
+    padded to a whole chunk with tokens that leave the state alone.
+
+    The backward is autodiff. Under ``checkpoint`` (the default) only the
+    five inputs are kept for it and the chunk's intermediates are made
+    again; a caller that recomputes the whole layer anyway passes False.
+    """
+    from ..profiler.telemetry import get_telemetry
+
+    # trace-time facts, like attn/calls
+    tel = get_telemetry()
+    tel.counter("kda/calls")
+    tel.gauge("kda/chunk", chunk)
+    fn = functools.partial(_chunk_kda, chunk=chunk, sub=min(_SUB, chunk))
+    if checkpoint:
+        fn = jax.checkpoint(fn)
+    return fn(q, k, v, g, beta)

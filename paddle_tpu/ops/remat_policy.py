@@ -48,11 +48,25 @@ import jax
 logger = logging.getLogger("paddle_tpu.ops")
 
 __all__ = ["POLICY_IDS", "apply_policy", "program_cost", "resolve",
-           "normalize"]
+           "normalize", "layers_checkpointed"]
 
-# stable ids for gauge/remat/<entry> (schema: >= 0)
+# stable ids for gauge/remat/<entry> (schema: >= 0). 'layer' is no rung of
+# the 'auto' ladder: it is asked for by name
 POLICY_IDS = {"off": 0, "dots": 1, "dots_no_batch": 2, "nothing": 3,
-              "offload": 4, "full": 5}
+              "offload": 4, "full": 5, "layer": 6}
+
+# 'layer': the forward is traced with this flag up, and a model that knows
+# its own layer boundaries (``jit.functionalize.checkpointed_call``) puts
+# each layer under ``jax.checkpoint``: the step keeps one activation a
+# layer and the backward makes a layer's own again when it reaches it.
+# ``jax.checkpoint`` around the whole forward ('full') cannot do that: its
+# backward holds every layer's activations at once, as no remat does.
+_LAYERS_CHECKPOINTED: list = []
+
+
+def layers_checkpointed() -> bool:
+    """True while a forward is traced under ``remat='layer'``."""
+    return bool(_LAYERS_CHECKPOINTED)
 
 _warned_off = False
 
@@ -60,7 +74,7 @@ _warned_off = False
 def normalize(remat) -> str:
     """Engine ctor values -> canonical policy name. Accepts the legacy
     ``recompute`` vocabulary (False/True/'dots'/'dots_no_batch'/
-    'nothing') plus 'off'/'full'/'offload'/'auto'."""
+    'nothing') plus 'off'/'full'/'offload'/'layer'/'auto'."""
     if remat in (None, False, "off", ""):
         return "off"
     if remat is True or remat == "full":
@@ -91,6 +105,15 @@ def apply_policy(fn: Callable, policy: str) -> Callable:
     policy = normalize(policy)
     if policy == "off":
         return fn
+    if policy == "layer":
+        def layer_by_layer(*args, **kwargs):
+            _LAYERS_CHECKPOINTED.append(True)
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                _LAYERS_CHECKPOINTED.pop()
+
+        return layer_by_layer
     if policy == "full":
         return jax.checkpoint(fn, static_argnums=())
     return jax.checkpoint(fn, static_argnums=(),

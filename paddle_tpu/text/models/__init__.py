@@ -18,3 +18,9 @@ from .gpt import (  # noqa: F401
     gpt2_small,
     gpt2_tiny,
 )
+from .kimi_linear import (  # noqa: F401
+    KimiLinearConfig,
+    KimiLinearForCausalLM,
+    KimiLinearModel,
+    kimi_linear_tiny,
+)
