@@ -1,7 +1,12 @@
 """Linear attention with a recurrent state: the gated delta rule with a
 per-channel decay (Kimi Delta Attention), and the short causal convolution
-that feeds it. Plain XLA: batched matmuls inside chunks, one ``lax.scan``
-over the chunks for the state.
+that feeds it. ``chunk_kda`` is one algorithm on two lowerings, chosen by
+rule (``_tier``): on a TPU, at head widths that are multiples of 128, the
+Pallas kernel pair of ``ops/kda_tpu.py``, which holds a chunk's
+intermediates in VMEM, forward and hand-written backward; everywhere else
+plain XLA (``_chunk_kda``: batched matmuls inside chunks, one ``lax.scan``
+over the chunks for the state, autodiff), which is also the definition the
+kernels are tested against.
 
 The recurrence, per head, with S in R^{dk x dv} and S_0 = 0:
 
@@ -199,6 +204,66 @@ def _chunk_kda(q, k, v, g, beta, chunk, sub):
     return out[:, :l].astype(dtype)
 
 
+_INTERPRET = False  # a CPU test of the kernels sets it, with ``_on_tpu``
+
+
+def _on_tpu():
+    return jax.default_backend() == "tpu"
+
+
+def _tier(q, k, v, chunk):
+    """Which lowering a call takes: ``"pallas"`` on a TPU when q, k and v
+    share bfloat16 or float32, dk and dv are multiples of 128 (a head's
+    tile is whole lanes) and ``chunk`` is 16, 32, 64 or 128 (16-row
+    sub-blocks times a power of two, a whole bfloat16 tile of rows);
+    ``"xla"`` for every other call. Nothing is timed and nothing is read
+    from the machine."""
+    fits = (q.dtype == k.dtype == v.dtype
+            and q.dtype in (jnp.bfloat16, jnp.float32)
+            and k.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0
+            and chunk in (16, 32, 64, 128))
+    return "pallas" if fits and _on_tpu() else "xla"
+
+
+def _whole_chunks(chunk, *tensors):
+    """Pad [b, l, ...] tensors with zeros to a whole number of chunks: a
+    padded token writes nothing, decays nothing and is asked nothing."""
+    pad = -tensors[0].shape[1] % chunk
+    return [jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in tensors]
+
+
+def _kernel_forward(inputs, chunk, keep_states):
+    from . import kda_tpu
+
+    out, states = kda_tpu.forward(*_whole_chunks(chunk, *inputs), chunk, _SUB,
+                                  keep_states, interpret=_INTERPRET)
+    return out[:, :inputs[0].shape[1]], states
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _kda_pallas(q, k, v, g, beta, chunk):
+    return _kernel_forward((q, k, v, g, beta), chunk, False)[0]
+
+
+def _kda_pallas_fwd(q, k, v, g, beta, chunk):
+    out, states = _kernel_forward((q, k, v, g, beta), chunk, True)
+    return out, (q, k, v, g, beta, states)
+
+
+def _kda_pallas_bwd(chunk, residuals, d_out):
+    from . import kda_tpu
+
+    *inputs, states = residuals
+    grads = kda_tpu.backward(*_whole_chunks(chunk, *inputs), states,
+                             *_whole_chunks(chunk, d_out), chunk, _SUB,
+                             interpret=_INTERPRET)
+    return tuple(t[:, :d_out.shape[1]] for t in grads)
+
+
+_kda_pallas.defvjp(_kda_pallas_fwd, _kda_pallas_bwd)
+
+
 def chunk_kda(q, k, v, g, beta, chunk=64, checkpoint=True):
     """The gated delta rule with a per-channel decay, chunked.
 
@@ -208,17 +273,25 @@ def chunk_kda(q, k, v, g, beta, chunk=64, checkpoint=True):
     inputs' dtype; the state starts at zero. Any length: the tail is
     padded to a whole chunk with tokens that leave the state alone.
 
-    The backward is autodiff. Under ``checkpoint`` (the default) only the
-    five inputs are kept for it and the chunk's intermediates are made
-    again; a caller that recomputes the whole layer anyway passes False.
+    The lowering is chosen by ``_tier``'s rule. The XLA form's backward is
+    autodiff; the kernels' is written by hand and keeps, beside the five
+    inputs, each chunk's incoming state. Under ``checkpoint`` (the default)
+    only the five inputs are kept and the rest is made again in the
+    backward; a caller that recomputes the whole layer anyway passes False.
     """
     from ..profiler.telemetry import get_telemetry
+    from .tier_policy import TIER_IDS
 
-    # trace-time facts, like attn/calls
+    tier = _tier(q, k, v, chunk)
+    # trace-time facts, like attn/calls and attn/tier.*
     tel = get_telemetry()
     tel.counter("kda/calls")
     tel.gauge("kda/chunk", chunk)
-    fn = functools.partial(_chunk_kda, chunk=chunk, sub=min(_SUB, chunk))
+    tel.gauge(f"kda/tier.{tier}", TIER_IDS[tier])
+    if tier == "pallas":
+        fn = functools.partial(_kda_pallas, chunk=chunk)
+    else:
+        fn = functools.partial(_chunk_kda, chunk=chunk, sub=min(_SUB, chunk))
     if checkpoint:
         fn = jax.checkpoint(fn)
     return fn(q, k, v, g, beta)

@@ -14,6 +14,7 @@ tests plant: a token dropped from one expert moves the layer's output by
 """
 import collections
 import contextlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +24,7 @@ from jax.sharding import Mesh
 
 import paddle_tpu as paddle
 from benchmark.families import kimi_linear as family
-from benchmark.lib import layout
+from benchmark.lib import inner_scopes, layout
 from benchmark.reference import common
 from benchmark.reference import kimi_linear as reference
 from paddle_tpu import nn
@@ -308,6 +309,53 @@ def test_loss_and_gradients_match_the_reference():
     low = jax.jit(jax.grad(lambda p: reference.block_loss(
         p, data, denoms, CONFIG, EINSUM)))(rounded)
     assert max(worst(low[leaf], want[leaf]) for leaf in names) > 10 * TOL
+
+
+@pytest.mark.parametrize("remat", ["off", "layer"])
+def test_the_two_lowerings_of_the_delta_rule_agree_in_the_model(
+        remat, monkeypatch):
+    """KDA heads of 128 at a tiny hidden size: the loss and every
+    parameter's gradient are the same whether ``chunk_kda`` lowers to XLA
+    (as the CPU takes it) or to the kernel pair (the test stands in for the
+    TPU and runs Pallas in interpret mode), with the layers kept or made
+    again; the kernels, forward and backward, lie under the ``kda`` scope."""
+    from paddle_tpu.ops import linear_attention, remat_policy
+
+    paddle.seed(5)
+    model = KimiLinearForCausalLM(program_config(kda_head_dim=128))
+    data = batch(seed=1, rows=1, length=96)
+    apply = functionalize(model, training=True)
+    buffers = {n: b._value for n, b in model.named_buffers()}
+    loss_of = remat_policy.apply_policy(
+        lambda p: apply(p, buffers, data["ids"], data["labels"])[0], remat)
+    step = lambda: jax.jit(jax.value_and_grad(loss_of))  # noqa: E731
+
+    def run():
+        get_telemetry().reset()
+        lowered = step().lower(get_params(model))
+        tiers = sorted(k.rsplit(".", 1)[1] for k in get_telemetry().scalars()
+                       if k.startswith("gauge/kda/tier."))
+        return tiers, lowered.as_text(debug_info=True), \
+            step()(get_params(model))
+
+    tiers, _, (want_loss, want) = run()
+    assert tiers == ["xla"]
+    monkeypatch.setattr(linear_attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(linear_attention, "_INTERPRET", True)
+    tiers, text, (got_loss, got) = run()
+    assert tiers == ["pallas"]
+    assert abs(float(got_loss) - float(want_loss)) < 1e-5 * float(want_loss)
+    for name in want:
+        assert worst(got[name], want[name]) < TOL, name
+    # the kernels' operations carry the scope by the rule the benchmark's
+    # reader has (``inner_scopes.innermost``), the backward's too
+    paths = re.findall(r'loc\("([^"]*chunk_kda_(?:fwd|bwd)/pallas_call)"',
+                       text)
+    assert {p.rsplit("/", 2)[1] for p in paths} == {"chunk_kda_fwd",
+                                                    "chunk_kda_bwd"}
+    assert all(inner_scopes.innermost(p) == "kda" for p in paths)
+    assert any("transpose(" in p for p in paths)
+    get_telemetry().reset()
 
 
 def test_layer_types_follow_the_published_lists():

@@ -15,11 +15,28 @@ import numpy as np
 import pytest
 
 from benchmark.reference import kimi_linear as reference
+from paddle_tpu.ops import kda_tpu, linear_attention
 from paddle_tpu.ops.linear_attention import chunk_kda, short_conv
 from paddle_tpu.profiler import get_telemetry
 
 TOL = 2e-5
 F32 = jnp.float32
+
+
+@pytest.fixture
+def impl(request, monkeypatch):
+    """The lowering under test: the XLA form as the CPU takes it by rule,
+    or the kernel pair, for which the test stands in for the TPU and runs
+    Pallas in interpret mode, two heads a grid step so that a state is
+    handed over between chunks and kept apart between head groups."""
+    if request.param == "pallas":
+        monkeypatch.setattr(linear_attention, "_on_tpu", lambda: True)
+        monkeypatch.setattr(linear_attention, "_INTERPRET", True)
+        monkeypatch.setattr(kda_tpu, "heads_per_step", lambda h: 2)
+    return request.param
+
+
+both = pytest.mark.parametrize("impl", ["xla", "pallas"], indirect=True)
 
 
 def inputs(seed, b, l, h, d, fastest=2.0):
@@ -41,23 +58,37 @@ def worst(got, want):
     return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
 
 
-# (length, chunk): one chunk, several, a length that is no multiple of the
-# chunk, a chunk of one sub-block, and one shorter than a chunk
-CASES = [(64, 64), (200, 64), (96, 32), (50, 16), (40, 64)]
+# (length, chunk, heads, width): one chunk, several, a length that is no
+# multiple of the chunk, a chunk of one sub-block, and one shorter than a
+# chunk; then at a width the kernels take (two groups of two heads): one
+# chunk, several, and a length that is no multiple of the chunk
+CASES = [(64, 64, 2, 16), (200, 64, 2, 16), (96, 32, 2, 16), (50, 16, 2, 16),
+         (40, 64, 2, 16), (64, 64, 4, 128), (256, 64, 4, 128),
+         (150, 64, 4, 128)]
 
 
-@pytest.mark.parametrize("length,chunk", CASES)
-def test_chunked_matches_the_recurrence(length, chunk):
-    args = inputs(length, 2, length, 2, 16)
+def taken(impl, width):
+    """The lowering a call of this width takes under ``impl``."""
+    return impl if width % 128 == 0 else "xla"
+
+
+@both
+@pytest.mark.parametrize("length,chunk,heads,width", CASES)
+def test_chunked_matches_the_recurrence(length, chunk, heads, width, impl):
+    args = inputs(length, 2 if width < 128 else 1, length, heads, width)
     want = jax.jit(reference.delta_rule)(*args)
+    get_telemetry().reset()
     got = jax.jit(lambda *a: chunk_kda(*a, chunk=chunk))(*args)
+    assert f"gauge/kda/tier.{taken(impl, width)}" in get_telemetry().scalars()
     assert got.shape == want.shape and got.dtype == F32
     assert worst(got, want) < TOL
 
 
-@pytest.mark.parametrize("length,chunk", CASES)
-def test_all_five_gradients_match_the_recurrence(length, chunk):
-    args = inputs(100 + length, 2, length, 2, 16)
+@both
+@pytest.mark.parametrize("length,chunk,heads,width", CASES)
+def test_all_five_gradients_match_the_recurrence(length, chunk, heads, width,
+                                                 impl):
+    args = inputs(100 + length, 2 if width < 128 else 1, length, heads, width)
     ct = jax.random.normal(jax.random.PRNGKey(9), args[2].shape, F32)
     grads = lambda f: jax.jit(jax.grad(  # noqa: E731
         lambda *a: jnp.sum(f(*a) * ct), argnums=(0, 1, 2, 3, 4)))(*args)
@@ -77,11 +108,13 @@ def test_checkpoint_changes_no_number(checkpoint):
         assert worst(a, b) < TOL
 
 
+@both
+@pytest.mark.parametrize("width", [16, 128])
 @pytest.mark.parametrize("per_token", [5.0, 40.0, 200.0])
-def test_fast_decays_do_not_overflow(per_token):
+def test_fast_decays_do_not_overflow(per_token, width, impl):
     """Channels that lose exp(-200) a token: exp(-cumsum g) of a chunk
     would be inf in any float; the sub-blocks never form it."""
-    q, k, v, g, beta = inputs(7, 1, 128, 2, 16)
+    q, k, v, g, beta = inputs(7, 1, 128, 2, width)
     g = g.at[..., ::2].set(-per_token)     # every other channel very fast
     g = g.at[:, 40:44].set(0.0)            # and a few tokens that keep all
     want = jax.jit(reference.delta_rule)(q, k, v, g, beta)
@@ -111,17 +144,96 @@ def test_a_bfloat16_state_would_fail():
     assert worst(jax.jit(chunk_kda)(q, k, v, g, beta), want) < TOL
 
 
-def test_bfloat16_operands_keep_a_float32_state():
+@both
+@pytest.mark.parametrize("length,width", [(512, 16), (256, 128)])
+def test_bfloat16_operands_keep_a_float32_state(length, width, impl):
     """bf16 inputs: the output is bf16 and within bf16's rounding of the
     float32 recurrence on the same (rounded) inputs: nothing compounds
-    over 8 chunks."""
-    args = inputs(5, 1, 512, 2, 16, fastest=0.05)
+    over the chunks."""
+    args = inputs(5, 1, length, 2, width, fastest=0.05)
     q, k, v = (t.astype(jnp.bfloat16) for t in args[:3])
     got = jax.jit(chunk_kda)(q, k, v, *args[3:])
     want = jax.jit(reference.delta_rule)(
         q.astype(F32), k.astype(F32), v.astype(F32), *args[3:])
     assert got.dtype == jnp.bfloat16
     assert worst(got.astype(F32), want) < 3e-2
+
+
+@pytest.mark.parametrize("impl", ["pallas"], indirect=True)
+@pytest.mark.parametrize("dtype", [F32, jnp.bfloat16])
+def test_the_backward_kernel_is_the_vjp_of_the_xla_form(dtype, impl):
+    """The hand-written backward against autodiff of ``_chunk_kda`` on the
+    same inputs, all five cotangents; in bf16 both sides round the same
+    operands and part by bf16's rounding of the results."""
+    args = inputs(21, 1, 192, 4, 128)
+    args = tuple(t.astype(dtype) for t in args[:3]) + args[3:]
+    ct = jax.random.normal(jax.random.PRNGKey(4), args[2].shape, F32)
+    ct = ct.astype(dtype)
+    xla = lambda *a: linear_attention._chunk_kda(*a, chunk=64, sub=16)  # noqa: E731
+    want = jax.jit(lambda a: jax.vjp(xla, *a)[1](ct))(args)
+    got = jax.jit(lambda a: jax.vjp(
+        lambda *x: chunk_kda(*x, checkpoint=False), *a)[1](ct))(args)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert worst(a.astype(F32), b.astype(F32)) < (
+            TOL if dtype == F32 else 2e-2), name
+
+
+def lowered_primitives(fn, *args):
+    """The names of every primitive in ``fn``'s jaxpr, kernels' bodies
+    left out."""
+    names = set()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            names.add(eqn.primitive.name)
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return names
+
+
+@pytest.mark.parametrize("impl", ["pallas"], indirect=True)
+def test_the_rule_chooses_by_backend_and_shape(impl, monkeypatch):
+    """On a TPU a call at d = 128 and chunk 64 is the kernel pair, forward
+    and backward, with no scan over chunks and nothing of XLA's form; at
+    d = 32, at a chunk the kernels do not take, or off the TPU it is the
+    XLA form. The gauge says which."""
+    tel = get_telemetry()
+    grad = lambda **kw: jax.grad(  # noqa: E731
+        lambda *a: chunk_kda(*a, **kw).sum(), argnums=(0, 1, 2, 3, 4))
+
+    def tier_of(args, **kw):
+        tel.reset()
+        names = lowered_primitives(grad(**kw), *args)
+        tiers = sorted(k for k in tel.scalars() if k.startswith(
+            "gauge/kda/tier."))
+        assert len(tiers) == 1 and tel.counter_value("kda/calls") == 1
+        return tiers[0].rsplit(".", 1)[1], names
+
+    wide, narrow = inputs(1, 1, 128, 2, 128), inputs(1, 1, 128, 2, 32)
+    tier, names = tier_of(wide)
+    assert tier == "pallas" and "pallas_call" in names
+    assert not names & {"scan", "while", "dot_general", "cumsum", "exp"}
+    assert tier_of(wide, checkpoint=False)[0] == "pallas"
+    tier, names = tier_of(narrow)
+    assert tier == "xla" and "scan" in names and "pallas_call" not in names
+    assert tier_of(inputs(1, 1, 128, 2, 128), chunk=256)[0] == "xla"
+    mixed = (wide[0].astype(jnp.bfloat16),) + wide[1:]
+    assert tier_of(mixed)[0] == "xla"
+    monkeypatch.setattr(linear_attention, "_on_tpu", lambda: False)
+    tier, names = tier_of(wide)
+    assert tier == "xla" and "pallas_call" not in names
+    tel.reset()
+
+
+def test_the_cpu_takes_the_xla_form():
+    """Unsteered: this process has no TPU, so every call is XLA's."""
+    assert linear_attention._tier(*inputs(1, 1, 64, 2, 128)[:3], 64) == "xla"
+    assert not linear_attention._INTERPRET
 
 
 def test_chunk_must_be_sub_block_times_a_power_of_two():
