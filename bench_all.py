@@ -287,14 +287,11 @@ def bench_gpt_long_context():
     causal-attention term included (at L=8192 attention is ~38% of model
     FLOPs).
 
-    PR 8 additions: (1) the attention tier is now chosen by MEASUREMENT —
-    the config runs under ``PADDLE_TPU_ATTN_POLICY=bench`` (the TPU
-    default, forced here so CPU CI exercises the same path): the first
-    trace micro-benches the feasible tiers, and where a compile cache
-    directory is in effect the verdict persists beside it, so every
-    later run is a cache hit; (2) a
+    PR 8 additions: (1) the attention tier is the rule's
+    (``ops.attention._tier``; before PR 32 a race's); (2) a
     ``tokens_per_sec_forced_blockwise`` ablation column records what the
-    pre-policy streaming floor measures, so the tier win is a recorded
+    streaming floor measures (``set_attention_impl('blockwise')``), so
+    the tier win is a recorded
     number, not a claim; (3) a remat control-loop probe pins the HBM
     budget to 60% of the no-remat peak and records which checkpoint
     policy ``remat='auto'`` escalates to and the peak it measured —
@@ -303,7 +300,7 @@ def bench_gpt_long_context():
     import paddle_tpu as paddle
     from jax.sharding import Mesh
     from paddle_tpu.distributed.fleet.engine import ParallelTrainStep
-    from paddle_tpu.ops import remat_policy, tier_policy
+    from paddle_tpu.ops import remat_policy, set_attention_impl, tier_policy
     from paddle_tpu.profiler import get_telemetry
     from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
@@ -346,21 +343,16 @@ def bench_gpt_long_context():
 
     tel = get_telemetry()
     saved_env = {k: os.environ.get(k) for k in
-                 ("PADDLE_TPU_ATTN_POLICY", "PADDLE_TPU_DEVICE_HBM_BYTES")}
+                 ("PADDLE_TPU_DEVICE_HBM_BYTES",)}
     try:
         # -- tier ablation leg: the forced streaming floor ---------------
-        os.environ["PADDLE_TPU_ATTN_POLICY"] = "blockwise"
-        engine = build_engine()
-        abl_tps = measure(engine, max(2, iters // 2))
+        set_attention_impl("blockwise")
+        try:
+            engine = build_engine()
+            abl_tps = measure(engine, max(2, iters // 2))
+        finally:
+            set_attention_impl("auto")  # the rule, for the remaining legs
         del engine
-
-        # -- measured tier selection for the remaining legs --------------
-        if saved_env["PADDLE_TPU_ATTN_POLICY"] is None:
-            os.environ["PADDLE_TPU_ATTN_POLICY"] = "bench"
-        else:
-            os.environ["PADDLE_TPU_ATTN_POLICY"] = \
-                saved_env["PADDLE_TPU_ATTN_POLICY"]
-        tier_policy.reset()  # in-memory verdicts; the disk cache decides
 
         # -- remat control-loop probe ------------------------------------
         probe = build_engine(remat="auto")  # deferred build; probed by hand
@@ -382,7 +374,7 @@ def bench_gpt_long_context():
             del os.environ["PADDLE_TPU_DEVICE_HBM_BYTES"]
         del probe
 
-        # -- the headline leg: measured tier selection, clean telemetry --
+        # -- the headline leg: the rule's tier, clean telemetry ---------
         tel.reset()  # the record must carry ONLY this leg's attribution
         engine = build_engine()
         tps = measure(engine, iters)

@@ -103,17 +103,28 @@ def tier_fallbacks() -> int:
     return get_telemetry().counter_value("attn/tier_fallbacks")
 
 
-def tier_report(keys, fallbacks_before: int) -> dict:
-    """The attention-tier verdicts behind the shapes a phase dispatched
-    (``keys``: ``tier_policy.make_key`` / ``make_paged_key``; empty where
-    the policy does not measure), each with every candidate's time. On the
-    TPU a candidate the compiler refuses raises inside the micro-bench
+def tier_report(dense, paged_keys, fallbacks_before: int) -> dict:
+    """The attention tiers a phase ran. ``dense``: the (L, head_dim,
+    causal) of its dense calls, whose tier is a rule and is read back from
+    ``gauge/attn/tier.*``. ``paged_keys`` (``tier_policy.make_paged_key``;
+    empty where the paged policy does not measure): the verdicts behind its
+    decode shapes, each with both tiers' times: on the TPU a tier the
+    compiler refuses raises inside the micro-bench
     (``tier_policy.TierCompileError``), so a verdict that exists has a
     time for every tier that was offered — asserted here, not assumed."""
     from paddle_tpu.ops import tier_policy
+    from paddle_tpu.profiler.telemetry import get_telemetry
 
+    names = {i: name for name, i in tier_policy.TIER_IDS.items()}
+    scalars = get_telemetry().scalars()
+    tiers = {}
+    for L, d, causal in dense:
+        gauge = f"gauge/attn/tier.{tier_policy.gauge_key(L, d, causal)}"
+        check(scalars.get(gauge) in names,
+              f"{gauge} names no tier: {scalars.get(gauge)}")
+        tiers[gauge] = names[scalars[gauge]]
     verdicts = {}
-    for key in keys:
+    for key in paged_keys:
         v = tier_policy.registry().verdict(key)
         check(v is not None, f"no attention-tier verdict for {key}")
         check(v.get("candidates")
@@ -125,7 +136,8 @@ def tier_report(keys, fallbacks_before: int) -> dict:
     fallbacks = tier_fallbacks() - fallbacks_before
     check(fallbacks == 0, f"counter/attn/tier_fallbacks moved by "
                           f"{fallbacks}: a dispatch was rerouted")
-    return {"verdicts": verdicts, "tier_fallbacks": fallbacks}
+    return {"tiers": tiers, "verdicts": verdicts,
+            "tier_fallbacks": fallbacks}
 
 
 def _memory(devices) -> dict:
@@ -149,7 +161,6 @@ def train_phase(name, config, devices, mesh_shape=None, zero_stage=0,
     from jax.sharding import Mesh
 
     from bench import build_trainer, token_batch
-    from paddle_tpu.ops import tier_policy
 
     cache0, fallbacks0 = cache_state(), tier_fallbacks()
     if mesh_shape is None:
@@ -180,7 +191,7 @@ def train_phase(name, config, devices, mesh_shape=None, zero_stage=0,
         "mesh": dict(mesh.shape), "zero_stage": zero_stage,
         "batch": batch, "seq": seq, "layers": config.num_layers,
         "hidden": config.hidden_size,
-        # trace + attention-tier micro-bench + XLA compile + the first step
+        # trace + XLA compile + the first step
         "compile_s": round(first_step_s, 2),
         "smoke_step_ms": step_ms,
         "losses": [round(v, 5) for v in losses],
@@ -192,11 +203,8 @@ def train_phase(name, config, devices, mesh_shape=None, zero_stage=0,
     if mesh_shape is not None:
         record["spread"] = _check_spread(name, step, mesh)
     record["memory"] = _memory(mesh.devices.flat)
-    heads, head_dim = config.num_heads, config.hidden_size // config.num_heads
     record["attention"] = tier_report(
-        [tier_policy.make_key(heads, seq, head_dim, jnp.dtype("bfloat16"),
-                              True)]
-        if tier_policy.policy_mode() == "bench" else [], fallbacks0)
+        [(seq, config.hidden_size // config.num_heads, True)], [], fallbacks0)
     record["compile_cache"] = cache_report(cache0)
     emit(record)
     return record
@@ -342,14 +350,11 @@ def serve_phase(config, prompt_lens=(64, 128, 256, 512), new_tokens=32,
     heads, head_dim = config.num_heads, config.hidden_size // config.num_heads
     f32 = jnp.dtype("float32")
     keys = []
-    if tier_policy.paged_policy_mode() == "bench":
-        keys += [tier_policy.make_paged_key(t, heads, head_dim, m, block_size,
-                                            f32, False)
-                 for t, m in ((1, per_seq), (prefill_chunk, per_seq),
-                              (prefill_chunk, -(-len(longest) // block_size)))]
     if tier_policy.policy_mode() == "bench":
-        keys.append(tier_policy.make_key(heads, len(longest), head_dim, f32,
-                                         True))
+        keys = [tier_policy.make_paged_key(t, heads, head_dim, m, block_size,
+                                           f32, False)
+                for t, m in ((1, per_seq), (prefill_chunk, per_seq),
+                             (prefill_chunk, -(-len(longest) // block_size)))]
     record = {
         "phase": "serve", "ok": True,
         "layers": config.num_layers, "hidden": config.hidden_size,
@@ -366,7 +371,8 @@ def serve_phase(config, prompt_lens=(64, 128, 256, 512), new_tokens=32,
         "greedy_agree_frac": float(np.mean(
             paged.argmax(-1) == dense.argmax(-1))),
         "memory": _memory(jax.devices()[:1]),
-        "attention": tier_report(keys, fallbacks0),
+        "attention": tier_report([(len(longest), head_dim, True)], keys,
+                                 fallbacks0),
         "compile_cache": cache_report(cache0),
     }
     emit(record)

@@ -1,9 +1,8 @@
 """Transformer layers — parity with python/paddle/nn/layer/transformer.py.
 
 Attention math stays as plain jnp ops so XLA fuses QK^T→softmax→V into MXU
-pipelines (replacing the reference's hand-fused multihead_matmul_op.cu); a
-Pallas flash-attention kernel is used for long sequences when available
-(see paddle_tpu.ops.flash_attention).
+pipelines (replacing the reference's hand-fused multihead_matmul_op.cu).
+The model zoo's attention goes through paddle_tpu.ops.dot_product_attention.
 """
 from __future__ import annotations
 

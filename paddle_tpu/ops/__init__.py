@@ -3,7 +3,6 @@ reference's operators/fused/ tier, built for the MXU instead of CUDA."""
 from .attention import (  # noqa: F401
     blockwise_attention,
     dot_product_attention,
-    flash_attention,
     ring_attention,
     set_attention_impl,
     set_ring_context,

@@ -3,14 +3,25 @@ attention CUDA kernels (operators/fused/multihead_matmul_op.cu,
 fused_attention) plus net-new long-context support (ring/context parallelism,
 absent in the reference — SURVEY.md §5 'Long-context: Absent').
 
-Three tiers, one API:
-- ``blockwise_attention``: online-softmax scan over K blocks (FlashAttention
-  recurrence in pure lax) — O(seq) memory, differentiable, runs anywhere.
-- ``flash_attention``: Pallas TPU kernel for the forward (MXU-tiled, VMEM
-  blocked), custom_vjp whose backward recomputes via the blockwise path.
+Four tiers behind one call, ``dot_product_attention``, which picks one by
+a rule on the call and the backend (`_tier`; nothing is timed, nothing on
+disk is read):
+- ``xla_attention``: the [Lq, Lk] scores materialized chunk by chunk at the
+  XLA level. Every cell of the benchmark runs it: GPT-2 345M's causal
+  L = 1024 call reads ``attention_ms.train`` 24.67 ms in the step, against
+  87.43 ms for the jax-shipped Pallas flash kernel the same call once
+  raced against and sometimes drew (ledger, PR 29's GPT row: 56,935
+  against 38,355 tokens/s). That kernel and the race are gone (PR 32).
+- ``flash_tpu`` (ops/flash_tpu.py): the repo's Pallas kernel, for a causal
+  unbiased call on a TPU past L = 8192, where the scores no longer fit.
+- ``blockwise_attention``: online-softmax scan over K blocks (the
+  FlashAttention recurrence in pure lax): O(seq) memory, differentiable,
+  runs anywhere. The tests' reference, the path off the TPU, ring's inner
+  step.
 - ``ring_attention``: sequence-parallel attention inside shard_map — K/V
   shards rotate around the 'sp' mesh axis via ppermute (ICI neighbor
   transfers) while each device keeps running softmax stats for its Q shard.
+``paged_attention`` is the serving path's call over the KV-cache pool.
 """
 from __future__ import annotations
 
@@ -22,50 +33,41 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 logger = logging.getLogger("paddle_tpu.ops")
 
 __all__ = [
-    "blockwise_attention", "flash_attention", "ring_attention",
+    "blockwise_attention", "ring_attention",
     "xla_attention", "dot_product_attention", "set_attention_impl",
     "set_ring_context", "paged_attention",
 ]
 
-# Attention implementation selector. 'auto' (default) picks per context:
-# ring for sp-sharded, the materialized XLA path on TPU up to a
-# per-context length threshold — measured fastest end-to-end on v5e for
-# GPT-2 345M (L=1024, d=64: the big batched einsums tile onto the MXU
-# better than per-head Pallas kernel ops) AND, q-chunked, for causal
-# unbiased sequences up to L=8192 (46.5k vs 27.5k tok/s on the longctx
-# bench, r5) — then the repo's flash_tpu Mosaic kernel for longer causal
-# sequences (the materialized scores exhaust HBM and blockwise is 8-10x
-# slower). 'pallas' (the jax-shipped kernel) and 'flash_tpu' can
-# also be forced explicitly.
-_IMPL = os.environ.get("PADDLE_TPU_ATTENTION", "auto")
-# beyond these lengths the materialized scores dominate HBM; stream
-# instead. Two thresholds (r5): CAUSAL unbiased attention runs q-chunked
-# (_q_chunks — fully-masked blocks never computed, ~0.53·L² footprint) and
-# measured 46.5k tok/s at GPT-small L=8192 b=1 vs 27.5k on flash_tpu +
-# recompute, so its auto threshold is 8192; everything else computes (and
-# saves for its backward) the full [b,h,L,L] scores, chunk by chunk, and
-# keeps the stricter 4096.
-_XLA_MAX_SEQ = int(os.environ.get("PADDLE_TPU_ATTENTION_MAX_SEQ", "4096"))
-_XLA_MAX_SEQ_CAUSAL = int(os.environ.get(
-    "PADDLE_TPU_ATTENTION_MAX_SEQ_CAUSAL", "8192"))
+# 'auto' is the rule of `_tier`; `set_attention_impl` names a tier instead
+_IMPL = "auto"
+# past these lengths the materialized scores dominate HBM and a call
+# streams instead. The causal unbiased call runs q-chunked with its
+# fully-masked blocks never computed (`_q_chunks`, about 0.53·L² of
+# scores); every other call computes, and saves for its backward, the
+# whole [b, h, L, L] square, and keeps the stricter length.
+_XLA_MAX_SEQ = 4096
+_XLA_MAX_SEQ_CAUSAL = 8192
 
 
 def set_attention_impl(impl: str):
-    """impl ∈ {'auto', 'pallas', 'flash_tpu', 'xla', 'blockwise'}.
+    """impl ∈ {'auto', 'xla', 'flash_tpu', 'blockwise'}: the one way to
+    name a tier instead of taking the rule's (`_tier`), for a test or a
+    user who must. A named tier also keeps a registered ring mesh from
+    taking the call.
 
-    'pallas' selects the jax-shipped Mosaic flash kernel; 'flash_tpu' the
-    repo's layout-native Pallas kernel (ops/flash_tpu.py). The selector is
-    read at TRACE time: functions already jitted keep the implementation
-    they compiled with (jit cache). Call before building the train/eval
-    step, or clear caches, for the change to take effect.
+    'flash_tpu' is the repo's layout-native Pallas kernel
+    (ops/flash_tpu.py); it holds on a TPU for a causal unbiased call and
+    is 'xla' elsewhere. The selector is read at TRACE time: functions
+    already jitted keep the implementation they compiled with (jit
+    cache). Call before building the train/eval step, or clear caches,
+    for the change to take effect.
     """
     global _IMPL
-    if impl not in ("auto", "pallas", "flash_tpu", "xla", "blockwise"):
+    if impl not in ("auto", "xla", "flash_tpu", "blockwise"):
         raise ValueError(f"unknown attention impl {impl!r}")
     _IMPL = impl
 
@@ -148,188 +150,6 @@ def blockwise_attention(q, k, v, causal=False, block_k=512, bias=None,
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU flash-attention forward
-# ---------------------------------------------------------------------------
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, sm_scale,
-                      seq_len):
-    from jax.experimental import pallas as pl
-
-    # NOTE: all index math is pinned to int32 — with jax_enable_x64 on,
-    # python-int promotion would inject int64 converts, which the Mosaic
-    # lowering cannot handle (infinite recursion in convert_element_type).
-    i32 = jnp.int32
-    q = q_ref[0].astype(jnp.float32)  # [block_q, d]
-    block_q, d = q.shape
-    qi = pl.program_id(1).astype(i32)
-    q_pos = qi * i32(block_q) + jax.lax.broadcasted_iota(
-        i32, (block_q, block_k), 0)
-
-    nk = seq_len // block_k
-
-    def body(i, carry):
-        acc, m, l = carry
-        i = i.astype(i32)
-        k = k_ref[0, pl.dslice(i * i32(block_k), block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.dslice(i * i32(block_k), block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            k_pos = i * i32(block_k) + jax.lax.broadcasted_iota(
-                i32, (block_q, block_k), 1
-            )
-            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + p.sum(axis=-1)
-        acc_new = acc * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        return acc_new, m_new, l_new
-
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    if causal:
-        # only scan k blocks up to (and including) this q block's diagonal
-        upper = jnp.minimum((qi + i32(1)) * i32(block_q) // i32(block_k)
-                            + i32(1), i32(nk))
-    else:
-        upper = i32(nk)
-    acc, m, l = jax.lax.fori_loop(i32(0), upper, body, (acc0, m0, l0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-
-
-def _flash_fwd_pallas(q, k, v, causal, block_q, block_k):
-    from jax.experimental import pallas as pl
-
-    b, h, L, d = q.shape
-    sm_scale = 1.0 / math.sqrt(d)
-    bh = b * h
-    q3 = q.reshape(bh, L, d)
-    k3 = k.reshape(bh, L, d)
-    v3 = v.reshape(bh, L, d)
-    grid = (bh, L // block_q)
-    out = pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, block_k=block_k, causal=causal,
-                          sm_scale=sm_scale, seq_len=L),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, L, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, L, d), lambda i, j: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, L, d), q.dtype),
-    )(q3, k3, v3)
-    return out.reshape(b, h, L, d)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention(q, k, v, causal=False, block_q=256, block_k=256):
-    """Pallas-accelerated attention; falls back to blockwise when shapes or
-    platform don't fit the kernel. [b, h, l, d] layout."""
-    return _flash_attention_impl(q, k, v, causal, block_q, block_k)
-
-
-def _flash_attention_impl(q, k, v, causal, block_q, block_k):
-    L = q.shape[2]
-    d = q.shape[3]
-    on_tpu = jax.default_backend() == "tpu"
-    fits = (L % block_q == 0 and L % block_k == 0 and d % 128 == 0
-            and k.shape[2] == L)
-    if on_tpu and fits:
-        return _flash_fwd_pallas(q, k, v, causal, block_q, block_k)
-    if on_tpu:
-        # the kernel was on the table (TPU) and the SHAPE knocked it off:
-        # that silent 8-10x drop must be counted and named (off-TPU the
-        # blockwise path is the documented behavior, not a fallback)
-        _count_fallback(
-            "flash", q.shape,
-            f"shape does not tile the Pallas forward (needs L % "
-            f"{block_q}/{block_k} == 0, d % 128 == 0, Lq == Lk)")
-    return blockwise_attention(q, k, v, causal=causal, block_k=block_k)
-
-
-def jax_flash_attention(q, k, v, causal=False, block_q=None, block_k=None):
-    """The jax-shipped Mosaic flash-attention kernel (fwd AND bwd kernels,
-    [b, h, l, d]), with block sizes clamped to the shape. Falls back to the
-    local ``flash_attention`` tier (→ blockwise), counted, when the shape
-    doesn't tile; a kernel the compiler refuses is an error."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
-
-    L = q.shape[2]
-    bq = min(block_q or 512, L)
-    bk = min(block_k or 512, L)
-    if L % bq != 0 or L % bk != 0 or k.shape[2] != L:
-        if jax.default_backend() == "tpu":
-            _count_fallback(
-                "pallas", q.shape,
-                f"shape does not tile the jax flash kernel "
-                f"(L % {bq}/{bk} != 0 or Lq != Lk)")
-        return flash_attention(q, k, v, causal)
-    bs = BlockSizes(
-        block_q=bq, block_k_major=bk, block_k=bk, block_b=1,
-        block_q_major_dkv=bq, block_k_major_dkv=bk, block_k_dkv=bk,
-        block_q_dkv=bq, block_k_major_dq=bk, block_k_dq=bk, block_q_dq=bq,
-    )
-    return _jax_flash_x32(q, k, v, causal, bs)
-
-
-# The jax-shipped kernel's index math assumes 32-bit python-int promotion
-# and this repo enables x64 globally. Its backward kernels are traced when
-# the cotangent arrives — long after a ``with enable_x64(False)`` around
-# the forward call has exited (on the chip: "lax.select requires arguments
-# to have the same dtypes, got int64, int32") — so forward and backward
-# each get their own 32-bit scope through this custom_vjp.
-def _jax_flash_call(q, k, v, causal, bs):
-    from jax.experimental.pallas.ops.tpu.flash_attention import \
-        flash_attention as _fa
-
-    return _fa(q, k, v, causal=causal, block_sizes=bs,
-               sm_scale=1.0 / math.sqrt(q.shape[3]))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _jax_flash_x32(q, k, v, causal, bs):
-    with jax.enable_x64(False):
-        return _jax_flash_call(q, k, v, causal, bs)
-
-
-def _jax_flash_x32_fwd(q, k, v, causal, bs):
-    with jax.enable_x64(False):
-        return jax.vjp(functools.partial(_jax_flash_call, causal=causal,
-                                         bs=bs), q, k, v)
-
-
-def _jax_flash_x32_bwd(causal, bs, vjp, g):
-    with jax.enable_x64(False):
-        return vjp(g)
-
-
-_jax_flash_x32.defvjp(_jax_flash_x32_fwd, _jax_flash_x32_bwd)
-
-
-def _flash_fwd_rule(q, k, v, causal, block_q, block_k):
-    out = _flash_attention_impl(q, k, v, causal, block_q, block_k)
-    return out, (q, k, v)
-
-
-def _flash_bwd_rule(causal, block_q, block_k, res, g):
-    q, k, v = res
-    # recompute-based backward through the blockwise recurrence
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: blockwise_attention(q_, k_, v_, causal=causal,
-                                               block_k=block_k),
-        q, k, v,
-    )
-    return vjp(g)
-
-
-flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
-
-
-# ---------------------------------------------------------------------------
 # Ring attention (sequence/context parallelism over a mesh axis)
 # ---------------------------------------------------------------------------
 def _ring_pass(q, k, v, axis_name, causal, fn, init):
@@ -405,8 +225,8 @@ def ring_attention(q, k, v, axis_name, causal=False, block_k=512):
     (lax.ppermute → ICI neighbor copy, overlapping with the next compute).
 
     The backward is a hand-written recompute pass (custom_vjp, like the
-    flash/chunked tiers): the forward saves only the [b, h, L_local]
-    logsumexp — never the O(L·L/ring) probability blocks autodiff-through-
+    flash_tpu tier's and the latent call's): the forward saves only the
+    [b, h, L_local] logsumexp — never the O(L·L/ring) probability blocks autodiff-through-
     scan would stack per rotation — and the backward re-runs the ring,
     recomputing each block's probabilities from the saved statistic while
     dK/dV partial sums travel around the ring WITH the K/V shards they
@@ -489,44 +309,12 @@ def set_ring_context(mesh, axis: Optional[str], batch_axis=None) -> None:
 
 
 def _ring_auto_ok(L: int, causal: bool, bias) -> bool:
-    from . import tier_policy
-
     mesh, axis = _ring_ctx["mesh"], _ring_ctx["axis"]
     if mesh is None or axis is None or not causal or bias is not None:
         return False
     if axis not in mesh.axis_names or mesh.shape[axis] <= 1:
         return False
-    # an EXPLICIT policy override outranks promotion: a forced tier or a
-    # pinned heuristic must measure exactly what it names (the bench
-    # ablation legs depend on this); the unset default and 'bench' leave
-    # the engine's sp_axis request in force
-    forced = tier_policy.forced_mode()
-    if forced in ("xla", "blockwise", "flash_tpu", "pallas", "heuristic"):
-        return False
-    size = mesh.shape[axis]
-    return L % size == 0 and (L >= _ring_min_seq() or forced == "ring")
-
-
-def _ring_unavailable_reason(L: int, causal: bool, bias) -> str:
-    """Why ``_ring_auto_ok`` said no, for the forced-ring fallback
-    warning — the operator gets the ACTUAL blocker, not a generic hint
-    (the usual failure is not a missing context at all)."""
-    mesh, axis = _ring_ctx["mesh"], _ring_ctx["axis"]
-    if mesh is None or axis is None:
-        return ("no ring mesh context is registered "
-                "(fleet.ParallelTrainStep(sp_axis=) / "
-                "ops.attention.set_ring_context)")
-    if not causal:
-        return "the ring path only supports causal attention"
-    if bias is not None:
-        return "the ring path does not support an attention bias"
-    if axis not in mesh.axis_names or mesh.shape[axis] <= 1:
-        return (f"registered axis {axis!r} is not a multi-device axis of "
-                f"the mesh {dict(mesh.shape)}")
-    if L % mesh.shape[axis] != 0:
-        return (f"sequence length {L} does not divide the ring size "
-                f"{mesh.shape[axis]}")
-    return "the ring context was cleared by a later engine"
+    return L % mesh.shape[axis] == 0 and L >= _ring_min_seq()
 
 
 def _ring_sharded(q, k, v, causal, blhd):
@@ -562,8 +350,8 @@ def _ring_sharded(q, k, v, causal, blhd):
 # attends the query chunk (T=1 for plain decode, T=k+1 for speculative
 # verify, T=chunk for prefill) against them. Two XLA-level tiers with
 # genuinely different memory/compute profiles, selected by
-# tier_policy.select_paged (micro-benched + verdict-cached like every
-# training tier):
+# tier_policy.select_paged (micro-benched + verdict-cached: the one
+# choice of kernel here that is still measured, ROADMAP D2b):
 # - 'paged_gather': one gather of the whole context then one fused
 #   masked softmax — fastest while the context is score-tensor-small;
 # - 'paged_scan': lax.scan over pages with online softmax — O(block)
@@ -704,35 +492,23 @@ def paged_attention(q, k_pages, v_pages, block_tables, q_positions,
 # output (`_weights_pv`; inline with bf16 row statistics for the causal
 # unbiased call, `_bf16_row_stats`).
 #
-# minimum q-chunk rows (sweepable; 128 measured optimum on v5e)
-_CAUSAL_CHUNK = int(os.environ.get("PADDLE_TPU_ATTN_MIN_CHUNK", "128"))
-# max q-chunks (sweepable: more causal chunks skip more upper-triangle work
-# but emit more ops). Together with the 128-row minimum the default of 32
-# gives the measured v5e optima at both ends: L=1024 -> c=128 (8 chunks;
-# c=256 measured -6%) and L=8192 -> c=256 (32 chunks; +27% over the old
-# 16-chunk default — 47.0k -> 60.0k tok/s on the longctx config; c=128
-# and c=64 both measured worse there)
-_CAUSAL_MAX_CHUNKS = int(os.environ.get("PADDLE_TPU_ATTN_CHUNKS", "32"))
-# sweep knob (bench tuning): force the [b,h,l,d] layout path
-_FORCE_BHLD = os.environ.get("PADDLE_TPU_ATTN_LAYOUT", "") == "bhld"
-# bf16 score STORAGE, default ON for bf16/f16 inputs: the centered logits
-# already round-trip through bf16 before exp, and softmax cancels the max
-# shift exactly (m only guards overflow), so bf16-stored scores are
+# least rows of a q-chunk, and most chunks of a call: more causal chunks
+# skip more of the masked triangle but emit more operations. Together they
+# give L = 1024 -> 8 chunks of 128 rows (GPT-2 345M's call) and
+# L = 8192 -> 32 chunks of 256.
+_CAUSAL_CHUNK = 128
+_CAUSAL_MAX_CHUNKS = 32
+# scores are STORED in the inputs' dtype for bf16/f16 inputs: the centred
+# logits already round-trip through bf16 before exp, and softmax cancels
+# the max shift exactly (m only guards overflow), so bf16-stored scores are
 # numerically ~equivalent (~1 ulp of bf16 either way) while halving the
-# O(L²) tensor's bytes. Set =0 for f32 score storage.
-_SCORE_BF16 = os.environ.get("PADDLE_TPU_ATTN_SCORE_BF16", "1") == "1"
-# sweep knob: hand-written chunked-attention backward (custom_vjp) vs
-# autodiff of the same forward. Default OFF — measured end-to-end on v5e
-# GPT-2 345M the manual rule is ~3% SLOWER (52.4k vs 53.9k tok/s/chip):
-# its per-chunk dk/dv pad+sum accumulation costs more than autodiff's
-# cotangent accumulation saves, and the backward's contract-q dots hit the
-# same ~43 TFLOP/s emitter ceiling either way (every orientation rewrite —
-# 'bhdk' outputs, pre-transposed operands, optimization barriers — was
-# canonicalized by XLA to the identical dot and measured identical).
-# Kept as an opt-in for the causal unbiased call: it halves residual
-# memory bookkeeping for long-L sweeps and documents the measured negative
-# result.
-_MANUAL_ATTN_VJP = os.environ.get("PADDLE_TPU_ATTN_MANUAL_VJP", "0") == "1"
+# O(L²) tensor's bytes.
+_SCORE_BF16 = True
+# the causal unbiased call's hand-written backward (`_causal_chunked_bwd`,
+# the latent call's) makes the exp weights again from a chunk's row maxima
+# instead of saving them: one more QK einsum and exp a chunk, for the
+# largest residual of the call, flash attention's trade at the XLA level.
+_REMAT_E = True
 
 
 def _einsum_eqs(blhd: bool):
@@ -844,7 +620,7 @@ def _chunk_logits(q, k, chunk, blhd, causal, bias=None, m=None):
         s = jnp.where(mask, s, neg)
     if m is None:
         m = jax.lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True))
-    if sdt != jnp.float32:  # honors the PADDLE_TPU_ATTN_SCORE_BF16 opt-out
+    if sdt != jnp.float32:
         return (s - m).astype(q.dtype).astype(jnp.float32), m
     return s - m, m
 
@@ -859,17 +635,6 @@ def _chunk_e(q, k, chunk, blhd, causal, m=None):
     divide_subtract fusions). Returns (e, m)."""
     x, m = _chunk_logits(q, k, chunk, blhd, causal, m=m)
     return jnp.exp(x).astype(q.dtype), m
-
-
-def _remat_e() -> bool:
-    """Backward recomputes the exp weights instead of saving them (default
-    ON). The saved-e residuals are the single largest non-matmul cost of
-    the GPT-2 345M step: ~148 MB/layer of bf16 written in fwd, re-read in
-    bwd, PLUS ~5 ms/step of relayout copies XLA inserts moving them across
-    the custom_vjp boundary (profiled shapes bf16[8,16,128,ub]). Recompute
-    costs one extra QK einsum + exp per chunk (~0.2 ms/layer) — flash
-    attention's trade, expressed at the XLA level."""
-    return os.environ.get("PADDLE_TPU_ATTN_REMAT_E", "1") == "1"
 
 
 def _weights_pv_impl(x, v, blhd, dtype):
@@ -976,8 +741,7 @@ _chunk_attend_jit = jax.jit(_chunk_attend,
 def _chunked_fwd_impl(q, k, v, blhd: bool, causal: bool, bias=None):
     """Forward pass; returns (out, residuals per chunk, for
     `_causal_chunked_bwd`, where `_bf16_row_stats`). Residual slot 4 holds
-    the exp weights (save-e mode) or their per-chunk row maxima (remat
-    mode, `_remat_e`)."""
+    the exp weights or, under `_REMAT_E`, their per-chunk row maxima."""
     from ..profiler.telemetry import get_telemetry
 
     axis_l = 1 if blhd else 2
@@ -994,7 +758,6 @@ def _chunked_fwd_impl(q, k, v, blhd: bool, causal: bool, bias=None):
                      for chunk in chunks]), None
     sl = functools.partial(jax.lax.slice_in_dim, axis=axis_l)
     eq = _einsum_eqs(blhd)
-    remat = _remat_e()
     outs, aux, invs = [], [], []
     for chunk in chunks:
         e, m = _chunk_e(q, k, chunk, blhd, causal)
@@ -1003,7 +766,7 @@ def _chunked_fwd_impl(q, k, v, blhd: bool, causal: bool, bias=None):
         o = jnp.einsum(eq[1], e.astype(q.dtype), vi)
         inv = (1.0 / l_sum).astype(q.dtype)
         outs.append(o * _inv_rows(inv, blhd))
-        aux.append(m if remat else e)
+        aux.append(m if _REMAT_E else e)
         invs.append(inv)
     out = join(outs)
     return out, (q, k, v, out, tuple(aux), tuple(invs))
@@ -1012,8 +775,8 @@ def _chunked_fwd_impl(q, k, v, blhd: bool, causal: bool, bias=None):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _causal_chunked(q, k, v, blhd: bool):
     """Causal unbiased self-attention through `_chunked_fwd_impl` with a
-    hand-written backward (`_causal_chunked_bwd`), the opt-in of
-    ``PADDLE_TPU_ATTN_MANUAL_VJP``: every backward contraction stays in
+    hand-written backward (`_causal_chunked_bwd`), the latent call's by
+    rule (`xla_attention`): every backward contraction stays in
     the forward's layout family and the 1/l normalization folds into the
     [.., c, d] dO chunk (flash's backward trick at the XLA level), so no
     O(L²) divide pass exists in either direction."""
@@ -1033,7 +796,6 @@ def _causal_chunked_bwd(blhd, res, g):
     d = q.shape[-1]
     scale = jnp.asarray(1.0 / math.sqrt(d), q.dtype)
     dP_eq, dq_eq, dk_eq, dv_eq, delta_eq = _BWD_EQS[blhd]
-    remat = _remat_e()
 
     dqs, dks, dvs = [], [], []
     for i, chunk in enumerate(_call_chunks(q, k, v, blhd, True)):
@@ -1042,7 +804,7 @@ def _causal_chunked_bwd(blhd, res, g):
         ki, vi = sl(k, 0, ub), sl(v, 0, ub)
         gi = sl(g, lo, hi)
         oi = sl(out, lo, hi)
-        if remat:  # aux holds the chunk maxima; e recomputed bitwise
+        if _REMAT_E:  # aux holds the chunk maxima; e recomputed bitwise
             e, _ = _chunk_e(q, k, chunk, blhd, True, m=aux[i])
         else:
             e = aux[i]
@@ -1082,13 +844,11 @@ def xla_attention(q, k, v, causal=False, bias=None, layout="bhld"):
     """softmax(QKᵀ + bias)V with the [Lq, Lk] scores materialized
     (XLA-level), one q-chunked body for every call (`_chunked_fwd_impl`).
 
-    TPU-first details (profile-driven on v5e: GPT-2 345M 12.9k→53k
-    tok/s/chip end-to-end vs the scan-based blockwise path; BERT-large's
-    biased non-causal call, PERF.md section 6 "PR 29"):
+    TPU-first details (profile-driven on the v5e; GPT-2 345M's causal call
+    and BERT-large's biased non-causal one, PERF.md section 6):
     - scores ACCUMULATE in f32 on the MXU regardless of storage dtype; for
       bf16/f16 inputs the stored scores, centered logits, and unnormalized
-      probabilities round-trip through the input dtype by default
-      (``PADDLE_TPU_ATTN_SCORE_BF16=0`` opts back into f32 storage) —
+      probabilities round-trip through the input dtype (`_SCORE_BF16`) —
       softmax cancels the max shift exactly, so this is numerically ~1 ulp
       of bf16 either way while halving the O(L²) HBM bytes;
     - self-attention runs q-chunked (`_q_chunks`): a **causal** chunk only
@@ -1103,18 +863,18 @@ def xla_attention(q, k, v, causal=False, bias=None, layout="bhld"):
       divide runs on the [.., c, d] output, never in score space;
     - ``layout='blhd'`` contracts [b, l, h, d] operands directly, letting
       the model skip the four [b,h,l,d] transpose copies per layer.
-    The causal unbiased call's backward is autodiff of that forward (or
-    `_causal_chunked_bwd` under ``PADDLE_TPU_ATTN_MANUAL_VJP=1``); every
-    other call sees its values centred on their mean over the keys
-    (`_centred`) and takes a hand-written rule for the chunk's tail
-    (`_weights_pv`), so that the rows of dS sum to zero in bf16 too.
+    The causal unbiased call's backward is autodiff of that forward (or,
+    for a latent call, `_causal_chunked_bwd`); every other call sees its
+    values centred on their mean over the keys (`_centred`) and takes a
+    hand-written rule for the chunk's tail (`_weights_pv`), so that the
+    rows of dS sum to zero in bf16 too.
     """
     blhd = layout == "blhd"
     # the hand-written backward keeps a chunk's row maxima and makes its
     # exp weights again: a latent call takes it by rule, because at 32
     # heads of 8192 keys autodiff's saved weights are 2.3 GB a layer and
     # were the step's peak
-    if ((_MANUAL_ATTN_VJP or _latent(q, v)) and causal and bias is None
+    if (_latent(q, v) and causal and bias is None
             and len(_call_chunks(q, k, v, blhd, True)) > 1):
         return _causal_chunked(q, k, v, blhd)
     return _chunked_fwd_impl(q, k, v, blhd, causal, bias)[0]
@@ -1144,33 +904,76 @@ def _count_fallback(tier: str, shape, reason: str) -> None:
             tier, tuple(shape), reason)
 
 
-# impl-name → tier-policy name (the kernel impls split per backend)
-_TIER_OF_IMPL = {"jax_flash": "pallas", "flash": "pallas"}
-
-
 def dot_product_attention(q, k, v, causal=False, bias=None, sp_axis=None,
                           use_flash=True, layout="bhld"):
-    """Attention dispatch by context, measurement, and
-    ``set_attention_impl``: ring (sp sharded, or auto-promoted when an
-    engine registered a ring mesh via ``set_ring_context`` and the
-    sequence is long enough) > the benchmarked tier policy
-    (``ops.tier_policy``, consulted by ``impl='auto'``) > the threshold
-    heuristic > blockwise fallback.
+    """Attention, on the tier that `_tier` names for this call: a rule on
+    the call, the backend and ``set_attention_impl``, decided at TRACE
+    time and baked into the compiled program (zero per-step work, zero
+    extra retraces). The tier's id is published as ``gauge/attn/tier.*``.
 
     ``layout='blhd'`` passes [b, l, h, d] operands straight into the XLA
     path (causal or not, with or without a ``bias``) and the flash_tpu
-    path (no transpose copies); impls that need [b, h, l, d] get a
-    transposed view and transpose back. ``v`` may be narrower or wider
-    than ``q`` and ``k``: such a call takes the XLA path by rule. ``bias`` broadcasts against
-    [b, h, Lq, Lk] under either layout. All selection happens at TRACE
-    time: the chosen tier is baked into the compiled program (zero
-    per-step work, zero extra retraces).
+    path (no transpose copies); blockwise and ring get a transposed view
+    and transpose back. ``v`` may be narrower or wider than ``q`` and
+    ``k``: such a call takes the XLA path. ``bias`` broadcasts against
+    [b, h, Lq, Lk] under either layout. ``use_flash=False`` asks for the
+    exact f32 blockwise recurrence (the model-level flag selects numerics,
+    not just a kernel).
 
     Whatever tier runs, its operations carry the ``attention`` scope:
     score space only (QK, mask or bias, softmax, PV), which a trace
     reduction tells from the projections around it."""
     with jax.named_scope("attention"):
         return _dispatch(q, k, v, causal, bias, sp_axis, use_flash, layout)
+
+
+def _tier(q, k, v, causal, bias, sp_axis, use_flash, blhd) -> str:
+    """The tier a call takes, the only place that decides it:
+
+    - ``sp_axis`` given, or a ring mesh registered and `_ring_auto_ok`:
+      ``ring``;
+    - values narrower or wider than the keys (latent attention: 192
+      against 128): ``xla``, the one tier that takes two head widths;
+    - a tier named by ``set_attention_impl``: that tier (``flash_tpu``
+      holds on a TPU for a causal unbiased call and is ``xla`` elsewhere);
+    - ``use_flash=False``, or off the TPU: ``blockwise``;
+    - on a TPU up to L = 8192 for a causal unbiased call and up to 4096
+      for any other: ``xla``, which read 24.67 ms of the step against the
+      jax-shipped Pallas kernel's 87.43 at GPT-2 345M's call (ledger,
+      PR 29's GPT row);
+    - past that, ``flash_tpu`` for a causal unbiased call and
+      ``blockwise`` for the rest: 8-10x slower than either, but O(L) in
+      memory;
+    - ``flash_tpu`` on a shape its kernel does not tile
+      (`_flash_tpu_fits`): ``blockwise``, counted in
+      ``counter/attn/tier_fallbacks``."""
+    L = q.shape[1 if blhd else 2]
+    if sp_axis is not None or (_IMPL == "auto"
+                               and _ring_auto_ok(L, causal, bias)):
+        return "ring"
+    if _latent(q, v):
+        return "xla"
+    on_tpu = jax.default_backend() == "tpu"
+    plain = causal and bias is None
+    if _IMPL != "auto":
+        tier = _IMPL if (_IMPL != "flash_tpu" or (on_tpu and plain)) else "xla"
+    elif not use_flash or not on_tpu:
+        tier = "blockwise"
+    elif L <= (_XLA_MAX_SEQ_CAUSAL if plain else _XLA_MAX_SEQ):
+        tier = "xla"
+    else:
+        tier = "flash_tpu" if plain else "blockwise"
+    if tier == "flash_tpu" and not _flash_tpu_fits(q, k, blhd=blhd):
+        # the kernel's own fallback is the materialized O(L²) form, wrong
+        # at this length: keep the memory-safe streaming path, and say so
+        _count_fallback(
+            "flash_tpu", q.shape,
+            "shape does not fit the flash_tpu kernel (needs Lq == Lk, "
+            "L % 256 == 0, heads*dim % 128 == 0, K and V of one batch "
+            "row within its VMEM budget) — streaming via blockwise "
+            "instead, ~8-10x slower at long L")
+        tier = "blockwise"
+    return tier
 
 
 def _dispatch(q, k, v, causal, bias, sp_axis, use_flash, layout):
@@ -1182,146 +985,47 @@ def _dispatch(q, k, v, causal, bias, sp_axis, use_flash, layout):
     # contains (marks a bench record "attention-bearing" for the tier
     # gate); in eager mode it counts calls, which is equally true
     get_telemetry().counter("attn/calls")
+    tier = _tier(q, k, v, causal, bias, sp_axis, use_flash, blhd)
+    # every call publishes its tier (with ``sp_axis`` L is the LOCAL
+    # shard): the tier gate requires one on every attention-bearing record
+    tier_policy.publish_tier(q.shape[1 if blhd else 2], q.shape[-1], causal,
+                             tier)
     tr = lambda t: t.transpose(0, 2, 1, 3)
-    L = q.shape[1] if blhd else q.shape[2]
-    d = q.shape[-1]
-    if sp_axis is not None:
-        # explicit sequence-sharded call (L here is the LOCAL shard):
-        # the verdict gauge must still land — the tier gate requires one
-        # on every attention-bearing record
-        tier_policy.publish_tier(L, d, causal, "ring")
-        if blhd:
-            return tr(ring_attention(tr(q), tr(k), tr(v), sp_axis,
-                                     causal, 512))
-        return ring_attention(q, k, v, sp_axis, causal, 512)
-    if _IMPL == "auto" and _ring_auto_ok(L, causal, bias):
-        tier_policy.publish_tier(L, d, causal, "ring")
-        return _ring_sharded(q, k, v, causal, blhd)
-    if v.shape[-1] != d:
-        # values narrower than the keys (latent attention: 192 against
-        # 128): the XLA chunk body by rule, as a biased call takes it; the
-        # kernel tiers assume one head width, and a race would time them
-        # on other shapes than the call's
-        impl = "xla"
-    else:
-        impl = _select_impl(q, k, bias, use_flash, causal, blhd)
-    tier_policy.publish_tier(L, d, causal, _TIER_OF_IMPL.get(impl, impl))
-    if blhd:
-        if not _FORCE_BHLD:
-            if impl == "flash_tpu":
-                from .flash_tpu import flash_attention_blhd
-
-                return flash_attention_blhd(q, k, v, causal)
-            if impl == "xla":
-                return xla_attention(q, k, v, causal=causal, bias=bias,
-                                     layout="blhd")
-        return tr(_apply_impl(impl, tr(q), tr(k), tr(v), causal, bias))
-    return _apply_impl(impl, q, k, v, causal, bias)
-
-
-def _apply_impl(impl, q, k, v, causal, bias):
-    """Run one resolved impl on [b, h, l, d] operands."""
-    if impl == "flash_tpu":
+    if tier == "xla":
+        return xla_attention(q, k, v, causal=causal, bias=bias, layout=layout)
+    if tier == "flash_tpu":
         from .flash_tpu import flash_attention_blhd
 
-        tr = lambda t: t.transpose(0, 2, 1, 3)
+        if blhd:
+            return flash_attention_blhd(q, k, v, causal)
         return tr(flash_attention_blhd(tr(q), tr(k), tr(v), causal))
-    if impl == "jax_flash":
-        return jax_flash_attention(q, k, v, causal=causal)
-    if impl == "flash":
-        return flash_attention(q, k, v, causal)
-    if impl == "xla":
-        return xla_attention(q, k, v, causal=causal, bias=bias)
-    return blockwise_attention(q, k, v, causal=causal, bias=bias)
-
-
-def _select_impl(q, k, bias, use_flash, causal, blhd):
-    """The impl this dispatch will take, both layouts agreeing: the
-    benchmarked tier policy when it has jurisdiction (``impl='auto'``,
-    unbiased, ``use_flash``), the measured-threshold heuristic
-    (``_resolve_impl``) otherwise."""
-    from . import tier_policy
-
-    L = q.shape[1] if blhd else q.shape[2]
-    if _IMPL == "auto" and bias is None and use_flash:
-        mode = tier_policy.policy_mode()
-        choice = None
-        if mode in ("xla", "blockwise", "flash_tpu", "pallas"):
-            choice = mode  # PADDLE_TPU_ATTN_POLICY forced tier wins
-        elif mode == "ring":
-            _count_fallback(
-                "ring", q.shape,
-                "PADDLE_TPU_ATTN_POLICY=ring but "
-                + _ring_unavailable_reason(L, causal, bias))
-        elif mode == "bench":
-            h = q.shape[2] if blhd else q.shape[1]
-            choice = tier_policy.select(
-                h, L, q.shape[-1], q.dtype, causal,
-                _tier_candidates(q, k, causal, blhd))
-        if choice is not None:
-            return _impl_of_tier(choice, q, k, causal, blhd)
-    impl = _resolve_impl(L, bias, use_flash, causal)
-    if impl == "flash_tpu" and not _flash_tpu_fits(q, k, blhd=blhd):
-        # the heuristic picked the kernel but the shape doesn't tile: keep
-        # the MEMORY-SAFE streaming path (the kernel's own fallback is the
-        # materialized O(L²) form — wrong for long L)
-        _count_fallback(
-            "flash_tpu", q.shape,
-            "shape does not fit the flash_tpu kernel (needs Lq == Lk, "
-            "L % 256 == 0, heads*dim % 128 == 0, K and V of one batch "
-            "row within its VMEM budget) — streaming via blockwise "
-            "instead, ~8-10x slower at long L")
-        impl = "blockwise"
-    return impl
-
-
-def _impl_of_tier(tier, q, k, causal, blhd):
-    """Map a tier-policy verdict onto a dispatchable impl name, with the
-    same shape safety net the heuristic path has."""
-    if tier == "flash_tpu":
-        if _flash_tpu_fits(q, k, blhd=blhd) and causal:
-            return "flash_tpu"
-        _count_fallback("flash_tpu", q.shape,
-                        "cached tier verdict no longer tiles this call — "
-                        "streaming via blockwise")
-        return "blockwise"
-    if tier == "pallas":
-        return "jax_flash" if jax.default_backend() == "tpu" else "flash"
-    return tier  # xla | blockwise
-
-
-def _tier_candidates(q, k, causal, blhd):
-    """Feasible tiers for the micro-bench: shape/backend gates only —
-    never preferences (preference is exactly what gets measured). The
-    xla candidate is capped at 2x its heuristic threshold so the bench
-    itself cannot OOM materializing scores for extreme L."""
-    if blhd:
-        L, H = q.shape[1], q.shape[2]
-        Lk = k.shape[1]
+    if tier == "ring" and sp_axis is None:
+        return _ring_sharded(q, k, v, causal, blhd)
+    if blhd:  # ring_attention and the recurrence take [b, h, l, d]
+        q, k, v = tr(q), tr(k), tr(v)
+    if tier == "ring":
+        out = ring_attention(q, k, v, sp_axis, causal, 512)
     else:
-        H, L = q.shape[1], q.shape[2]
-        Lk = k.shape[2]
-    on_tpu = jax.default_backend() == "tpu"
-    cands = []
-    xla_cap = 2 * (_XLA_MAX_SEQ_CAUSAL if causal else _XLA_MAX_SEQ)
-    if Lk == L and L <= xla_cap:
-        cands.append("xla")
-    if on_tpu and causal and _flash_tpu_fits(q, k, blhd=blhd):
-        cands.append("flash_tpu")
-    # mirror jax_flash_attention's own dispatch gate (L must tile its
-    # min(512, L) default blocks) — a candidate the kernel would bounce
-    # back off would time the FALLBACK under the 'pallas' label and could
-    # persist that mislabel to the verdict cache
-    if on_tpu and Lk == L and L % min(512, L) == 0:
-        cands.append("pallas")
-    cands.append("blockwise")
-    return cands
+        out = blockwise_attention(q, k, v, causal=causal, bias=bias,
+                                  block_k=_block_k(bias, use_flash))
+    return tr(out) if blhd else out
+
+
+def _block_k(bias, use_flash) -> int:
+    """Key-block rows of the blockwise tier. Off the TPU the unbiased call
+    reached this recurrence through the Pallas tier's fallback, in blocks
+    of 256; it keeps them, so that the order of summation, and every CPU
+    test's numbers, are what they were."""
+    if (_IMPL == "auto" and use_flash and bias is None
+            and jax.default_backend() != "tpu"):
+        return 256
+    return 512
 
 
 def _flash_tpu_fits(q, k, blhd):
-    """Shape gate for routing AUTO dispatch into the flash_tpu kernel:
-    self-attention only (Lq == Lk — the kernel reshapes k to q's length)
-    and the kernel's own tiling and VMEM constraints."""
+    """Shape gate of the flash_tpu kernel for `_tier`: self-attention only
+    (Lq == Lk — the kernel reshapes k to q's length) and the kernel's own
+    tiling and VMEM constraints."""
     from .flash_tpu import _fits
 
     if blhd:
@@ -1331,46 +1035,3 @@ def _flash_tpu_fits(q, k, blhd):
         b, H, L, d = q.shape
         Lk = k.shape[2]
     return Lk == L and _fits(b, L, H, d, 256, q.dtype.itemsize)
-
-
-def _resolve_impl(L, bias, use_flash, causal=True):
-    """Single source of truth for the impl a [b,h,l,d] dispatch will take
-    (the blhd fast path consults it too, so both layouts always agree).
-
-    auto: ``use_flash=False`` keeps the exact f32 blockwise recurrence (the
-    model-level flag selects numerics, not just a kernel); on TPU short/mid
-    sequences take the materialized XLA path (measured fastest at GPT-class
-    shapes — L=1024/d=64: 53k vs 40k for the kernels). CAUSAL unbiased
-    sequences stay on the q-chunked XLA tier up to _XLA_MAX_SEQ_CAUSAL
-    (r5: its fully-masked blocks are skipped and its residuals fit HBM at
-    the longctx bench shape — GPT-small L=8192 measured 46.5k tok/s vs
-    27.5k on flash_tpu + recompute); NON-causal or biased calls keep the
-    stricter _XLA_MAX_SEQ=4096 guard — their [b,h,L,L] score tensor has
-    no masked blocks to skip and exhausts HBM well before 8k at real
-    batch sizes. Past the threshold, causal goes to the repo's Pallas
-    flash kernel (flash_tpu.py) and the rest to the blockwise recurrence
-    (the scan path is 8-10x slower — measured L=8192 f+b: 100ms vs 13ms —
-    but O(L) in memory). Off-TPU flash_attention safely degrades to
-    blockwise. The kernel tiers gate on SHAPE at trace time."""
-    on_tpu = jax.default_backend() == "tpu"
-    if _IMPL == "flash_tpu":
-        return "flash_tpu" if (on_tpu and bias is None and causal) else "xla"
-    if _IMPL == "pallas":
-        if bias is not None:
-            return "blockwise"
-        return "jax_flash" if on_tpu else "flash"
-    if _IMPL == "xla":
-        return "xla"
-    if _IMPL == "blockwise":
-        return "blockwise"
-    if not use_flash:
-        return "blockwise"
-    if on_tpu:
-        xla_max = (_XLA_MAX_SEQ_CAUSAL if (causal and bias is None)
-                   else _XLA_MAX_SEQ)
-        if L <= xla_max:
-            return "xla"
-        if causal and bias is None:
-            return "flash_tpu"
-        return "blockwise"
-    return "blockwise" if bias is not None else "flash"

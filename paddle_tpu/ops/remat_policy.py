@@ -31,11 +31,10 @@ is published as ``gauge/remat/<entry>`` (policy id) and
 ``gauge/remat/peak_hbm/<entry>`` so bench records prove what the control
 loop chose and what it cost.
 
-The attention tiers keep their own finer-grained residual knob
-(``PADDLE_TPU_ATTN_REMAT_E``, exp-weight recompute inside the chunked
-tier) — that one is about O(L²) attention residuals specifically and is
-already measurement-backed; this module decides the transformer-block
-level question the engines used to answer with a blanket flag.
+The chunked attention tier settles its own O(L²) residuals
+(``ops.attention._REMAT_E``: a hand-written backward makes the exp
+weights again); this module decides the transformer-block level question
+the engines used to answer with a blanket flag.
 """
 from __future__ import annotations
 

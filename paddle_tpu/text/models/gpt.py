@@ -309,8 +309,8 @@ def gpt_decode_fns(config: GPTConfig, kv_dtype: str = "float32"):
     inference/serving/kv_cache.py). Each layer writes the chunk's K/V
     into its pages (int8 pools quantize on write via
     ``quant.quantize_kv``), then attends through
-    ``ops.attention.paged_attention`` — so the tier policy measures and
-    selects the decode attention path exactly like the training tiers.
+    ``ops.attention.paged_attention``, whose tier
+    ``ops.tier_policy.select_paged`` measures and selects.
 
     Numerics match the eval-mode Layer forward (dropout-free, gelu
     approximate, tied lm_head) up to the attention tier's accumulation

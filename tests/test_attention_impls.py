@@ -1,7 +1,7 @@
 """Attention implementation tiers agree numerically (blockwise is the
-reference recurrence; xla_attention is the materialized TPU fast path;
-flash falls back to blockwise off-TPU) and the dispatch honors
-set_attention_impl."""
+reference recurrence; xla_attention is the materialized TPU fast path),
+the dispatch takes the tier its rule names for the call and nothing else,
+and it honors set_attention_impl."""
 import collections
 import json
 import os
@@ -10,8 +10,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh, PartitionSpec as P
 
 from paddle_tpu.ops import attention as att
+from paddle_tpu.ops import tier_policy
 from paddle_tpu.profiler import get_telemetry, hlo_attrib
 
 # operation histogram of `causal_program_ops()` at commit 4bd524f (PR 27),
@@ -247,45 +249,161 @@ class TestXlaAttention:
                                        rtol=0.1, atol=0.1)
 
 
+def _call(L, causal=True, bias=None, heads=2, d=64, dv=None, layout="blhd",
+          dtype=jnp.bfloat16, **kw):
+    """One row of `TIER_RULE`: the operands' shapes and the keywords of a
+    ``dot_product_attention`` call. ``bias`` is a shape."""
+    shape = lambda w: ((1, L, heads, w) if layout == "blhd"
+                       else (1, heads, L, w))
+    x = jax.ShapeDtypeStruct(shape(d), dtype)
+    v = jax.ShapeDtypeStruct(shape(dv or d), dtype)
+    if bias is not None:
+        bias = jax.ShapeDtypeStruct(bias, jnp.float32)
+    return (x, x, v, bias), dict(causal=causal, layout=layout, **kw)
+
+
+# (backend, set_attention_impl, ring mesh registered, call, tier): the
+# table of `ops.attention._tier`, top to bottom
+TIER_RULE = {
+    "sp_axis_given": ("tpu", "auto", False, _call(256, sp_axis="sp"), "ring"),
+    "ring_mesh_registered": ("tpu", "auto", True, _call(8192), "ring"),
+    "ring_mesh_short_call": ("tpu", "auto", True, _call(1024), "xla"),
+    "named_tier_outranks_ring": ("tpu", "xla", True, _call(8192), "xla"),
+    "kimi_latent_L8192": ("tpu", "auto", False,
+                          _call(8192, heads=4, d=192, dv=128), "xla"),
+    "latent_use_flash_false": ("cpu", "auto", False,
+                               _call(256, d=24, dv=16, use_flash=False),
+                               "xla"),
+    "named_blockwise": ("tpu", "blockwise", False, _call(1024), "blockwise"),
+    "named_flash_tpu_non_causal": ("tpu", "flash_tpu", False,
+                                   _call(512, causal=False), "xla"),
+    "named_flash_tpu_off_tpu": ("cpu", "flash_tpu", False, _call(512), "xla"),
+    "use_flash_false": ("tpu", "auto", False,
+                        _call(1024, use_flash=False), "blockwise"),
+    "gpt2_causal_L1024": ("tpu", "auto", False,
+                          _call(1024, heads=16), "xla"),
+    "causal_L8192": ("tpu", "auto", False, _call(8192), "xla"),
+    "bert_biased_L512": ("tpu", "auto", False,
+                         _call(512, causal=False, heads=16,
+                               bias=(1, 1, 1, 512)), "xla"),
+    "non_causal_L4096": ("tpu", "auto", False,
+                         _call(4096, causal=False), "xla"),
+    "causal_L16384": ("tpu", "auto", False, _call(16384), "flash_tpu"),
+    "causal_L16384_bhld": ("tpu", "auto", False,
+                           _call(16384, layout="bhld"), "flash_tpu"),
+    "causal_biased_L8192": ("tpu", "auto", False,
+                            _call(8192, bias=(1, 1, 1, 8192)), "blockwise"),
+    "non_causal_L8192": ("tpu", "auto", False,
+                         _call(8192, causal=False), "blockwise"),
+    "causal_L9000_does_not_tile": ("tpu", "auto", False,
+                                   _call(9000), "blockwise"),
+    "off_tpu": ("cpu", "auto", False, _call(1024, heads=16), "blockwise"),
+    "off_tpu_biased": ("cpu", "auto", False,
+                       _call(512, causal=False, bias=(1, 1, 1, 512)),
+                       "blockwise"),
+}
+
+# the key of GPT-2 345M's call in the verdict file of the race that was
+GPT_KEY = "tpu:TPU_v5_lite:h16:L1024:d64:bfloat16:causal"
+
+
 class TestDispatch:
-    def test_set_attention_impl_validates(self):
+    @pytest.fixture(autouse=True)
+    def _auto_again(self):
+        yield
+        att.set_attention_impl("auto")
+        att.set_ring_context(None, None)
+
+    @pytest.mark.parametrize("impl", ["nope", "pallas"])
+    def test_set_attention_impl_validates(self, impl):
         with pytest.raises(ValueError):
-            att.set_attention_impl("nope")
+            att.set_attention_impl(impl)
 
     def test_explicit_xla_impl(self, rng):
         att.set_attention_impl("xla")
-        try:
-            q, k, v = rand_qkv(rng)
-            out = att.dot_product_attention(q, k, v, causal=True)
-            ref = att.blockwise_attention(q, k, v, causal=True)
-            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                       rtol=2e-5, atol=2e-5)
-        finally:
-            att.set_attention_impl("auto")
+        q, k, v = rand_qkv(rng)
+        out = att.dot_product_attention(q, k, v, causal=True)
+        ref = att.blockwise_attention(q, k, v, causal=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
 
     def test_blockwise_impl(self, rng):
         att.set_attention_impl("blockwise")
-        try:
-            q, k, v = rand_qkv(rng)
-            out = att.dot_product_attention(q, k, v, causal=True)
-            assert out.shape == q.shape
-        finally:
-            att.set_attention_impl("auto")
+        q, k, v = rand_qkv(rng)
+        out = att.dot_product_attention(q, k, v, causal=True)
+        assert out.shape == q.shape
 
+    @staticmethod
+    def _traced_tier(operands, kwargs):
+        """Trace the call (nothing runs) and read back the tier it
+        published in ``gauge/attn/tier.*``."""
+        q, k, v, bias = operands
+        blhd = kwargs["layout"] == "blhd"
+        L, d = q.shape[1 if blhd else 2], q.shape[-1]
+        call = lambda q_, k_, v_, b_: att.dot_product_attention(
+            q_, k_, v_, bias=b_, **kwargs)
+        if kwargs.get("sp_axis"):  # the axis has to be bound; L is local
+            mesh = Mesh(np.array(jax.devices()[:4]), (kwargs["sp_axis"],))
+            spec = (P(None, kwargs["sp_axis"]) if blhd
+                    else P(None, None, kwargs["sp_axis"]))
+            call = jax.shard_map(call, mesh=mesh, out_specs=spec,
+                                 in_specs=(spec, spec, spec, None),
+                                 check_vma=False)
+            L //= 4
+        gauge = f"attn/tier.{tier_policy.gauge_key(L, d, kwargs['causal'])}"
+        tel = get_telemetry()
+        tel.gauge(gauge, -1)
+        jax.eval_shape(call, q, k, v, bias)
+        return tel.scalars()["gauge/" + gauge]
 
-def test_auto_long_sequence_resolves_to_flash_kernel(monkeypatch):
-    """Causal unbiased dispatch keeps the q-chunked XLA tier up to
-    _XLA_MAX_SEQ_CAUSAL=8192 (r5: measured 46.5k vs 27.5k tok/s at the
-    longctx shape) and picks the Pallas flash kernel past it; biased/
-    non-causal calls keep the stricter 4096 guard (their full [L, L]
-    scores have no masked blocks to skip) and stream via blockwise."""
-    monkeypatch.setattr(att.jax, "default_backend", lambda: "tpu")
-    assert att._resolve_impl(8192, None, True, causal=True) == "xla"
-    assert att._resolve_impl(16384, None, True, causal=True) == "flash_tpu"
-    assert att._resolve_impl(8192, object(), True, causal=True) == "blockwise"
-    assert att._resolve_impl(8192, None, True, causal=False) == "blockwise"
-    assert att._resolve_impl(4096, None, True, causal=False) == "xla"
-    assert att._resolve_impl(1024, None, True, causal=True) == "xla"
+    @pytest.mark.parametrize("row", list(TIER_RULE))
+    def test_tier_is_a_rule_of_the_call(self, monkeypatch, row):
+        """Every row of the table in `ops.attention._tier`: the tier is a
+        function of the call, the backend, ``set_attention_impl`` and a
+        registered ring mesh, and the call publishes its id."""
+        backend, impl, ring, (operands, kwargs), tier = TIER_RULE[row]
+        monkeypatch.setattr(att.jax, "default_backend", lambda: backend)
+        att.set_attention_impl(impl)
+        if ring:
+            att.set_ring_context(Mesh(np.array(jax.devices()[:4]), ("sp",)),
+                                 "sp")
+        tel = get_telemetry()
+        fallbacks = tel.counter_value("attn/tier_fallbacks")
+        q, k, v, bias = operands
+        assert att._tier(q, k, v, kwargs["causal"], bias,
+                         kwargs.get("sp_axis"), kwargs.get("use_flash", True),
+                         kwargs["layout"] == "blhd") == tier
+        assert self._traced_tier(operands, kwargs) == \
+            tier_policy.TIER_IDS[tier]
+        # only the call that wanted the kernel and does not tile is counted
+        assert (tel.counter_value("attn/tier_fallbacks") - fallbacks
+                == (2 if row == "causal_L9000_does_not_tile" else 0))
+
+    def test_a_stale_verdict_file_decides_nothing(self, monkeypatch,
+                                                  tmp_path):
+        """A machine may still hold the verdicts of the race that was
+        (``attn_tiers.json`` beside its compile cache): GPT's call takes
+        `xla` whatever they say, nothing is timed and the file is left as
+        it was."""
+        cache = tmp_path / "attn_tiers.json"
+        cache.write_text(json.dumps({GPT_KEY: {
+            "tier": "pallas", "candidates": ["xla", "pallas", "blockwise"],
+            "timings_ms": {"xla": 1.71, "pallas": 1.7, "blockwise": 9.0},
+            "ts": 0.0}}))
+        stale = cache.read_bytes()
+        monkeypatch.setenv("PADDLE_TPU_ATTN_TIER_CACHE", str(cache))
+        monkeypatch.setattr(att.jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(
+            tier_policy, "_backend_key", lambda: "tpu:TPU_v5_lite")
+        tier_policy.reset()
+        tel = get_telemetry()
+        benches = tel.counter_value("attn/tier_bench")
+        operands, kwargs = _call(1024, heads=16)
+        assert self._traced_tier(operands, kwargs) == \
+            tier_policy.TIER_IDS["xla"]
+        assert tel.counter_value("attn/tier_bench") == benches
+        assert cache.read_bytes() == stale
+        tier_policy.reset()
 
 
 def test_auto_long_nonfitting_falls_back_to_blockwise(monkeypatch):

@@ -1,8 +1,8 @@
 """PR 8: the kernel-selection and memory-policy layer.
 
-- ``ops.tier_policy``: benchmarked attention tier selection — one
-  micro-bench per shape, persistent verdict cache (restart-warm, corrupt
-  file never deleted), ``PADDLE_TPU_ATTN_POLICY`` override.
+- ``ops.tier_policy``: the paged pair's verdict file (restart-warm, a
+  corrupt file never deleted). A dense call's tier is a rule
+  (tests/test_attention_impls.py::TestDispatch).
 - ``ops.attention``: ring attention gradients (hand-written recompute
   custom_vjp) vs the materialized core, 'auto' promotion onto a
   registered ring mesh, fallback accounting
@@ -13,7 +13,6 @@
 - ``tools/check_attribution.py``: the tier gate over bench records.
 """
 import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -40,153 +39,50 @@ def _clean_tier_state():
     att._fallback_warned.clear()
 
 
-def _stub_times(monkeypatch, times, calls=None):
-    """Replace the micro-bench clock with canned per-tier timings (None =
-    infeasible); ``calls`` collects the tiers actually timed."""
-    def fake(tier, q, k, v, causal):
-        if calls is not None:
-            calls.append(tier)
-        return times.get(tier)
-
-    monkeypatch.setattr(tier_policy, "_time_tier", fake)
-
-
 def _qkv(rng, b=2, h=2, L=32, d=8, dtype=jnp.float32):
     mk = lambda: jnp.asarray(rng.randn(b, h, L, d), dtype)
     return mk(), mk(), mk()
 
 
 # ---------------------------------------------------------------------------
-# tier_policy: the verdict cache
+# tier_policy: the verdict file (the paged pair's; no dense call reads it)
 # ---------------------------------------------------------------------------
 class TestTierCache:
-    def test_same_shape_benches_exactly_once(self, monkeypatch, tmp_path):
-        monkeypatch.setenv("PADDLE_TPU_ATTN_POLICY", "bench")
-        monkeypatch.setenv("PADDLE_TPU_ATTN_TIER_CACHE",
-                           str(tmp_path / "tiers.json"))
-        calls = []
-        _stub_times(monkeypatch, {"xla": 1.0, "blockwise": 2.0}, calls)
-        cands = ["xla", "blockwise"]
-        assert tier_policy.select(4, 128, 32, jnp.float32, True, cands) == "xla"
-        assert calls == ["xla", "blockwise"]  # every candidate timed once
-        assert tier_policy.select(4, 128, 32, jnp.float32, True, cands) == "xla"
-        assert len(calls) == 2  # pure cache hit: no re-measure
-        # a DIFFERENT shape is a different key and benches again
-        tier_policy.select(4, 256, 32, jnp.float32, True, cands)
-        assert len(calls) == 4
+    SHAPE = (1, 2, 8, 4, 4, jnp.float32, False)  # t, h, d, m, bs, dtype, int8
 
-    def test_cache_hit_across_process_restart(self, monkeypatch, tmp_path):
-        cache = tmp_path / "tiers.json"
-        monkeypatch.setenv("PADDLE_TPU_ATTN_POLICY", "bench")
-        monkeypatch.setenv("PADDLE_TPU_ATTN_TIER_CACHE", str(cache))
-        _stub_times(monkeypatch, {"xla": 1.0, "blockwise": 2.0})
-        assert tier_policy.select(4, 128, 32, jnp.float32, True,
-                                  ["xla", "blockwise"]) == "xla"
-        data = json.loads(cache.read_text())
-        (key, verdict), = data.items()
-        assert verdict["tier"] == "xla" and "timings_ms" in verdict
+    @pytest.fixture(autouse=True)
+    def _bench_mode(self, monkeypatch, tmp_path):
+        self.cache = tmp_path / "tiers.json"
+        monkeypatch.setenv("PADDLE_TPU_ATTN_PAGED_POLICY", "bench")
+        monkeypatch.setenv("PADDLE_TPU_ATTN_TIER_CACHE", str(self.cache))
+
+    def test_cache_hit_across_process_restart(self, monkeypatch):
+        tier = tier_policy.select_paged(*self.SHAPE)
+        (key, verdict), = json.loads(self.cache.read_text()).items()
+        assert key == tier_policy.make_paged_key(*self.SHAPE)
+        assert verdict["tier"] == tier and "timings_ms" in verdict
 
         # "restart": the in-memory registry is gone, the file remains
         tier_policy.reset()
 
-        def boom(*a):
+        def boom(*a, **kw):
             raise AssertionError("restart-warm select must not re-bench")
 
-        monkeypatch.setattr(tier_policy, "_time_tier", boom)
-        assert tier_policy.select(4, 128, 32, jnp.float32, True,
-                                  ["xla", "blockwise"]) == "xla"
+        monkeypatch.setattr(tier_policy, "bench_paged", boom)
+        assert tier_policy.select_paged(*self.SHAPE) == tier
 
-    def test_corrupt_cache_remeasures_and_deletes_nothing(
-            self, monkeypatch, tmp_path):
-        cache = tmp_path / "tiers.json"
+    def test_corrupt_cache_remeasures_and_deletes_nothing(self):
         garbage = "{not json" * 3
-        cache.write_text(garbage)
-        monkeypatch.setenv("PADDLE_TPU_ATTN_POLICY", "bench")
-        monkeypatch.setenv("PADDLE_TPU_ATTN_TIER_CACHE", str(cache))
-        _stub_times(monkeypatch, {"xla": 1.0, "blockwise": 2.0})
-        assert tier_policy.select(4, 128, 32, jnp.float32, True,
-                                  ["xla", "blockwise"]) == "xla"
-        # the unreadable file is evidence, not disposable state: its bytes
-        # survive both the failed load AND later verdict persistence
-        assert cache.read_text() == garbage
-        tier_policy.select(4, 256, 32, jnp.float32, True,
-                           ["xla", "blockwise"])
-        assert cache.read_text() == garbage
-
-    def test_env_override_wins_and_never_benches(self, monkeypatch, rng):
-        monkeypatch.setenv("PADDLE_TPU_ATTN_POLICY", "blockwise")
-
-        def boom(*a):
-            raise AssertionError("forced policy must not micro-bench")
-
-        monkeypatch.setattr(tier_policy, "_time_tier", boom)
-        q, k, v = _qkv(rng)
-        out = att.dot_product_attention(q, k, v, causal=True)
-        ref = att.blockwise_attention(q, k, v, causal=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-        scal = get_telemetry().scalars()
-        assert scal["gauge/attn/tier.L32.d8.c"] == \
-            tier_policy.TIER_IDS["blockwise"]
-
-    def test_unknown_policy_falls_back_to_heuristic(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_ATTN_POLICY", "warp-drive")
-        assert tier_policy.policy_mode() == "heuristic"
-
-    def test_restricted_candidates_never_clobber_disk_verdict(
-            self, monkeypatch, tmp_path):
-        """A restricted candidate set (a gate that changed since the
-        verdict was written) re-measures for its own process but must not
-        overwrite the full-set verdict on disk."""
-        cache = tmp_path / "tiers.json"
-        monkeypatch.setenv("PADDLE_TPU_ATTN_POLICY", "bench")
-        monkeypatch.setenv("PADDLE_TPU_ATTN_TIER_CACHE", str(cache))
-        _stub_times(monkeypatch,
-                    {"flash_tpu": 1.0, "xla": 2.0, "blockwise": 3.0})
-        assert tier_policy.select(
-            4, 128, 32, jnp.float32, True,
-            ["flash_tpu", "xla", "blockwise"]) == "flash_tpu"
-        # "restart" into a process whose gate knocked flash_tpu out
-        tier_policy.reset()
-        assert tier_policy.select(4, 128, 32, jnp.float32, True,
-                                  ["xla", "blockwise"]) == "xla"
-        # the restricted winner serves THIS process (cache hit, no
-        # re-bench) but the disk keeps the full-set verdict...
-        (_, verdict), = json.loads(cache.read_text()).items()
-        assert verdict["tier"] == "flash_tpu"
-        # ...even after a later persist of a different key
-        tier_policy.select(4, 256, 32, jnp.float32, True,
-                           ["xla", "blockwise"])
-        data = json.loads(cache.read_text())
-        assert {v["tier"] for v in data.values()} == {"flash_tpu", "xla"}
-        # unrestricted "restart": the fast verdict is intact and used
-        tier_policy.reset()
-
-        def boom(*a):
-            raise AssertionError("full-set select must not re-bench")
-
-        monkeypatch.setattr(tier_policy, "_time_tier", boom)
-        assert tier_policy.select(
-            4, 128, 32, jnp.float32, True,
-            ["flash_tpu", "xla", "blockwise"]) == "flash_tpu"
-
-    def test_bench_mode_dispatch_one_bench_across_traces(
-            self, monkeypatch, rng):
-        monkeypatch.setenv("PADDLE_TPU_ATTN_POLICY", "bench")
-        monkeypatch.delenv("PADDLE_TPU_ATTN_TIER_CACHE", raising=False)
-        _stub_times(monkeypatch, {"xla": 1.0, "blockwise": 2.0})
+        self.cache.write_text(garbage)
         tel = get_telemetry()
         before = tel.counter_value("attn/tier_bench")
-        q, k, v = _qkv(rng, L=64)
-        f1 = jax.jit(lambda a, b, c: att.dot_product_attention(
-            a, b, c, causal=True))
-        f2 = jax.jit(lambda a, b, c: att.dot_product_attention(
-            a, b, c, causal=True) * 2.0)
-        f1(q, k, v)
-        f2(q, k, v)  # second trace, same shape: verdict reused
+        assert tier_policy.select_paged(*self.SHAPE) in tier_policy.PAGED_TIERS
         assert tel.counter_value("attn/tier_bench") - before == 1
-        assert tel.scalars()["gauge/attn/tier.L64.d8.c"] == \
-            tier_policy.TIER_IDS["xla"]
+        # the unreadable file is evidence, not disposable state: its bytes
+        # survive both the failed load AND later verdict persistence
+        assert self.cache.read_text() == garbage
+        tier_policy.select_paged(1, 2, 8, 8, 4, jnp.float32, False)
+        assert self.cache.read_text() == garbage
 
 
 # ---------------------------------------------------------------------------
@@ -195,41 +91,17 @@ class TestTierCache:
 class TestFallbackAccounting:
     def test_heuristic_flash_misfit_counts_and_warns_once(self, monkeypatch):
         monkeypatch.setattr(att.jax, "default_backend", lambda: "tpu")
-        monkeypatch.setenv("PADDLE_TPU_ATTN_POLICY", "heuristic")
         tel = get_telemetry()
         before = tel.counter_value("attn/tier_fallbacks")
         q = jnp.zeros((1, 9000, 4, 64), jnp.float32)  # 9000 % 256 != 0
-        assert att._select_impl(q, q, None, True, True, True) == "blockwise"
+        tier = lambda: att._tier(q, q, q, True, None, None, True, True)
+        assert tier() == "blockwise"
         assert tel.counter_value("attn/tier_fallbacks") - before == 1
         assert len(att._fallback_warned) == 1
         # every occurrence COUNTS; the warning stays one-shot per shape
-        assert att._select_impl(q, q, None, True, True, True) == "blockwise"
+        assert tier() == "blockwise"
         assert tel.counter_value("attn/tier_fallbacks") - before == 2
         assert len(att._fallback_warned) == 1
-
-    def test_flash_attention_shape_fallback_on_tpu_counts(self, monkeypatch):
-        monkeypatch.setattr(att.jax, "default_backend", lambda: "tpu")
-        tel = get_telemetry()
-        before = tel.counter_value("attn/tier_fallbacks")
-        q = jnp.zeros((1, 2, 100, 8), jnp.float32)  # 100 % 256 != 0
-        out = att._flash_attention_impl(q, q, q, True, 256, 256)
-        assert out.shape == q.shape
-        assert tel.counter_value("attn/tier_fallbacks") - before == 1
-
-    def test_off_tpu_blockwise_is_documented_not_a_fallback(self, rng):
-        tel = get_telemetry()
-        before = tel.counter_value("attn/tier_fallbacks")
-        q, k, v = _qkv(rng, L=100)  # doesn't tile either
-        att._flash_attention_impl(q, k, v, True, 256, 256)
-        assert tel.counter_value("attn/tier_fallbacks") == before
-
-    def test_ring_policy_without_context_counts_fallback(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_ATTN_POLICY", "ring")
-        tel = get_telemetry()
-        before = tel.counter_value("attn/tier_fallbacks")
-        q = jnp.zeros((1, 2, 32, 8), jnp.float32)
-        att._select_impl(q, q, None, True, True, False)
-        assert tel.counter_value("attn/tier_fallbacks") - before == 1
 
 
 # ---------------------------------------------------------------------------
@@ -301,21 +173,24 @@ class TestRingAutoPromotion:
         att.set_ring_context(mesh, "sp")
         assert not att._ring_auto_ok(130, True, None)  # 130 % 4 != 0
 
-    @pytest.mark.parametrize("forced", ["blockwise", "xla", "heuristic"])
+    @pytest.mark.parametrize("forced", ["blockwise", "xla"])
     def test_explicit_policy_override_outranks_promotion(
             self, monkeypatch, rng, forced):
-        """PADDLE_TPU_ATTN_POLICY must measure exactly what it names —
+        """A tier named by ``set_attention_impl`` is the tier that runs:
         the forced-blockwise bench ablation leg depends on ring NOT
         hijacking the dispatch."""
         monkeypatch.setenv("PADDLE_TPU_ATTN_RING_MIN_SEQ", "64")
-        monkeypatch.setenv("PADDLE_TPU_ATTN_POLICY", forced)
         mesh = Mesh(np.array(jax.devices()[:4]), ("sp",))
         att.set_ring_context(mesh, "sp")
-        assert not att._ring_auto_ok(128, True, None)
+        assert att._ring_auto_ok(128, True, None)
         q, k, v = _qkv(rng, L=128)
-        att.dot_product_attention(q, k, v, causal=True)
-        assert get_telemetry().scalars()["gauge/attn/tier.L128.d8.c"] != \
-            tier_policy.TIER_IDS["ring"]
+        att.set_attention_impl(forced)
+        try:
+            att.dot_product_attention(q, k, v, causal=True)
+        finally:
+            att.set_attention_impl("auto")
+        assert get_telemetry().scalars()["gauge/attn/tier.L128.d8.c"] == \
+            tier_policy.TIER_IDS[forced]
 
     def test_explicit_sp_axis_dispatch_publishes_ring_verdict(self, rng):
         mesh = Mesh(np.array(jax.devices()[:4]), ("sp",))

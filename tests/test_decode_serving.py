@@ -269,7 +269,7 @@ class TestPagedTierPolicy:
 
     def test_unknown_policy_falls_back(self, monkeypatch):
         monkeypatch.setenv("PADDLE_TPU_ATTN_PAGED_POLICY", "warp-drive")
-        assert tier_policy.paged_policy_mode() == "heuristic"
+        assert tier_policy.policy_mode() == "heuristic"
 
 
 # ---------------------------------------------------------------------------

@@ -124,8 +124,7 @@ def _cases():
 
     def case_longctx_attn_chunked():
         # through the PUBLIC xla_attention so the case measures whatever
-        # backward the model dispatch actually runs (autodiff by default;
-        # PADDLE_TPU_ATTN_MANUAL_VJP=1 flips both this case and the model)
+        # backward the model dispatch actually runs (autodiff)
         from paddle_tpu.ops.attention import xla_attention
 
         return _longctx_grad_case(
