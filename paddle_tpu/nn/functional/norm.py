@@ -270,19 +270,35 @@ def rms_norm(x, weight=None, epsilon=1e-05, gate=None, name=None):
     as ``weight`` has; all of them without one), the statistic in float32.
     With ``gate`` (``x``'s shape, or its last axes flattened) the result
     is multiplied by sigmoid(gate): the gated, head-wise form a linear
-    attention layer puts on its output."""
+    attention layer puts on its output. That form computes on the gate's
+    shape, [..., heads * d] with a head's d values side by side: the sums
+    over a head are products with a 0/1 matrix (``ops.linear_attention.
+    head_sums``), so that no operation asks for the heads as an axis (on a
+    TPU that is a relayout of the whole tensor, there and back)."""
+
+    def gated(a, w, g):
+        from ...ops.linear_attention import head_sums, over_heads
+
+        d = a.shape[-1]
+        heads = g.shape[-1] // d
+        a32 = a.astype(jnp.float32).reshape(g.shape)
+        out = a32 * over_heads(jax.lax.rsqrt(
+            head_sums(jnp.square(a32), heads) / d + epsilon), d)
+        if w is not None:
+            out = out * jnp.tile(w.astype(jnp.float32), heads)
+        out = out * jax.nn.sigmoid(g.astype(jnp.float32))
+        return out.astype(a.dtype).reshape(a.shape)
 
     def f(a, *rest):
         rest = list(rest)
         w = rest.pop(0) if weight is not None else None
+        if gate is not None:
+            return gated(a, w, rest[0])
         a32 = a.astype(jnp.float32)
         out = a32 * jax.lax.rsqrt(
             jnp.mean(jnp.square(a32), axis=-1, keepdims=True) + epsilon)
         if w is not None:
             out = out * w.astype(jnp.float32)
-        if gate is not None:
-            out = out * jax.nn.sigmoid(
-                rest[0].astype(jnp.float32).reshape(a.shape))
         return out.astype(a.dtype)
 
     args = [_t(x)] + [t for t in (weight, gate) if t is not None]

@@ -154,7 +154,8 @@ class RMSNorm(Layer):
 class GatedRMSNorm(RMSNorm):
     """RMSNorm(x) * sigmoid(gate), the norm over the last axis of ``x``
     (a head's width, one learned weight shared by the heads); ``gate`` may
-    come with the heads flattened."""
+    come with the heads flattened, and is the shape the arithmetic runs
+    in (``F.rms_norm``)."""
 
     def forward(self, input, gate):
         return F.rms_norm(input, self.weight, self._epsilon, gate=gate)

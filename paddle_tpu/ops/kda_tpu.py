@@ -2,6 +2,13 @@
 gated delta rule in VMEM (``ops/linear_attention.py`` has the mathematics
 and the XLA form these kernels are held to).
 
+Every tensor of a token is [b, l, h d], a head's values side by side: a
+grid step's tile is whole lanes and a head a 128-lane slice of it
+(``_head``). The layer around the kernels computes in the same shape (the
+convolutions, the gates, q's and k's norm, the output norm), so nothing is
+laid out anew on the way in or out (PERF.md section 6, PR 33). q and k come
+normalised, q scaled.
+
 Forward: grid (batch, head groups, chunks), the chunk axis sequential. A
 grid step loads the chunk's q, k, v, g and beta tiles of its heads, makes G,
 A, B, the unit-lower inverse T, u and o in VMEM and hands the f32 state of
@@ -409,18 +416,14 @@ def _by_group(beta, heads):
         0, 2, 1, 3)
 
 
-def _flat(t):
-    """[b, l, h, d] -> [b, l, h d]: a grid step's heads lie side by side
-    in the lanes of its tile."""
-    return t.reshape(t.shape[0], t.shape[1], -1)
-
-
 def forward(q, k, v, g, beta, chunk, sub, keep_states, interpret=False):
-    """o [b, l, h, dv] in q's dtype and, with ``keep_states``, every
-    chunk's incoming state [b, n, h, dv, dk] f32 (else None). l is a whole
-    number of chunks."""
-    b, l, h, dk = k.shape
-    dv, n = v.shape[-1], l // chunk
+    """o [b, l, h dv] in q's dtype and, with ``keep_states``, every
+    chunk's incoming state [b, n, h, dv, dk] f32 (else None), from q, k, g
+    [b, l, h dk], v [b, l, h dv] (a head's values side by side, as a grid
+    step's tile has them) and beta [b, l, h]. l is a whole number of
+    chunks."""
+    b, l, h = beta.shape
+    dk, dv, n = k.shape[-1] // h, v.shape[-1] // h, l // chunk
     heads = heads_per_step(h)
     tokens, beta_spec, states_spec = _specs(n, heads, chunk, dk, dv, False)
     out_specs = [tokens(dv)]
@@ -439,16 +442,15 @@ def forward(q, k, v, g, beta, chunk, sub, keep_states, interpret=False):
             scratch_shapes=[pltpu.VMEM((heads, dv, dk), F32)],
             compiler_params=_params(), interpret=interpret,
             name="chunk_kda_fwd",
-        )(_flat(q), _flat(k), _flat(v), _flat(g.astype(F32)),
-          _by_group(beta, heads))
-    return (outs[0].reshape(b, l, h, dv),
-            outs[1] if keep_states else None)
+        )(q, k, v, g.astype(F32), _by_group(beta, heads))
+    return outs[0], outs[1] if keep_states else None
 
 
 def backward(q, k, v, g, beta, states, dO, chunk, sub, interpret=False):
-    """The cotangents of the five inputs, in their shapes and dtypes."""
-    b, l, h, dk = k.shape
-    dv, n = v.shape[-1], l // chunk
+    """The cotangents of ``forward``'s five inputs, in their shapes and
+    dtypes."""
+    b, l, h = beta.shape
+    dk, dv, n = k.shape[-1] // h, v.shape[-1] // h, l // chunk
     heads = heads_per_step(h)
     tokens, beta_spec, states_spec = _specs(n, heads, chunk, dk, dv, True)
     with jax.enable_x64(False):
@@ -469,8 +471,7 @@ def backward(q, k, v, g, beta, states, dO, chunk, sub, interpret=False):
             scratch_shapes=[pltpu.VMEM((heads, dv, dk), F32)],
             compiler_params=_params(), interpret=interpret,
             name="chunk_kda_bwd",
-        )(_flat(q), _flat(k), _flat(v), _flat(g.astype(F32)),
-          _by_group(beta, heads), states, _flat(dO.astype(q.dtype)))
+        )(q, k, v, g.astype(F32), _by_group(beta, heads), states,
+          dO.astype(q.dtype))
     d_beta = d_beta.transpose(0, 2, 1, 3).reshape(b, l, h)
-    return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
-            dg.reshape(g.shape).astype(g.dtype), d_beta.astype(beta.dtype))
+    return dq, dk_, dv_, dg.astype(g.dtype), d_beta.astype(beta.dtype)

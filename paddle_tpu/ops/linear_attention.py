@@ -14,6 +14,14 @@ The recurrence, per head, with S in R^{dk x dv} and S_0 = 0:
     S_t = S~ + beta_t k_t (v_t - S~^T k_t)^T
     o_t = S_t^T q_t
 
+where q_t and k_t are what the caller hands over, each over its L2 norm
+across a head's dk, q_t times dk^-1/2 besides. The norm belongs to the
+operation, so that each lowering takes it in the layout it computes in: the
+XLA form on [b, l, h, dk], the kernels' on [b, l, h dk] (``_unit_flat``),
+where a sum over a head is a product with a 0/1 matrix and nothing asks for
+the heads as an axis. Either way it is taken in float32 and its result
+rounded once, to v's dtype.
+
 ``chunk_kda`` computes it a chunk of C tokens at a time. With G the
 chunk's running sum of g and u_t = v_t - S~_t^T k_t (what token t writes):
 
@@ -159,7 +167,50 @@ def _intra(q, k, G, sub):
     return jnp.concatenate(rows_a, axis=-2), jnp.concatenate(rows_b, axis=-2)
 
 
+def _whose(heads, d):
+    """[heads d, heads], 1 where the column is the head's."""
+    return (jnp.arange(heads * d)[:, None] // d
+            == jnp.arange(heads)).astype(F32)
+
+
+def head_sums(x, heads):
+    """[..., heads d] f32 -> [..., heads]: the sum over each head's d
+    values as a product with ``_whose`` (f32 at ``HIGHEST``, exact to
+    float32's rounding). On a TPU a reduction over the last axis of
+    [..., heads, d] lays the whole tensor out anew, heads in sublanes, and
+    back again; this does not."""
+    return jnp.matmul(x, _whose(heads, x.shape[-1] // heads), precision=_HI)
+
+
+def over_heads(s, d):
+    """[..., heads] f32 -> [..., heads d]: each head's value under its d
+    columns, ``head_sums``' transpose (exact: one term a column)."""
+    return jnp.matmul(s, _whose(s.shape[-1], d).T, precision=_HI)
+
+
+def _unit(t, eps):
+    """t [b, l, h, dk] over its L2 norm a head (``eps`` under the root),
+    in f32."""
+    t = t.astype(F32)
+    return t * jax.lax.rsqrt(jnp.sum(jnp.square(t), -1, keepdims=True) + eps)
+
+
+def _unit_flat(t, heads, eps):
+    """The same on [b, l, h dk]."""
+    t = t.astype(F32)
+    return t * over_heads(jax.lax.rsqrt(
+        head_sums(jnp.square(t), heads) + eps), t.shape[-1] // heads)
+
+
+def _handed(unit_q, unit_k, dk, dtype):
+    """What the rule takes of q and k once they are unit vectors a head:
+    both in ``dtype``, q times dk^-1/2 there."""
+    return (unit_q.astype(dtype) * jnp.asarray(dk ** -0.5, dtype),
+            unit_k.astype(dtype))
+
+
 def _chunk_kda(q, k, v, g, beta, chunk, sub):
+    """The chunked rule on q and k that are normalised already."""
     b, l, h, dk = k.shape
     dtype = q.dtype
     pad = -l % chunk
@@ -211,15 +262,14 @@ def _on_tpu():
     return jax.default_backend() == "tpu"
 
 
-def _tier(q, k, v, chunk):
-    """Which lowering a call takes: ``"pallas"`` on a TPU when q, k and v
-    share bfloat16 or float32, dk and dv are multiples of 128 (a head's
-    tile is whole lanes) and ``chunk`` is 16, 32, 64 or 128 (16-row
-    sub-blocks times a power of two, a whole bfloat16 tile of rows);
-    ``"xla"`` for every other call. Nothing is timed and nothing is read
-    from the machine."""
-    fits = (q.dtype == k.dtype == v.dtype
-            and q.dtype in (jnp.bfloat16, jnp.float32)
+def _tier(k, v, chunk):
+    """Which lowering a call takes: ``"pallas"`` on a TPU when v (whose
+    dtype the rule's operands take) is bfloat16 or float32, dk and dv are
+    multiples of 128 (a head's tile is whole lanes) and ``chunk`` is 16,
+    32, 64 or 128 (16-row sub-blocks times a power of two, a whole
+    bfloat16 tile of rows); ``"xla"`` for every other call. Nothing is
+    timed and nothing is read from the machine."""
+    fits = (v.dtype in (jnp.bfloat16, jnp.float32)
             and k.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0
             and chunk in (16, 32, 64, 128))
     return "pallas" if fits and _on_tpu() else "xla"
@@ -233,6 +283,11 @@ def _whole_chunks(chunk, *tensors):
             for t in tensors]
 
 
+def _kda_xla(q, k, v, g, beta, eps, chunk):
+    q, k = _handed(_unit(q, eps), _unit(k, eps), k.shape[-1], v.dtype)
+    return _chunk_kda(q, k, v, g, beta, chunk, min(_SUB, chunk))
+
+
 def _kernel_forward(inputs, chunk, keep_states):
     from . import kda_tpu
 
@@ -242,16 +297,17 @@ def _kernel_forward(inputs, chunk, keep_states):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _kda_pallas(q, k, v, g, beta, chunk):
+def _kernels(q, k, v, g, beta, chunk):
+    """The kernel pair on [b, l, h d] tensors, q and k normalised."""
     return _kernel_forward((q, k, v, g, beta), chunk, False)[0]
 
 
-def _kda_pallas_fwd(q, k, v, g, beta, chunk):
+def _kernels_fwd(q, k, v, g, beta, chunk):
     out, states = _kernel_forward((q, k, v, g, beta), chunk, True)
     return out, (q, k, v, g, beta, states)
 
 
-def _kda_pallas_bwd(chunk, residuals, d_out):
+def _kernels_bwd(chunk, residuals, d_out):
     from . import kda_tpu
 
     *inputs, states = residuals
@@ -261,37 +317,57 @@ def _kda_pallas_bwd(chunk, residuals, d_out):
     return tuple(t[:, :d_out.shape[1]] for t in grads)
 
 
-_kda_pallas.defvjp(_kda_pallas_fwd, _kda_pallas_bwd)
+_kernels.defvjp(_kernels_fwd, _kernels_bwd)
 
 
-def chunk_kda(q, k, v, g, beta, chunk=64, checkpoint=True):
+def _kda_pallas(q, k, v, g, beta, eps, chunk):
+    """Everything on [b, l, h d], the kernels' layout and the layer's:
+    the norm by products, then the kernel pair."""
+    heads = beta.shape[-1]
+    q, k = _handed(_unit_flat(q, heads, eps), _unit_flat(k, heads, eps),
+                   k.shape[-1] // heads, v.dtype)
+    return _kernels(q, k, v, g, beta, chunk)
+
+
+def chunk_kda(q, k, v, g, beta, eps=1e-6, chunk=64, checkpoint=True):
     """The gated delta rule with a per-channel decay, chunked.
 
-    ``q``, ``k`` [b, l, h, dk] (as the layer hands them: normalised, q
-    scaled), ``v`` [b, l, h, dv], ``g`` [b, l, h, dk] the log of the decay
-    (<= 0), ``beta`` [b, l, h] in (0, 1). Returns o [b, l, h, dv] in the
-    inputs' dtype; the state starts at zero. Any length: the tail is
-    padded to a whole chunk with tokens that leave the state alone.
+    ``q``, ``k`` [b, l, h, dk] as the layer's convolutions leave them, in
+    any float dtype (float32 keeps what the layer computed): the rule runs
+    on q / sqrt(sum q^2 + ``eps``) / sqrt(dk) and k / sqrt(sum k^2 +
+    ``eps``), the sums over a head's dk, taken in float32 and rounded to
+    v's dtype. ``v`` [b, l, h, dv], ``g`` [b, l, h, dk] the log of the
+    decay (<= 0), ``beta`` [b, l, h] in (0, 1). Returns o [b, l, h, dv] in
+    v's dtype; the state starts at zero. Any length: the tail is padded to
+    a whole chunk with tokens that leave the state alone. On the kernels'
+    lowering nothing computes on the four axes: a caller whose tensors are
+    [b, l, h d] reshapes for free.
 
-    The lowering is chosen by ``_tier``'s rule. The XLA form's backward is
-    autodiff; the kernels' is written by hand and keeps, beside the five
-    inputs, each chunk's incoming state. Under ``checkpoint`` (the default)
-    only the five inputs are kept and the rest is made again in the
-    backward; a caller that recomputes the whole layer anyway passes False.
+    The lowering is chosen by ``_tier``'s rule, and the norm's layout
+    follows it. The XLA form's backward is autodiff; the kernels' is
+    written by hand and keeps, beside their five inputs, each chunk's
+    incoming state. Under ``checkpoint`` (the default) only the five
+    inputs are kept and the rest is made again in the backward; a caller
+    that recomputes the whole layer anyway passes False.
     """
     from ..profiler.telemetry import get_telemetry
     from .tier_policy import TIER_IDS
 
-    tier = _tier(q, k, v, chunk)
+    tier = _tier(k, v, chunk)
     # trace-time facts, like attn/calls and attn/tier.*
     tel = get_telemetry()
     tel.counter("kda/calls")
     tel.gauge("kda/chunk", chunk)
     tel.gauge(f"kda/tier.{tier}", TIER_IDS[tier])
-    if tier == "pallas":
-        fn = functools.partial(_kda_pallas, chunk=chunk)
-    else:
-        fn = functools.partial(_chunk_kda, chunk=chunk, sub=min(_SUB, chunk))
+    tel.gauge("kda/qk_norm." + ("flat" if tier == "pallas" else "heads"),
+              TIER_IDS[tier])
+    fn = functools.partial(_kda_pallas if tier == "pallas" else _kda_xla,
+                           eps=eps, chunk=chunk)
     if checkpoint:
         fn = jax.checkpoint(fn)
-    return fn(q, k, v, g, beta)
+    if tier == "xla":
+        return fn(q, k, v, g, beta)
+    # what a checkpoint keeps, it keeps in the shape it crosses in: by
+    # heads that would be a relayout, so the heads are flattened outside
+    flat = lambda t: t.reshape(*t.shape[:2], -1)  # noqa: E731
+    return fn(flat(q), flat(k), flat(v), flat(g), beta).reshape(v.shape)

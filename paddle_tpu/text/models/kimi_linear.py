@@ -128,10 +128,23 @@ def _linear(n_in, n_out, std):
 
 
 class KimiDeltaAttention(nn.Layer):
-    """q, k, v = SiLU(conv(W x)), q and k L2-normalised a head; a decay a
-    channel g = -exp(A_log) softplus(W_f2 W_f1 x + dt_bias); beta =
-    sigmoid(W_b x) a head; the gated delta rule (``chunk_kda``); output
-    W_o (RMSNorm_head(o) * sigmoid(W_g2 W_g1 x))."""
+    """q, k, v = SiLU(conv(W x)); a decay a channel g = -exp(A_log)
+    softplus(W_f2 W_f1 x + dt_bias); beta = sigmoid(W_b x) a head; the
+    gated delta rule on q and k L2-normalised a head (``chunk_kda``, which
+    takes them raw with the norm's epsilon); output W_o (RMSNorm_head(o) *
+    sigmoid(W_g2 W_g1 x)).
+
+    Between the projections every tensor of a token stays [b, l, heads d]:
+    what is a head's own is either ``chunk_kda``'s (the L2 norms, which on
+    the kernels' lowering are sums by products on that shape) or written
+    on the flat shape here (the decay's rate repeated over a head's
+    channels; the output norm, ``F.rms_norm`` with ``gate``). An operation
+    that computes on [b, l, heads, d] costs the TPU a relayout of the whole
+    tensor, there and back (``tests/test_kda_tpu_compile.py`` reads the
+    compiled layer for them). q and k are float32 from their convolutions
+    to their norm, as the compiler kept them when the norm was this
+    layer's: rounding them before it moves the step away from the float32
+    reference (PERF.md section 6, PR 33)."""
 
     def __init__(self, config: KimiLinearConfig):
         super().__init__()
@@ -170,19 +183,20 @@ class KimiDeltaAttention(nn.Layer):
 
         def mix(q, k, v, decay_in, write_in, q_conv, k_conv, v_conv, a_log,
                 dt_bias):
-            b, l = q.shape[:2]
-            q, k, v = (jax.nn.silu(short_conv(t, w)).reshape(b, l, heads, d)
-                       for t, w in ((q, q_conv), (k, k_conv), (v, v_conv)))
-            unit = lambda t: (t.astype(jnp.float32) * jax.lax.rsqrt(  # noqa: E731
-                jnp.sum(jnp.square(t.astype(jnp.float32)), -1, keepdims=True)
-                + eps)).astype(t.dtype)
-            q, k = unit(q) * jnp.asarray(d ** -0.5, q.dtype), unit(k)
-            g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(
-                (decay_in.astype(jnp.float32) + dt_bias.astype(jnp.float32))
-            ).reshape(b, l, heads, d)
+            # everything here computes on [b, l, h d], the convolutions'
+            # and the kernels' layout; the heads' axis is only named
+            by_head = lambda t: t.reshape(*t.shape[:2], heads, d)  # noqa: E731
+            # q and k stay float32 up to their norm (the class docstring)
+            f32 = jnp.float32
+            q, k, v = (jax.nn.silu(short_conv(t.astype(dtype), w))
+                       for t, w, dtype in ((q, q_conv, f32), (k, k_conv, f32),
+                                           (v, v_conv, v.dtype)))
+            rate = jnp.repeat(-jnp.exp(a_log.astype(jnp.float32)), d)
+            g = rate * jax.nn.softplus(
+                decay_in.astype(jnp.float32) + dt_bias.astype(jnp.float32))
             beta = jax.nn.sigmoid(write_in.astype(jnp.float32))
-            return chunk_kda(q, k, v, g, beta, chunk=chunk,
-                             checkpoint=keep_inputs)
+            return chunk_kda(by_head(q), by_head(k), by_head(v), by_head(g),
+                             beta, eps, chunk=chunk, checkpoint=keep_inputs)
 
         q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
         with jax.named_scope("kda"):

@@ -5,18 +5,25 @@ takes, more VMEM than a kernel may use) costs no chip time here. Nothing
 runs: the chip is described, not attached, and a compile that passes is not
 a chip run.
 
+The last case compiles a whole ``KimiDeltaAttention`` layer, value and
+gradient, and reads the optimised HLO: between the projections nothing may
+compute on the heads as an axis, or the layout pass brings back the
+relayouts of the whole tensor that PR 33 took out (43 ms a step of the Kimi
+cell).
+
 The topology is described inside a fixture, never at import, and the file
 is the only one that does so: one worker of the test run loads the TPU's
 library, and only when it is given this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from paddle_tpu.ops import kda_tpu
+from paddle_tpu.ops import kda_tpu, linear_attention
 
 B, L, H, D, CHUNK = 1, 256, 8, 128, 64
 
@@ -37,8 +44,8 @@ def one_chip():
 def shapes(one_chip, dtype):
     s = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
-    tokens = s((B, L, H, D), dtype)
-    return (tokens, tokens, tokens, s((B, L, H, D), jnp.float32),
+    tokens = s((B, L, H * D), dtype)
+    return (tokens, tokens, tokens, s((B, L, H * D), jnp.float32),
             s((B, L, H), jnp.float32)), s((B, L // CHUNK, H, D, D),
                                           jnp.float32)
 
@@ -68,3 +75,46 @@ def test_the_backward_kernel_compiles_for_the_v5e(one_chip):
         lambda *a: kda_tpu.backward(*a[:5], a[5], a[6], CHUNK, 16),
         *inputs, states, inputs[0])
     assert "tpu_custom_call" in text and "chunk_kda_bwd" in text
+
+
+def test_a_kda_layer_computes_nothing_on_the_heads_axis(one_chip,
+                                                        monkeypatch):
+    """The layer at the cell's heads (32 of 128) and a reduced length and
+    hidden size, bf16 activations, loss and every gradient: the kernels
+    are there, and no ``copy`` or ``transpose`` touches an f32 tensor whose
+    last axes are [heads, d], nor does a ``reshape`` that is no bitcast
+    make the flat f32 tensor: the L2 norm of q and k, the decay and the
+    gated output norm all run on [b, l, heads d]."""
+    from paddle_tpu.jit.functionalize import functionalize, get_params
+    from paddle_tpu.text.models.kimi_linear import (KimiDeltaAttention,
+                                                    KimiLinearConfig)
+
+    heads, d, length, hidden = 32, 128, 1024, 256
+    monkeypatch.setattr(linear_attention, "_on_tpu", lambda: True)
+    layer = KimiDeltaAttention(KimiLinearConfig(
+        hidden_size=hidden, num_hidden_layers=1, kda_layers=(1,),
+        full_attn_layers=(), kda_num_heads=heads, kda_head_dim=d))
+    apply = functionalize(layer, training=True)
+
+    def loss(params, x, ct):
+        weights = {n: p.astype(jnp.bfloat16) if p.ndim == 2 else p
+                   for n, p in params.items()}
+        return jnp.sum(apply(weights, {}, x)[0].astype(jnp.float32) * ct)
+
+    on_chip = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    x = on_chip((1, length, hidden), jnp.bfloat16)
+    text = compile_for_the_chip(
+        jax.value_and_grad(loss, argnums=(0, 1)),
+        {n: on_chip(p.shape, p.dtype) for n, p in get_params(layer).items()},
+        x, on_chip(x.shape, jnp.float32))
+    assert "chunk_kda_fwd" in text and "chunk_kda_bwd" in text
+    by_head = re.compile(r"f32\[[0-9,]*\b%d,%d\]" % (heads, d))
+    flat = "f32[1,%d,%d]" % (length, heads * d)
+    relayouts = [
+        line.strip()[:160] for line in text.splitlines()
+        if (m := re.match(r"\s*(?:ROOT )?\S+ = \S+ (copy|transpose|reshape)\(",
+                          line))
+        and (by_head.search(line) if m.group(1) != "reshape"
+             else flat in line)]
+    assert not relayouts, relayouts

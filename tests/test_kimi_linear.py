@@ -269,6 +269,37 @@ def test_mla_matches_the_reference_with_unequal_head_widths():
     get_telemetry().reset()
 
 
+@pytest.mark.parametrize("heads,d", [(4, 16), (2, 128)])
+@pytest.mark.parametrize("dtype", [F32, jnp.bfloat16])
+def test_the_gated_norm_on_the_flat_form_is_the_norm_by_heads(heads, d, dtype):
+    """``F.rms_norm(..., gate=...)`` computes on [b, l, heads d] (a head's
+    sums are products with a 0/1 matrix): value and the gradients of x,
+    weight and gate against the mean of squares over the last axis of the
+    [b, l, heads, d] form, in f32 to rounding, in bf16 to bf16's."""
+    x = normal(3, 2, 7, heads, d).astype(dtype)
+    gate = normal(4, 2, 7, heads * d).astype(dtype)
+    weight = 1.0 + 0.1 * normal(5, d)
+    ct = normal(6, 2, 7, heads, d)
+
+    def by_heads(x, weight, gate):
+        out = reference.rms_norm(x.astype(F32), weight, 1e-5) \
+            * jax.nn.sigmoid(gate.astype(F32).reshape(x.shape))
+        return out.astype(x.dtype)
+
+    flat = lambda x, weight, gate: paddle.nn.functional.rms_norm(  # noqa: E731
+        paddle.to_tensor(x), paddle.to_tensor(weight), 1e-5,
+        gate=paddle.to_tensor(gate))._value
+    both_of = lambda f: jax.vjp(f, x, weight, gate)  # noqa: E731
+    (want, want_vjp), (got, got_vjp) = both_of(by_heads), both_of(flat)
+    assert got.shape == x.shape and got.dtype == dtype
+    tol = 1e-6 if dtype == F32 else 1e-2
+    assert worst(got.astype(F32), want.astype(F32)) < tol
+    for name, a, b in zip(("x", "weight", "gate"), got_vjp(ct.astype(dtype)),
+                          want_vjp(ct.astype(dtype))):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert worst(a.astype(F32), b.astype(F32)) < 10 * tol, name
+
+
 # -- the whole model ---------------------------------------------------------------
 
 def model_and_weights(seed=3):
@@ -316,9 +347,13 @@ def test_the_two_lowerings_of_the_delta_rule_agree_in_the_model(
         remat, monkeypatch):
     """KDA heads of 128 at a tiny hidden size: the loss and every
     parameter's gradient are the same whether ``chunk_kda`` lowers to XLA
-    (as the CPU takes it) or to the kernel pair (the test stands in for the
-    TPU and runs Pallas in interpret mode), with the layers kept or made
-    again; the kernels, forward and backward, lie under the ``kda`` scope."""
+    (as the CPU takes it: q and k normalised by heads) or to the kernel pair
+    (the test stands in for the TPU and runs Pallas in interpret mode: the
+    norm on [b, l, heads d], its sums as products), with the layers kept or
+    made again; either way the decay and the gated output norm run on
+    [b, l, heads d]. The kernels, forward and backward, lie under the
+    ``kda`` scope, and ``gauge/kda/qk_norm.*`` says which layout the norm
+    took."""
     from paddle_tpu.ops import linear_attention, remat_policy
 
     paddle.seed(5)
@@ -333,17 +368,18 @@ def test_the_two_lowerings_of_the_delta_rule_agree_in_the_model(
     def run():
         get_telemetry().reset()
         lowered = step().lower(get_params(model))
-        tiers = sorted(k.rsplit(".", 1)[1] for k in get_telemetry().scalars()
-                       if k.startswith("gauge/kda/tier."))
+        tiers = sorted(k.split("kda/", 1)[1] for k in get_telemetry().scalars()
+                       if k.startswith(("gauge/kda/tier.",
+                                        "gauge/kda/qk_norm.")))
         return tiers, lowered.as_text(debug_info=True), \
             step()(get_params(model))
 
     tiers, _, (want_loss, want) = run()
-    assert tiers == ["xla"]
+    assert tiers == ["qk_norm.heads", "tier.xla"]
     monkeypatch.setattr(linear_attention, "_on_tpu", lambda: True)
     monkeypatch.setattr(linear_attention, "_INTERPRET", True)
     tiers, text, (got_loss, got) = run()
-    assert tiers == ["pallas"]
+    assert tiers == ["qk_norm.flat", "tier.pallas"]
     assert abs(float(got_loss) - float(want_loss)) < 1e-5 * float(want_loss)
     for name in want:
         assert worst(got[name], want[name]) < TOL, name

@@ -1,7 +1,9 @@
 """``ops.linear_attention``: the chunked gated delta rule with a
 per-channel decay against the token-by-token recurrence of the plain
-reference (``benchmark/reference/kimi_linear.py``), outputs and all five
-gradients, and the short causal convolution.
+reference (``benchmark/reference/kimi_linear.py``) on q and k normalised
+as the reference's layer normalises them, outputs and all five gradients
+(those of q and k as they were before the norm), and the short causal
+convolution.
 
 Tolerances, in float32 on the CPU: both sides compute the same sums in
 another order, so they part by a few roundings of float32 (measured
@@ -21,6 +23,7 @@ from paddle_tpu.profiler import get_telemetry
 
 TOL = 2e-5
 F32 = jnp.float32
+EPS = 1e-6
 
 
 @pytest.fixture
@@ -40,18 +43,31 @@ both = pytest.mark.parametrize("impl", ["xla", "pallas"], indirect=True)
 
 
 def inputs(seed, b, l, h, d, fastest=2.0):
-    """q, k as the layer hands them (unit norm, q scaled), decays a
-    channel from 0.999 a token down to exp(-fastest * softplus)."""
-    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
-    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
-    q = unit(jax.random.normal(keys[0], (b, l, h, d), F32)) * d ** -0.5
-    k = unit(jax.random.normal(keys[1], (b, l, h, d), F32))
+    """q, k as the layer hands them (before their norm, rows of lengths
+    from a tenth of sqrt(d) to ten times it), decays a channel from 0.999
+    a token down to exp(-fastest * softplus)."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 8)
+    length = lambda key: jnp.exp(jax.random.uniform(  # noqa: E731
+        key, (b, l, h, 1), F32, np.log(0.1), np.log(10.0)))
+    q = jax.random.normal(keys[0], (b, l, h, d), F32) * length(keys[6])
+    k = jax.random.normal(keys[1], (b, l, h, d), F32) * length(keys[7])
     v = jax.random.normal(keys[2], (b, l, h, d), F32)
     rate = jnp.exp(jax.random.uniform(keys[3], (h, d), F32, np.log(1e-3),
                                       np.log(fastest)))
     g = -rate * jax.nn.softplus(jax.random.normal(keys[4], (b, l, h, d), F32))
     beta = jax.nn.sigmoid(jax.random.normal(keys[5], (b, l, h), F32))
     return q, k, v, g, beta
+
+
+def normalised(q, k, eps=EPS):
+    """What the reference's layer does to q and k before the rule."""
+    unit = lambda t: t * jax.lax.rsqrt(  # noqa: E731
+        jnp.sum(jnp.square(t), -1, keepdims=True) + eps)
+    return unit(q) * q.shape[-1] ** -0.5, unit(k)
+
+
+def recurrence(q, k, v, g, beta):
+    return reference.delta_rule(*normalised(q, k), v, g, beta)
 
 
 def worst(got, want):
@@ -76,7 +92,7 @@ def taken(impl, width):
 @pytest.mark.parametrize("length,chunk,heads,width", CASES)
 def test_chunked_matches_the_recurrence(length, chunk, heads, width, impl):
     args = inputs(length, 2 if width < 128 else 1, length, heads, width)
-    want = jax.jit(reference.delta_rule)(*args)
+    want = jax.jit(recurrence)(*args)
     get_telemetry().reset()
     got = jax.jit(lambda *a: chunk_kda(*a, chunk=chunk))(*args)
     assert f"gauge/kda/tier.{taken(impl, width)}" in get_telemetry().scalars()
@@ -92,7 +108,7 @@ def test_all_five_gradients_match_the_recurrence(length, chunk, heads, width,
     ct = jax.random.normal(jax.random.PRNGKey(9), args[2].shape, F32)
     grads = lambda f: jax.jit(jax.grad(  # noqa: E731
         lambda *a: jnp.sum(f(*a) * ct), argnums=(0, 1, 2, 3, 4)))(*args)
-    want = grads(reference.delta_rule)
+    want = grads(recurrence)
     got = grads(lambda *a: chunk_kda(*a, chunk=chunk))
     for name, a, b in zip("q k v g beta".split(), got, want):
         assert worst(a, b) < TOL, name
@@ -102,7 +118,7 @@ def test_all_five_gradients_match_the_recurrence(length, chunk, heads, width,
 def test_checkpoint_changes_no_number(checkpoint):
     args = inputs(3, 1, 128, 2, 16)
     f = lambda *a: jnp.sum(chunk_kda(*a, checkpoint=checkpoint) ** 2)  # noqa: E731
-    want = lambda *a: jnp.sum(reference.delta_rule(*a) ** 2)  # noqa: E731
+    want = lambda *a: jnp.sum(recurrence(*a) ** 2)  # noqa: E731
     for a, b in zip(jax.jit(jax.grad(f, argnums=(0, 3)))(*args),
                     jax.jit(jax.grad(want, argnums=(0, 3)))(*args)):
         assert worst(a, b) < TOL
@@ -117,7 +133,7 @@ def test_fast_decays_do_not_overflow(per_token, width, impl):
     q, k, v, g, beta = inputs(7, 1, 128, 2, width)
     g = g.at[..., ::2].set(-per_token)     # every other channel very fast
     g = g.at[:, 40:44].set(0.0)            # and a few tokens that keep all
-    want = jax.jit(reference.delta_rule)(q, k, v, g, beta)
+    want = jax.jit(recurrence)(q, k, v, g, beta)
     got, grad = jax.jit(jax.value_and_grad(
         lambda g: chunk_kda(q, k, v, g, beta).sum(), has_aux=False))(g)
     assert bool(jnp.isfinite(grad).all()) and np.isfinite(float(got))
@@ -128,6 +144,7 @@ def test_a_bfloat16_state_would_fail():
     """The same recurrence with S rounded to bfloat16 after every token
     parts from the float32 one by a hundred times the tolerance."""
     q, k, v, g, beta = inputs(11, 1, 256, 2, 16, fastest=0.05)
+    q_n, k_n = normalised(q, k)
 
     def token(S, x):
         q_t, k_t, v_t, g_t, beta_t = x
@@ -137,9 +154,9 @@ def test_a_bfloat16_state_would_fail():
         S = S.astype(jnp.bfloat16).astype(F32)
         return S, jnp.sum(S * q_t[..., None], axis=-2)
 
-    xs = tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta))
+    xs = tuple(jnp.moveaxis(t, 1, 0) for t in (q_n, k_n, v, g, beta))
     _, o = jax.lax.scan(token, jnp.zeros((1, 2, 16, 16), F32), xs)
-    want = jax.jit(reference.delta_rule)(q, k, v, g, beta)
+    want = jax.jit(recurrence)(q, k, v, g, beta)
     assert worst(jnp.moveaxis(o, 0, 1), want) > 100 * TOL
     assert worst(jax.jit(chunk_kda)(q, k, v, g, beta), want) < TOL
 
@@ -153,7 +170,7 @@ def test_bfloat16_operands_keep_a_float32_state(length, width, impl):
     args = inputs(5, 1, length, 2, width, fastest=0.05)
     q, k, v = (t.astype(jnp.bfloat16) for t in args[:3])
     got = jax.jit(chunk_kda)(q, k, v, *args[3:])
-    want = jax.jit(reference.delta_rule)(
+    want = jax.jit(recurrence)(
         q.astype(F32), k.astype(F32), v.astype(F32), *args[3:])
     assert got.dtype == jnp.bfloat16
     assert worst(got.astype(F32), want) < 3e-2
@@ -162,14 +179,15 @@ def test_bfloat16_operands_keep_a_float32_state(length, width, impl):
 @pytest.mark.parametrize("impl", ["pallas"], indirect=True)
 @pytest.mark.parametrize("dtype", [F32, jnp.bfloat16])
 def test_the_backward_kernel_is_the_vjp_of_the_xla_form(dtype, impl):
-    """The hand-written backward against autodiff of ``_chunk_kda`` on the
-    same inputs, all five cotangents; in bf16 both sides round the same
-    operands and part by bf16's rounding of the results."""
+    """The hand-written backward (under the norm on the flat form and its
+    autodiff) against autodiff of the norm by heads and ``_chunk_kda``
+    after it on the same inputs, all five cotangents; in bf16 both sides
+    round the same operands and part by bf16's rounding of the results."""
     args = inputs(21, 1, 192, 4, 128)
     args = tuple(t.astype(dtype) for t in args[:3]) + args[3:]
     ct = jax.random.normal(jax.random.PRNGKey(4), args[2].shape, F32)
     ct = ct.astype(dtype)
-    xla = lambda *a: linear_attention._chunk_kda(*a, chunk=64, sub=16)  # noqa: E731
+    xla = norm_by_heads_then_the_rule
     want = jax.jit(lambda a: jax.vjp(xla, *a)[1](ct))(args)
     got = jax.jit(lambda a: jax.vjp(
         lambda *x: chunk_kda(*x, checkpoint=False), *a)[1](ct))(args)
@@ -177,6 +195,88 @@ def test_the_backward_kernel_is_the_vjp_of_the_xla_form(dtype, impl):
         assert a.dtype == b.dtype and a.shape == b.shape, name
         assert worst(a.astype(F32), b.astype(F32)) < (
             TOL if dtype == F32 else 2e-2), name
+
+
+def norm_by_heads_then_the_rule(q, k, v, g, beta, chunk=64):
+    """What the layer computed before the norm became the operation's:
+    ``unit(...)`` on [b, l, h, d] in XLA, rounded to v's dtype, then the
+    chunked rule on what it hands over. The XLA form does this still."""
+    unit = lambda t: linear_attention._unit(t, EPS)  # noqa: E731
+    return linear_attention._chunk_kda(
+        *linear_attention._handed(unit(q), unit(k), k.shape[-1], v.dtype),
+        v, g, beta, chunk=chunk, sub=16)
+
+
+# one chunk, several, a ragged length; four heads are two groups of two
+RAW_CASES = [64, 256, 150]
+
+
+# (q and k's dtype, v's): all float32, all bfloat16, and the layer's own
+# call, q and k float32 as the convolutions computed them beside a
+# bfloat16 v
+DTYPES = [(F32, F32), (jnp.bfloat16, jnp.bfloat16), (F32, jnp.bfloat16)]
+
+
+@both
+@pytest.mark.parametrize("raw,dtype", DTYPES)
+@pytest.mark.parametrize("length", RAW_CASES)
+def test_the_norm_on_the_flat_form_is_the_norm_by_heads(length, raw, dtype,
+                                                        impl):
+    """``chunk_kda`` on q and k as they come, with the epsilon, against the
+    norm by heads in XLA followed by the rule, output and all five
+    gradients (q's and k's in the dtype they came in). The kernels' tier
+    takes the norm on [b, l, h d], its sums as products: the same numbers
+    to float32's rounding, and after the rounding to v's dtype the same
+    operands for the same kernels."""
+    args = inputs(300 + length, 1, length, 4, 128)
+    args = (args[0].astype(raw), args[1].astype(raw),
+            args[2].astype(dtype)) + args[3:]
+    ct = jax.random.normal(jax.random.PRNGKey(6), args[2].shape, F32)
+    both_of = lambda f: jax.jit(lambda a: jax.vjp(f, *a))(args)  # noqa: E731
+    want, want_vjp = both_of(norm_by_heads_then_the_rule)
+    got, got_vjp = both_of(lambda *a: chunk_kda(*a, EPS))
+    assert got.dtype == want.dtype == dtype
+    tol_out, tol_grad = (TOL, TOL) if dtype == F32 else (3e-2, 2e-2)
+    assert worst(got.astype(F32), want.astype(F32)) < tol_out
+    for name, a, b in zip("q k v g beta".split(),
+                          got_vjp(ct.astype(dtype)), want_vjp(ct.astype(dtype))):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert worst(a.astype(F32), b.astype(F32)) < tol_grad, name
+
+
+def test_head_sums_are_products_with_a_0_1_matrix():
+    """``head_sums`` and ``over_heads`` against the reshape they stand in
+    for, values and transposes."""
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 5, 4 * 16), F32)
+    sums, back = jax.vjp(lambda t: linear_attention.head_sums(t, 4), x)
+    np.testing.assert_allclose(sums, x.reshape(2, 5, 4, 16).sum(-1),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(
+        back(sums)[0], linear_attention.over_heads(sums, 16))
+    np.testing.assert_array_equal(
+        linear_attention.over_heads(sums, 16),
+        jnp.repeat(sums, 16, axis=-1))
+
+
+@both
+@pytest.mark.parametrize("width", [16, 128])
+def test_rows_of_zeros_and_of_large_values_stay_finite(width, impl):
+    """A row of zeros has the norm sqrt(eps): it is asked nothing and
+    writes nothing, and its gradient is the cotangent over sqrt(eps). A
+    row of values near 1e4 is normalised like any other."""
+    q, k, v, g, beta = inputs(17, 1, 128, 2, width)
+    q = q.at[:, 3].set(0.0).at[:, 70].mul(3e3)
+    k = k.at[:, 5].set(0.0).at[:, 70].mul(3e3).at[:, 100, 1].set(0.0)
+    ct = jax.random.normal(jax.random.PRNGKey(8), v.shape, F32)
+    f = lambda f: jax.jit(lambda *a: jax.vjp(f, *a))(q, k, v, g, beta)  # noqa: E731
+    (want, want_vjp), (got, got_vjp) = f(recurrence), f(chunk_kda)
+    assert bool(jnp.isfinite(got).all()) and worst(got, want) < TOL
+    got_grads = got_vjp(ct)
+    for name, a, b in zip("q k v g beta".split(), got_grads, want_vjp(ct)):
+        assert bool(jnp.isfinite(a).all()), name
+        assert worst(a, b) < TOL, name
+    # a zero row of q gets the whole cotangent over sqrt(eps): not zero
+    assert float(jnp.abs(got_grads[0][:, 3]).max()) > 0
 
 
 def lowered_primitives(fn, *args):
@@ -212,18 +312,27 @@ def test_the_rule_chooses_by_backend_and_shape(impl, monkeypatch):
         tiers = sorted(k for k in tel.scalars() if k.startswith(
             "gauge/kda/tier."))
         assert len(tiers) == 1 and tel.counter_value("kda/calls") == 1
-        return tiers[0].rsplit(".", 1)[1], names
+        tier = tiers[0].rsplit(".", 1)[1]
+        # the norm's layout follows the tier
+        assert [k for k in tel.scalars() if k.startswith(
+            "gauge/kda/qk_norm.")] == ["gauge/kda/qk_norm." + (
+                "flat" if tier == "pallas" else "heads")]
+        return tier, names
 
     wide, narrow = inputs(1, 1, 128, 2, 128), inputs(1, 1, 128, 2, 32)
     tier, names = tier_of(wide)
     assert tier == "pallas" and "pallas_call" in names
-    assert not names & {"scan", "while", "dot_general", "cumsum", "exp"}
+    # (the products left are the norm's head sums)
+    assert not names & {"scan", "while", "cumsum", "exp"}
     assert tier_of(wide, checkpoint=False)[0] == "pallas"
     tier, names = tier_of(narrow)
     assert tier == "xla" and "scan" in names and "pallas_call" not in names
     assert tier_of(inputs(1, 1, 128, 2, 128), chunk=256)[0] == "xla"
+    # q and k may come in another float dtype than v's: the rule runs in v's
     mixed = (wide[0].astype(jnp.bfloat16),) + wide[1:]
-    assert tier_of(mixed)[0] == "xla"
+    assert tier_of(mixed)[0] == "pallas"
+    half = wide[:2] + (wide[2].astype(jnp.float16),) + wide[3:]
+    assert tier_of(half)[0] == "xla"
     monkeypatch.setattr(linear_attention, "_on_tpu", lambda: False)
     tier, names = tier_of(wide)
     assert tier == "xla" and "pallas_call" not in names
@@ -232,7 +341,7 @@ def test_the_rule_chooses_by_backend_and_shape(impl, monkeypatch):
 
 def test_the_cpu_takes_the_xla_form():
     """Unsteered: this process has no TPU, so every call is XLA's."""
-    assert linear_attention._tier(*inputs(1, 1, 64, 2, 128)[:3], 64) == "xla"
+    assert linear_attention._tier(*inputs(1, 1, 64, 2, 128)[1:3], 64) == "xla"
     assert not linear_attention._INTERPRET
 
 
@@ -266,4 +375,7 @@ def test_counters_are_set_when_traced():
     jax.jit(lambda *a: chunk_kda(*a, chunk=32)).lower(*args)
     assert tel.counter_value("kda/calls") == 1
     assert tel.scalars()["gauge/kda/chunk"] == 32
+    assert tel.scalars()["gauge/kda/tier.xla"] == 0
+    assert tel.scalars()["gauge/kda/qk_norm.heads"] == 0
+    assert "gauge/kda/qk_norm.flat" not in tel.scalars()
     tel.reset()
