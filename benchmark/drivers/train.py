@@ -139,6 +139,7 @@ def run(job: dict) -> dict:
     loop.drain()
 
     losses = loop.losses[window["first"]:window["last"]]
+    early_losses = loop.losses[:ref_plan["steps"] + WARM_STEPS]
     # the dispatch of step i + 1 comes before the fetch of step i
     dispatch_ms = loop.dispatch_ms[window["first"] + 1:window["last"] + 1]
     done = np.asarray(loop.done_at[window["first"] - 1:window["last"]])
@@ -171,8 +172,13 @@ def run(job: dict) -> dict:
         rows_per_block=ref_plan["rows_per_block"])
     nums = compare.numbers(got, want)
     report.note("reference", seconds=round(clock() - t_ref, 3))
+    labels, gaps = compare.leaf_gaps(got["change_norms"],
+                                     want["change_norms"])
     report.note("first_steps", program_losses=got["losses"],
+                program_losses_through_warm_up=early_losses,
                 reference_losses=want["losses"], worst=nums["worst"],
+                widest_change_gaps=[(labels[i], float(gaps[i]))
+                                    for i in np.argsort(-gaps)[:6]],
                 numbers={k: v for k, v in nums.items() if k != "worst"})
 
     bad = sum(not math.isfinite(x) for x in losses)

@@ -74,7 +74,10 @@ def make_batches(config: dict, traffic: dict, seed: int, n: int) -> list:
     same number of positions is masked (``mask_share`` of the length, so
     every seed gives the same work): 80% of them become [MASK], 10% a
     random id, 10% stay, as the paper has it. Segment 0 is the first half
-    of a row and segment 1 the second."""
+    of a row and segment 1 the second. Every batch holds the same number
+    of next-sentence rows (``nsp_share`` of the batch), placed by the seed:
+    a share drawn from the seed made some seeds ill-conditioned (PERF.md
+    section 2, "BERT's next-sentence labels")."""
     rng = np.random.default_rng([int(seed), 0x62657274])
     b, l = traffic["batch"], traffic["seq_len"]
     k = round(traffic["mask_share"] * l)
@@ -89,7 +92,8 @@ def make_batches(config: dict, traffic: dict, seed: int, n: int) -> list:
     np.put_along_axis(ids, picks, swapped, -1)
     segments = np.broadcast_to((np.arange(l) >= l // 2).astype(np.int32),
                                (b, l)).copy()
-    nsp = rng.integers(0, 2, (n, b), dtype=np.int32)
+    is_next = round(traffic["nsp_share"] * b)
+    nsp = (np.argsort(rng.random((n, b)), axis=-1) < is_next).astype(np.int32)
     return [{"ids": ids[i], "token_type_ids": segments,
              "attention_mask": np.ones((b, l), np.int32),
              "mlm_labels": labels[i], "nsp_labels": nsp[i]}

@@ -50,8 +50,9 @@ def main(argv=None) -> int:
             precision=precision, rows_per_block=plan["rows_per_block"])
 
     for seed in args.seeds:
+        # the batches of the benchmark's own run on this seed
         batches = family.make_batches(config, cell["traffic"], seed,
-                                      plan["steps"])
+                                      cell["traffic"]["pool"])[:plan["steps"]]
         want = follow(seed, batches, plan.get("precision", "float32"))
         half = [{k: v[:len(v) // 2] for k, v in b.items()} for b in batches]
         for what, got in (("control", follow(seed, batches, args.control)),
