@@ -1,0 +1,198 @@
+"""The Falcon-H1 serve cell at a tiny size on any backend: ``correct`` that
+the program passes and that the float8 control and the block-table mix-up
+fail, and the three readers of the ``ssm`` scope in a serving trace, on a
+trace made by hand.
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests/test_falcon_rehearsal.py -q
+"""
+import time
+
+import pytest
+from jax.profiler import ProfileData
+
+from benchmark.lib import (compare, manifest, scopes, serve_scopes,
+                           serve_trace, trace)
+
+MANIFEST = "benchmark/tests/rehearsal_falcon/BENCHMARK.json"
+CELL = "falcon-h1-tiny.serve-closed"
+REAL = "falcon-h1-34b.serve-closed-chat"
+
+
+def _job(seed, **extra):
+    found = manifest.load(MANIFEST, CELL)
+    cell = {**found["cell"], "chips": 1}
+    return cell, {"cell": cell, "config": found["config"], "seed": seed,
+                  "seconds": 1.0, "trace": False,
+                  "t0": time.perf_counter(), "trace_dir": None, **extra}
+
+
+@pytest.fixture(scope="module")
+def control_runs():
+    """The program's and the float8 control's numbers on three seeds, the
+    control read over the very prompts and tokens the program served."""
+    from benchmark.drivers import serve
+
+    out = []
+    for seed in (1, 2, 2**31 + 3):
+        cell, job = _job(seed, controls=("float8",))
+        controls = []
+        inner = serve.report.note
+        serve.report.note = lambda kind, **f: (
+            controls.append(f["numbers"]) if kind == "control"
+            else inner(kind, **f))
+        try:
+            result = serve.run(job)
+        finally:
+            serve.report.note = inner
+        out.append((cell, result, controls[0]))
+    return out
+
+
+@pytest.mark.parametrize("i", [0, 1, 2])
+def test_program_is_correct_and_control_is_not(control_runs, i):
+    cell, result, control = control_runs[i]
+    ok, compared = compare.verdict(result["numbers"], cell["limits"])
+    assert ok and result["failed"] == 0, compared
+    assert result["numbers"]["tokens_compared"] >= 50
+    bad, compared = compare.verdict(control, cell["limits"])
+    assert not bad, compared
+
+
+def test_a_block_table_mixup_is_not_correct():
+    from benchmark.drivers import serve
+
+    cell, job = _job(4, fault="block_table_mixup")
+    result = serve.run(job)
+    ok, compared = compare.verdict(result["numbers"], cell["limits"])
+    assert not ok, compared
+
+
+def test_the_pool_holds_state_and_leaks_none():
+    from benchmark.drivers import serve
+    from benchmark.families import falcon_h1_serve as family
+
+    found = manifest.load(MANIFEST, CELL)
+    model = family.build_model(found["config"], family.weights(
+        found["config"], 1, "bfloat16"))
+    eng = family.build_engine(model, found["cell"]["engine"])
+    try:
+        assert eng.max_seq_len == found["config"]["assumed"]["max_seq_len"]
+        assert set(eng.pool.pages) == {"k", "v", "ssm", "conv"}
+        assert str(eng.pool.pages["ssm"][0].dtype) == "float32"
+        assert str(eng.pool.pages["conv"][0].dtype) == "bfloat16"
+        assert "kv_occupancy" in serve.engine_gauges(eng)()
+    finally:
+        eng.shutdown()
+    assert eng.kv_accounting()["leaked_slots"] == 0
+
+
+# -- the readers, on a trace made by hand --------------------------------------
+
+US = 1_000_000  # picoseconds
+P = "jit(serve_decode_b4)/"
+OPS = {  # metadata id: (HLO line, tf_op or None)
+    2: ("%fusion.1 = bf16[4,64]{1,0} fusion(bf16[4,64]{1,0} %p.1), kind=kOutput, calls=%fc.1",
+        P + "self_attn/dot_general:"),
+    3: ("%fusion.2 = f32[4,4,16,16]{3,2,1,0} fusion(f32[9,4,16,16]{3,2,1,0} %p.2), kind=kLoop, calls=%fc.2",
+        P + "self_attn/ssm/mul:"),
+    4: ("%fusion.3 = f32[9,4,16,16]{3,2,1,0} fusion(f32[4,4,16,16]{3,2,1,0} %p.3), kind=kLoop, calls=%fc.3",
+        P + "self_attn/ssm/scatter:"),
+    5: ("%fusion.4 = bf16[4,4,16]{2,1,0} fusion(bf16[4,64]{1,0} %p.4), kind=kLoop, calls=%fc.4",
+        P + "self_attn/attention/while/body/dot_general:"),
+    6: ("%fusion.5 = bf16[4,128]{1,0} fusion(bf16[4,64]{1,0} %p.5), kind=kOutput, calls=%fc.5",
+        P + "mlp/dot_general:"),
+    7: ("%copy.1 = f32[8]{0} copy(f32[8]{0} %p.6)", None),
+}
+# a decode run: 50 us, of which ssm 6 + 4; a prefill run: 30 us, ssm 12
+DECODE = {2: (0, 10), 3: (10, 6), 4: (16, 4), 5: (20, 15), 6: (35, 10),
+          7: (45, 5)}
+PREFILL = {2: (0, 8), 3: (8, 12), 6: (20, 10)}
+NAMES = {1: "jit_serve_decode_b4(2)", 8: "jit_serve_prefill_c16(1)"}
+GPT_NAMES = {1: "jit_step(2)", 8: "jit_step(1)"}
+
+
+def _textproto(names, with_ssm=True):
+    def event(meta, start_us, length_us):
+        return (f"    events {{ metadata_id: {meta} offset_ps: "
+                f"{int(start_us * US)} duration_ps: {int(length_us * US)} }}\n")
+
+    def line(ident, name, events):
+        return (f'  lines {{ id: {ident} name: "{name}" timestamp_ns: 1000000\n'
+                + "".join(events) + "  }\n")
+
+    metadata = "".join(
+        f'  event_metadata {{ key: {k} value {{ id: {k} name: "{v}" }} }}\n'
+        for k, v in names.items())
+    for k, (hlo, path) in OPS.items():
+        if path and not with_ssm:
+            path = path.replace("ssm/", "")
+        stat = (f' stats {{ metadata_id: 1 str_value: "{path}" }}'
+                if path else "")
+        metadata += (f'  event_metadata {{ key: {k} value {{ id: {k} '
+                     f'name: "{hlo}"{stat} }} }}\n')
+    metadata += '  stat_metadata { key: 1 value { id: 1 name: "tf_op" } }\n'
+    modules, ops = [], []
+    for i in range(6):  # six iterations of 100 us: a prefill, then a decode
+        t = 100.0 * i
+        modules += [event(8, t, 30), event(1, t + 40, 50)]
+        ops += [event(k, t + a, n) for k, (a, n) in PREFILL.items()]
+        ops += [event(k, t + 40 + a, n) for k, (a, n) in DECODE.items()]
+    device = line(2, "XLA Modules", modules) + line(3, "XLA Ops", ops)
+    return ('planes { id: 1 name: "/device:TPU:0"\n' + metadata + device
+            + '}\nplanes { id: 2 name: "/host:CPU"\n}\n')
+
+
+@pytest.fixture
+def checkout(tmp_path, monkeypatch):
+    """A checkout whose newest raw trace is the one made here."""
+    monkeypatch.setattr(scopes, "ROOT", str(tmp_path))
+    scopes._reduced.clear()
+    serve_scopes._reduced.clear()
+
+    def put(cell, text):
+        d = tmp_path / ".bench_out" / cell / "trace" / "plugins" / \
+            "profile" / "2026_10_04"
+        d.mkdir(parents=True)
+        (d / "vm.xplane.pb").write_bytes(
+            ProfileData.text_proto_to_serialized_xspace(text))
+        return str(d / "vm.xplane.pb")
+
+    return put
+
+
+def _run(path):
+    traced = serve_trace.reduce(trace.load(path))
+    return {"trace": traced, "batch_occupancy": 0.75,
+            "device": {"platform": "tpu", "kind": "TPU v5 lite"}}
+
+
+def test_the_three_readers_read_the_ssm_scope_of_a_serving_trace(checkout):
+    from benchmark.families import falcon_h1_serve as family
+
+    run = _run(checkout(REAL, _textproto(NAMES)))
+    read = {n: manifest.metric_reader(n + ".serve")(run) for n in (
+        "ssm_decode_ms", "ssm_prefill_ms", "ssm_decode_roofline_pct")}
+    # whole runs: five decodes and five prefills (the first prefill and the
+    # last decode are left out); per run 10 us and 12 us under ssm
+    assert read["ssm_decode_ms"] == pytest.approx(0.010)
+    assert read["ssm_prefill_ms"] == pytest.approx(0.012)
+    found = manifest.load("BENCHMARK.json", REAL)
+    rows = 0.75 * found["cell"]["engine"]["max_running"]
+    least_s = family.ssm_step_bytes(found["config"], rows) / 819e9
+    assert read["ssm_decode_roofline_pct"] == pytest.approx(
+        100.0 * least_s / 10e-6)
+    assert manifest.metric_reader("decode_ms.serve")(run) == \
+        pytest.approx(0.05)
+
+
+def test_the_readers_read_nothing_where_no_scope_is_named(checkout):
+    """The parent's program, and GPT's cell: no ``ssm`` anywhere."""
+    path = checkout("gpt2-345m.serve-closed-decode",
+                    _textproto(GPT_NAMES, with_ssm=False))
+    run = _run(path)
+    for n in ("ssm_decode_ms", "ssm_prefill_ms", "ssm_decode_roofline_pct"):
+        assert manifest.metric_reader(n + ".serve")(run) is None
+    run["device"]["platform"] = "cpu"
+    assert serve_scopes.device_ms(run, "ssm", "decode") is None
+    assert serve_scopes.device_ms({"trace": None, "device": run["device"]},
+                                  "ssm", "decode") is None

@@ -56,6 +56,7 @@ from .autograd import grad  # noqa: E402,F401
 # appended when its module exists so the package is importable mid-build.
 from . import nn  # noqa: E402
 from .nn.layer_base import Layer  # noqa: E402,F401
+from .nn.initializer import LazyGuard  # noqa: E402,F401
 from . import optimizer  # noqa: E402
 from . import io  # noqa: E402
 from . import metric  # noqa: E402

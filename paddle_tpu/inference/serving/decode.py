@@ -31,8 +31,10 @@ prefix every token. This module serves generation natively:
 
 Telemetry (schema-gated): counters ``serve/kv_blocks_{alloc,free}``,
 ``serve/decode_steps``, ``serve/prefill_chunks``, ``serve/kv_evictions``,
-``serve/tokens_generated``, ``serve/spec_{proposed,accepted}``; gauges
+``serve/tokens_generated``, ``serve/spec_{proposed,accepted}``,
+``serve/state_resets`` (chunks that started a recurrent state again); gauges
 ``serve/kv_occupancy`` ∈ [0,1], ``serve/kv_blocks_{total,used}``,
+``serve/state_occupancy`` ∈ [0,1], ``serve/state_slots_{total,used}``,
 ``serve/spec_accept_rate`` ∈ [0,1], ``serve/running``; histograms
 ``serve/ttft_ms``, ``serve/tpot_ms``, ``serve/decode_ms[.b<N>]``,
 ``serve/prefill_ms[.c<N>]``, ``serve/verify_ms[.b<N>]``,
@@ -48,6 +50,7 @@ import time
 import traceback
 from typing import Dict, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -238,19 +241,25 @@ class DecodeScheduler:
     def _make_step(self, fwd, name: str):
         """One compiled entry: forward a chunk through the cache, return
         the greedy token per position (argmax stays on device — the D2H
-        per step is [B, T] int32, not [B, T, V] logits). Pages (arg 3)
-        are donated: the pool is the largest serving buffer and must
-        never exist twice on device."""
+        per step is [B, T] int32, not [B, T, V] logits). The cache (arg
+        3: the pool's pages and, where the model carries recurrent state,
+        its state leaves) is donated: the pool is the largest serving
+        buffer and must never exist twice on device. ``slots`` [B] names
+        each row's state slot (0, the scratch slot, for padded rows and
+        for models without state). The jitted function is named after
+        the entry, so that a device trace tells a decode step from a
+        prefill chunk by its module's name."""
 
-        def step(params, tokens, qpos, pages, tables, kv_lens):
-            logits, pages = fwd(params, tokens, qpos, pages, tables,
-                                kv_lens)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), pages
+        def step(params, tokens, qpos, cache, tables, kv_lens, slots):
+            logits, cache = fwd(params, tokens, qpos, cache, tables,
+                                kv_lens, slots)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
+        step.__name__ = step.__qualname__ = name.replace(".", "_")
         # sig_argnums: hash only the drift-capable inputs — flattening
         # the full params pytree per decode step would put O(leaves)
         # host work on the token hot path
-        return tracked_jit(step, name=name, sig_argnums=(1, 2, 4, 5),
+        return tracked_jit(step, name=name, sig_argnums=(1, 2, 4, 5, 6),
                            donate_argnums=(3,))
 
     def _decode_fn(self, bucket: int):
@@ -303,7 +312,8 @@ class DecodeScheduler:
             tables = jnp.zeros((B, eng._table_width), jnp.int32)
             lens = jnp.zeros((B,), jnp.int32)
             t0 = time.perf_counter()
-            g, pages = fn(fwd_params, toks, qpos, pool.pages, tables, lens)
+            g, pages = fn(fwd_params, toks, qpos, pool.pages, tables, lens,
+                          jnp.zeros((B,), jnp.int32))
             np.asarray(g)  # block: measure compile+run
             pool.pages = pages
             out[label] = (time.perf_counter() - t0) * 1e3
@@ -464,9 +474,11 @@ class DecodeScheduler:
         return True
 
     def _evict(self, victim: GenRequest) -> None:
-        """Recompute-style preemption: free the victim's blocks; it
-        re-enters chunked prefill over its full known token sequence
-        (prompt + generated so far) when capacity returns."""
+        """Recompute-style preemption: free the victim's blocks and its
+        state slot; it re-enters chunked prefill over its full known
+        token sequence (prompt + generated so far) when capacity returns,
+        and its recurrent state starts again from zero there (the step
+        resets a row whose chunk begins at position 0)."""
         eng = self._engine
         eng._pool.release(victim.id)
         victim.ncache = 0
@@ -499,7 +511,8 @@ class DecodeScheduler:
                       tokens: List[List[int]], draft: bool = False):
         """Stack per-sequence feeds, padding rows to ``bucket``: padded
         rows carry kv_len 0, so every write they scatter is redirected to
-        the scratch page and every attention row is fully masked."""
+        the scratch page and every attention row is fully masked, and
+        state slot 0, the scratch slot."""
         eng = self._engine
         pool = eng._draft_pool if draft else eng._pool
         nc = [(r.draft_ncache if draft else r.ncache) for r in reqs]
@@ -507,13 +520,15 @@ class DecodeScheduler:
         qpos = np.zeros((bucket, T), np.int32)
         lens = np.zeros((bucket,), np.int32)
         tables = np.zeros((bucket, eng._table_width), np.int32)
+        slots = np.zeros((bucket,), np.int32)  # padded rows: the scratch slot
         for i, r in enumerate(reqs):
             toks[i] = tokens[i]
             qpos[i] = nc[i] + np.arange(T, dtype=np.int32)
             lens[i] = nc[i] + T
             tables[i] = pool.block_table(r.id, eng._table_width)
-        return (jnp.asarray(toks), jnp.asarray(qpos), jnp.asarray(tables),
-                jnp.asarray(lens))
+            slots[i] = pool.slot(r.id)
+        # one call hands all five to the device
+        return tuple(jax.device_put((toks, qpos, tables, lens, slots)))
 
     # -- prefill -----------------------------------------------------------
     def _prefill_chunk(self, r: GenRequest) -> None:
@@ -532,10 +547,17 @@ class DecodeScheduler:
         qpos = (r.ncache + np.arange(C, dtype=np.int32))[None]
         lens = np.asarray([r.ncache + real], np.int32)
         table = eng._pool.block_table(r.id, eng._table_width)[None]
+        slot = np.asarray([eng._pool.slot(r.id)], np.int32)
+        if r.ncache == 0 and eng._pool.config.state and tel.enabled:
+            # the chunk that starts at position 0 starts the state again
+            tel.counter("serve/state_resets")
         t0 = time.perf_counter()
+        # numpy to the device in one call: no array is made by a program
+        # of its own (a device run the trace would book to this round)
+        toks, qpos, table, lens, slot = jax.device_put(
+            (toks, qpos, table, lens, slot))
         g, pages = self._get_prefill_fn()(
-            eng._params, jnp.asarray(toks), jnp.asarray(qpos),
-            eng._pool.pages, jnp.asarray(table), jnp.asarray(lens))
+            eng._params, toks, qpos, eng._pool.pages, table, lens, slot)
         eng._pool.pages = pages
         g_np = np.asarray(g)
         ms = (time.perf_counter() - t0) * 1e3
@@ -551,9 +573,8 @@ class DecodeScheduler:
             dlens = np.asarray([r.draft_ncache + real], np.int32)
             t0 = time.perf_counter()
             dg, dpages = self._get_prefill_fn(draft=True)(
-                eng._draft_params, jnp.asarray(toks), jnp.asarray(qpos),
-                eng._draft_pool.pages, jnp.asarray(dtable),
-                jnp.asarray(dlens))
+                eng._draft_params, toks, qpos, eng._draft_pool.pages,
+                jnp.asarray(dtable), jnp.asarray(dlens), slot)
             eng._draft_pool.pages = dpages
             np.asarray(dg)
             if tel.enabled:
@@ -594,7 +615,7 @@ class DecodeScheduler:
         t0 = time.perf_counter()
         g, pages = self._decode_fn(bucket)(eng._params, arrays[0],
                                            arrays[1], eng._pool.pages,
-                                           arrays[2], arrays[3])
+                                           *arrays[2:])
         eng._pool.pages = pages
         g_np = np.asarray(g)
         ms = (time.perf_counter() - t0) * 1e3
@@ -659,7 +680,7 @@ class DecodeScheduler:
             t0 = time.perf_counter()
             dg, dpages = self._draft_fn(b1)(
                 eng._draft_params, arrays[0], arrays[1],
-                eng._draft_pool.pages, arrays[2], arrays[3])
+                eng._draft_pool.pages, *arrays[2:])
             eng._draft_pool.pages = dpages
             np.asarray(dg)  # catch-up: only the cache write matters
             if tel.enabled:
@@ -685,7 +706,8 @@ class DecodeScheduler:
                     eng._draft_params,
                     jnp.asarray(np.asarray(chunk, np.int32)[None]),
                     jnp.asarray(qpos), eng._draft_pool.pages,
-                    jnp.asarray(dtable), jnp.asarray(dlens))
+                    jnp.asarray(dtable), jnp.asarray(dlens),
+                    jnp.zeros((1,), jnp.int32))
                 eng._draft_pool.pages = dpages
                 np.asarray(dg)
                 if tel.enabled:
@@ -705,7 +727,7 @@ class DecodeScheduler:
             t0 = time.perf_counter()
             dg, dpages = self._draft_fn(bucket)(
                 eng._draft_params, arrays[0], arrays[1],
-                eng._draft_pool.pages, arrays[2], arrays[3])
+                eng._draft_pool.pages, *arrays[2:])
             eng._draft_pool.pages = dpages
             dg_np = np.asarray(dg)
             if tel.enabled:
@@ -723,7 +745,7 @@ class DecodeScheduler:
         t0 = time.perf_counter()
         g, pages = self._verify_fn(bucket)(eng._params, arrays[0],
                                            arrays[1], eng._pool.pages,
-                                           arrays[2], arrays[3])
+                                           *arrays[2:])
         eng._pool.pages = pages
         g_np = np.asarray(g)
         ms = (time.perf_counter() - t0) * 1e3
@@ -788,26 +810,25 @@ def paged_prefill_logits(model, prompt: Sequence[int], chunk: int,
                          block_size: int = 16,
                          kv_dtype: str = "float32") -> np.ndarray:
     """Logits ``[len(prompt), vocab]`` of one prompt pushed through the
-    paged path the engine serves with — ``gpt_decode_fns`` over a
-    ``KVCachePool``, ``chunk`` tokens at a time — outside any scheduler.
+    paged path the engine serves with — the model's own
+    ``decode_spec()['forward_chunk']`` over a ``KVCachePool``, ``chunk``
+    tokens at a time — outside any scheduler.
     The twin of ``dense_greedy_reference``: parity gates compare this
     against the eval-mode Layer forward (the paged cache is an
     optimization, never a numerics fork)."""
-    import jax
 
     from ...jit.functionalize import get_params
-    from ...text.models.gpt import gpt_decode_fns
 
-    mcfg = model.config
     prompt = np.asarray(prompt, np.int32)
     n = len(prompt)
     width = -(-n // block_size)
-    pool = KVCachePool(KVCacheConfig(
-        mcfg.num_layers, mcfg.num_heads, mcfg.hidden_size // mcfg.num_heads,
-        num_blocks=width + 1, block_size=block_size, dtype=kv_dtype))
+    spec = model.decode_spec(kv_dtype)
+    pool = KVCachePool(_pool_config(spec, width + 1, block_size, kv_dtype,
+                                    state_slots=1))
     pool.ensure(0, n)
     table = jnp.asarray(pool.block_table(0, width)[None])
-    fwd = jax.jit(gpt_decode_fns(mcfg, kv_dtype))  # chunks share one compile
+    slot = jnp.asarray([pool.slot(0)], jnp.int32)
+    fwd = jax.jit(spec["forward_chunk"])  # chunks share one compile
     params = get_params(model)
     pages = pool.pages
     rows = []
@@ -818,13 +839,24 @@ def paged_prefill_logits(model, prompt: Sequence[int], chunk: int,
         qpos = (c0 + np.arange(chunk, dtype=np.int32))[None]
         lens = np.asarray([c0 + real], np.int32)
         logits, pages = fwd(params, jnp.asarray(toks), jnp.asarray(qpos),
-                            pages, table, jnp.asarray(lens))
+                            pages, table, jnp.asarray(lens), slot)
         rows.append(np.asarray(logits)[0, :real])
     return np.concatenate(rows, axis=0)
 
 
+def _pool_config(spec: dict, num_blocks: int, block_size: int, kv_dtype: str,
+                 state_slots: int) -> KVCacheConfig:
+    """The pool a model's ``decode_spec`` asks for."""
+    return KVCacheConfig(
+        spec["num_layers"], spec["num_heads"], spec["head_dim"],
+        num_blocks=num_blocks, block_size=block_size, dtype=kv_dtype,
+        num_kv_heads=spec["num_kv_heads"], layout=spec["kv_layout"],
+        state=spec["state"], state_slots=state_slots)
+
+
 class TokenServingEngine(ServingEngine):
-    """Token-level serving over a ``GPTForCausalLM`` — the decode twin of
+    """Token-level serving over a causal LM that gives ``decode_spec()``
+    (``GPTForCausalLM``, ``FalconH1ForCausalLM``) — the decode twin of
     the PR 7 one-shot engine, sharing its whole request lifecycle
     (admission, deadlines, drain, accounting, preemption exit) and
     substituting the decode scheduler + paged KV pool for the one-shot
@@ -845,20 +877,22 @@ class TokenServingEngine(ServingEngine):
     def __init__(self, model, config: Optional[TokenServeConfig] = None,
                  draft_model=None):
         from ...jit.functionalize import get_params
-        from ...text.models.gpt import gpt_decode_fns
 
         self.config = config or TokenServeConfig()
         cfg = self.config
-        mcfg = model.config
-        head_dim = mcfg.hidden_size // mcfg.num_heads
+        # the model says how it is decoded and what cache it needs
+        spec = model.decode_spec(cfg.kv_dtype)
         self._params = get_params(model)
-        self._fwd = gpt_decode_fns(mcfg, cfg.kv_dtype)
-        pool_cfg = KVCacheConfig(
-            mcfg.num_layers, mcfg.num_heads, head_dim,
-            num_blocks=cfg.kv_blocks, block_size=cfg.kv_block_size,
-            dtype=cfg.kv_dtype)
-        max_seq = cfg.max_seq_len or mcfg.max_position_embeddings
-        max_seq = min(max_seq, mcfg.max_position_embeddings)
+        self._fwd = spec["forward_chunk"]
+        if cfg.spec_k > 0 and spec["state"]:
+            raise ValueError(
+                "spec_k > 0 with a model that holds recurrent state: a "
+                "rejected proposal has already moved the state, and it "
+                "cannot be rolled back")
+        pool_cfg = _pool_config(spec, cfg.kv_blocks, cfg.kv_block_size,
+                                cfg.kv_dtype, state_slots=cfg.max_running)
+        max_seq = min(cfg.max_seq_len or spec["max_positions"],
+                      spec["max_positions"])
         if pool_cfg.blocks_for(max_seq) > pool_cfg.usable_blocks:
             raise ValueError(
                 f"KV pool ({pool_cfg.usable_blocks} usable blocks of "
@@ -871,14 +905,15 @@ class TokenServingEngine(ServingEngine):
         if cfg.spec_k > 0 and draft_model is None:
             raise ValueError("spec_k > 0 needs a draft_model")
         if self.spec_enabled:
-            dcfg = draft_model.config
+            dspec = draft_model.decode_spec(cfg.kv_dtype)
+            if dspec["state"]:
+                raise ValueError("a draft model that holds recurrent state "
+                                 "cannot roll a rejected proposal back")
             self._draft_params = get_params(draft_model)
-            self._draft_fwd = gpt_decode_fns(dcfg, cfg.kv_dtype)
-            self._draft_pool = KVCachePool(KVCacheConfig(
-                dcfg.num_layers, dcfg.num_heads,
-                dcfg.hidden_size // dcfg.num_heads,
-                num_blocks=cfg.kv_blocks, block_size=cfg.kv_block_size,
-                dtype=cfg.kv_dtype))
+            self._draft_fwd = dspec["forward_chunk"]
+            self._draft_pool = KVCachePool(_pool_config(
+                dspec, cfg.kv_blocks, cfg.kv_block_size, cfg.kv_dtype,
+                state_slots=0))
         else:
             self._draft_params = self._draft_fwd = self._draft_pool = None
         self._init_runtime()
@@ -932,6 +967,18 @@ class TokenServingEngine(ServingEngine):
             self._draft_pool.release(req.id)
         super()._finish(req, status, outputs=outputs, detail=detail,
                         error=error)
+
+    def shutdown(self) -> dict:
+        """The base teardown, and then the device memory goes: a shut-down
+        engine serves nothing more, but the ops plane keeps the last
+        engine for its ledger (``ops_server.set_serving_engine``), and
+        with it would keep the weights and the whole pool resident."""
+        acct = super().shutdown()
+        self._params = self._draft_params = None
+        for pool in (self._pool, self._draft_pool):
+            if pool is not None:
+                pool.pages = None
+        return acct
 
     def kv_accounting(self) -> dict:
         out = self._pool.accounting()

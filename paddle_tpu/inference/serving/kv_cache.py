@@ -28,6 +28,25 @@ Page 0 is a reserved **scratch page**: it is never allocated, and every
 masked-out write (padding rows of a bucketed batch, padded tail of a
 prefill chunk) is redirected into it, so a scatter never needs a
 data-dependent guard inside the compiled step.
+
+**Recurrent state** (``state=``): a model whose mixers carry a fixed-size
+state between steps (a state-space layer's matrix a head, its convolution's
+tail) keeps it in the same pool, one **slot** a sequence beside the pages
+that grow with it. ``pages[<leaf>]`` holds, a layer, ``[slots + 1, ...]``;
+slot 0 is the scratch slot that padded rows read and write, as page 0 is
+for pages. A slot comes with a sequence's first block and goes back in
+``release``, so the one terminal funnel and the recompute-style eviction
+that free the blocks free the slot too; nothing is cleared on the host:
+the compiled step starts a sequence's state from zero at the chunk whose
+first position is 0.
+
+**Layout** (``layout=``): ``"stacked"`` keeps K and V as one array each,
+``[layers, blocks, block, kv heads, head_dim]``; ``"per_layer"`` keeps a
+tuple of one array a layer, ``[blocks, block, kv heads * head_dim]``, the
+heads flattened into one minor axis that is whole lanes wide, so that a
+layer's scatter and gather touch that layer's array alone and no step
+slices or relays out the whole pool (PERF.md, section 5). State leaves are
+always a tuple of one array a layer.
 """
 from __future__ import annotations
 
@@ -45,6 +64,7 @@ __all__ = ["KVCacheConfig", "KVCachePool", "SCRATCH_PAGE"]
 SCRATCH_PAGE = 0
 
 _STORE_DTYPES = ("float32", "bfloat16", "int8")
+_LAYOUTS = ("stacked", "per_layer")
 
 
 class KVCacheConfig:
@@ -61,18 +81,38 @@ class KVCacheConfig:
             per-token-head scale planes.
         compute_dtype: dtype K/V are dequantized to for the attention
             dot (defaults to float32 off-int8 storage dtype).
+        num_kv_heads: heads of K and V where they are fewer than the
+            query heads (grouped queries); the pages hold these.
+        layout: 'stacked' | 'per_layer' (module docstring).
+        state: recurrent-state leaves, ``{name: (shape a slot and layer,
+            dtype)}``, or None; ``state_slots`` sequences can hold one.
     """
 
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  num_blocks: int = 64, block_size: int = 16,
                  dtype: str = "float32",
-                 compute_dtype: Optional[str] = None):
+                 compute_dtype: Optional[str] = None,
+                 num_kv_heads: Optional[int] = None,
+                 layout: str = "stacked",
+                 state: Optional[Dict[str, tuple]] = None,
+                 state_slots: int = 0):
         if dtype not in _STORE_DTYPES:
             raise ValueError(f"kv dtype {dtype!r} not in {_STORE_DTYPES}")
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (page 0 is scratch)")
+        if layout not in _LAYOUTS:
+            raise ValueError(f"kv layout {layout!r} not in {_LAYOUTS}")
+        if layout == "per_layer" and dtype == "int8":
+            raise ValueError("the per_layer layout flattens the heads; int8 "
+                             "pages keep a scale a head and stay 'stacked'")
+        if state and state_slots < 1:
+            raise ValueError("a recurrent-state pool needs state_slots >= 1")
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
+        self.num_kv_heads = int(num_kv_heads or num_heads)
+        self.layout = layout
+        self.state = dict(state or {})
+        self.state_slots = int(state_slots) if self.state else 0
         self.head_dim = int(head_dim)
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
@@ -97,23 +137,38 @@ class KVCachePool:
     def __init__(self, config: KVCacheConfig):
         self.config = config
         c = config
-        shape = (c.num_layers, c.num_blocks, c.block_size, c.num_heads,
-                 c.head_dim)
         store = jnp.int8 if c.dtype == "int8" else jnp.dtype(c.dtype)
-        self.pages: Dict[str, jnp.ndarray] = {
-            "k": jnp.zeros(shape, store),
-            "v": jnp.zeros(shape, store),
-        }
+        if c.layout == "per_layer":
+            shape = (c.num_blocks, c.block_size, c.num_kv_heads * c.head_dim)
+            self.pages: Dict[str, object] = {
+                kv: tuple(jnp.zeros(shape, store)
+                          for _ in range(c.num_layers)) for kv in ("k", "v")}
+        else:
+            shape = (c.num_layers, c.num_blocks, c.block_size,
+                     c.num_kv_heads, c.head_dim)
+            self.pages = {"k": jnp.zeros(shape, store),
+                          "v": jnp.zeros(shape, store)}
         if c.dtype == "int8":
             sshape = shape[:-1]  # [L, N, bs, H] — one scale per token-head
             self.pages["k_scale"] = jnp.zeros(sshape, jnp.float32)
             self.pages["v_scale"] = jnp.zeros(sshape, jnp.float32)
+        for name, (slot_shape, dtype) in c.state.items():
+            if name in self.pages:
+                raise ValueError(f"state leaf {name!r} is a page leaf's name")
+            self.pages[name] = tuple(
+                jnp.zeros((c.state_slots + 1, *slot_shape), jnp.dtype(dtype))
+                for _ in range(c.num_layers))
         self._lock = threading.Lock()
         self._free: List[int] = list(range(1, c.num_blocks))
         self._owned: Dict[int, List[int]] = {}  # request id -> block ids
+        # slot 0 is scratch; a pool without state hands out none
+        self._free_slots: List[int] = list(range(c.state_slots, 0, -1))
+        self._slot_of: Dict[int, int] = {}      # request id -> state slot
         self._tel = get_telemetry()
         if self._tel.enabled:
             self._tel.gauge("serve/kv_blocks_total", c.usable_blocks)
+            if c.state:
+                self._tel.gauge("serve/state_slots_total", c.state_slots)
             self._publish_locked()
 
     # -- accounting (host, scheduler thread + the engine's finish funnel) --
@@ -124,12 +179,19 @@ class KVCachePool:
         self._tel.gauge("serve/kv_blocks_used", used)
         self._tel.gauge("serve/kv_occupancy",
                         used / max(self.config.usable_blocks, 1))
+        if self.config.state:
+            slots = self.config.state_slots
+            self._tel.gauge("serve/state_slots_used",
+                            slots - len(self._free_slots))
+            self._tel.gauge("serve/state_occupancy",
+                            (slots - len(self._free_slots)) / slots)
 
     def ensure(self, owner: int, n_tokens: int) -> bool:
-        """Grow ``owner``'s block list to cover ``n_tokens`` positions.
-        Returns False (allocating NOTHING — no partial grabs to unwind)
-        when the free list cannot cover the growth; the scheduler then
-        evicts or defers."""
+        """Grow ``owner``'s block list to cover ``n_tokens`` positions;
+        with its first block a sequence takes its state slot, where the
+        pool holds state. Returns False (allocating NOTHING — no partial
+        grabs to unwind) when the free list cannot cover the growth or no
+        slot is free; the scheduler then evicts or defers."""
         need = self.config.blocks_for(n_tokens)
         with self._lock:
             have = self._owned.setdefault(owner, [])
@@ -138,6 +200,10 @@ class KVCachePool:
                 return True
             if grow > len(self._free):
                 return False
+            if self.config.state and owner not in self._slot_of:
+                if not self._free_slots:
+                    return False
+                self._slot_of[owner] = self._free_slots.pop()
             taken = [self._free.pop() for _ in range(grow)]
             have.extend(taken)
             if self._tel.enabled:
@@ -146,16 +212,21 @@ class KVCachePool:
             return True
 
     def release(self, owner: int) -> int:
-        """Return every block of ``owner`` to the free list (idempotent —
-        the engine's terminal funnel calls it for every request, whether
-        or not it ever owned cache). Returns the number freed."""
+        """Return every block of ``owner``, and its state slot, to the
+        free lists (idempotent — the engine's terminal funnel calls it for
+        every request, whether or not it ever owned cache). Returns the
+        number of blocks freed."""
         with self._lock:
-            blocks = self._owned.pop(owner, None)
-            if not blocks:
+            blocks = self._owned.pop(owner, None) or []
+            slot = self._slot_of.pop(owner, None)
+            if not blocks and slot is None:
                 return 0
-            self._free.extend(blocks)
-            if self._tel.enabled:
-                self._tel.counter("serve/kv_blocks_free", len(blocks))
+            if slot is not None:
+                self._free_slots.append(slot)
+            if blocks:
+                self._free.extend(blocks)
+                if self._tel.enabled:
+                    self._tel.counter("serve/kv_blocks_free", len(blocks))
             self._publish_locked()
             return len(blocks)
 
@@ -176,15 +247,35 @@ class KVCachePool:
     def occupancy(self) -> float:
         return self.used_blocks / max(self.config.usable_blocks, 1)
 
+    def state_occupancy(self) -> float:
+        """Share of the state slots that sequences hold; 0.0 for a pool
+        without recurrent state."""
+        with self._lock:
+            return ((self.config.state_slots - len(self._free_slots))
+                    / max(self.config.state_slots, 1))
+
+    def slot(self, owner: int) -> int:
+        """``owner``'s state slot; the scratch slot 0 where it holds none
+        (a pool without state, a sequence without cache)."""
+        with self._lock:
+            return self._slot_of.get(owner, SCRATCH_PAGE)
+
     def accounting(self) -> dict:
-        """The leak ledger: after a drain, ``leaked_blocks`` must be 0 and
+        """The leak ledger: after a drain, ``leaked_blocks`` (and, where
+        the pool holds recurrent state, ``leaked_slots``) must be 0 and
         ``owners`` empty — the decode gate and the drain test assert it."""
         with self._lock:
             used = self.config.usable_blocks - len(self._free)
-            return {"total_blocks": self.config.usable_blocks,
-                    "used_blocks": used,
-                    "leaked_blocks": used,
-                    "owners": sorted(self._owned)}
+            out = {"total_blocks": self.config.usable_blocks,
+                   "used_blocks": used,
+                   "leaked_blocks": used,
+                   "owners": sorted(self._owned)}
+            if self.config.state:
+                held = self.config.state_slots - len(self._free_slots)
+                out.update(total_slots=self.config.state_slots,
+                           used_slots=held, leaked_slots=held,
+                           slot_owners=sorted(self._slot_of))
+            return out
 
     # -- device-facing helpers ---------------------------------------------
     def block_table(self, owner: int, width: int) -> np.ndarray:

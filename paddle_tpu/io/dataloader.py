@@ -14,6 +14,7 @@ from __future__ import annotations
 import atexit
 import itertools
 import multiprocessing as mp
+import multiprocessing.connection  # noqa: F401  (mp.connection.wait)
 import os
 import pickle
 import queue
@@ -129,7 +130,10 @@ class _MultiProcessIter:
         # forked child that touches jax (e.g. via a transform) can deadlock.
         ctx = mp.get_context("spawn")
         self._index_queues = []
-        self._out_queue = ctx.Queue()
+        # every worker writes to the first queue; a respawned worker gets
+        # one of its own (see _respawn), and all that are live are read
+        self._out_queues = [ctx.Queue()]
+        self._worker_out = [self._out_queues[0]] * self._num_workers
         self._workers = []
         self._batches = None if self._iterable else list(iter(loader.batch_sampler))
         self._send_idx = 0
@@ -165,7 +169,7 @@ class _MultiProcessIter:
         p = self._ctx.Process(
             target=_worker_loop,
             args=(self._loader.dataset, self._index_queues[w],
-                  self._out_queue, self._loader.collate_fn, w,
+                  self._worker_out[w], self._loader.collate_fn, w,
                   self._num_workers, self._loader.worker_init_fn,
                   self._iterable, self._ring_name),
             daemon=True,
@@ -178,13 +182,25 @@ class _MultiProcessIter:
         a fresh index queue gets every in-flight batch id the dead worker
         owned but never answered re-enqueued, so the epoch loses and
         duplicates nothing. Map-style datasets only — an iterable
-        dataset's position died with the worker's iterator."""
+        dataset's position died with the worker's iterator.
+
+        The new worker also gets a result queue of its own. A worker
+        killed while its feeder thread was writing dies holding the shared
+        queue's write lock (a semaphore all writers share) and may leave
+        half a record in its pipe: a successor on the same queue would
+        block on that lock for good, and the epoch would end in "worker
+        timed out" (seen under load). The old queue goes on being read
+        only while another live worker still writes to it."""
         from ..profiler.telemetry import get_telemetry
 
         get_telemetry().counter("resilience/worker_respawns")
         self._respawned.add(w)
         iq = self._ctx.Queue()
         self._index_queues[w] = iq  # old queue (and its backlog) dropped
+        old, self._worker_out[w] = self._worker_out[w], self._ctx.Queue()
+        self._out_queues.append(self._worker_out[w])
+        if not any(q is old for q in self._worker_out):
+            self._out_queues.remove(old)  # whole records were drained before
         for i in range(self._rcvd_idx, self._send_idx):
             if i % self._num_workers == w and i not in self._reorder:
                 iq.put((i, self._batches[i]))
@@ -210,15 +226,16 @@ class _MultiProcessIter:
         if self._ring is not None:
             # drain any queue-overflow records first (non-blocking)
             drained = False
-            try:
-                while True:
-                    batch_id, err, data = self._out_queue.get_nowait()
-                    self._reorder[batch_id] = (err, data)
-                    drained = True
-            except queue.Empty:
-                pass
-            except (EOFError, OSError, pickle.UnpicklingError):
-                pass  # truncated record from a killed worker
+            for out in self._out_queues:
+                try:
+                    while True:
+                        batch_id, err, data = out.get_nowait()
+                        self._reorder[batch_id] = (err, data)
+                        drained = True
+                except queue.Empty:
+                    pass
+                except (EOFError, OSError, pickle.UnpicklingError):
+                    pass  # truncated record from a killed worker
             if drained:
                 return True
             try:
@@ -240,16 +257,24 @@ class _MultiProcessIter:
             else:
                 self._reorder[batch_id] = (None, payload)
             return True
-        try:
-            batch_id, err, data = self._out_queue.get(timeout=timeout_s)
-        except queue.Empty:
-            return False
-        except (EOFError, OSError, pickle.UnpicklingError):
-            # truncated record from a SIGKILLed worker; anything else
-            # (ImportError from an unpicklable payload, …) must propagate
-            return False
-        self._reorder[batch_id] = (err, data)
-        return True
+        # wait on every live result queue at once, then take one record
+        ready = mp.connection.wait([q._reader for q in self._out_queues],
+                                   timeout_s)
+        for out in self._out_queues:
+            if out._reader not in ready:
+                continue
+            try:
+                batch_id, err, data = out.get(timeout=timeout_s)
+            except queue.Empty:
+                continue
+            except (EOFError, OSError, pickle.UnpicklingError):
+                # truncated record from a SIGKILLed worker; anything else
+                # (ImportError from an unpicklable payload, …) must
+                # propagate
+                continue
+            self._reorder[batch_id] = (err, data)
+            return True
+        return False
 
     # receive-poll quantum: short enough that dead-worker detection and
     # deadline checks run promptly (a 2 s quantum made respawn latency —

@@ -16,7 +16,7 @@ __all__ = [
     "linear", "dropout", "dropout2d", "dropout3d", "alpha_dropout", "embedding",
     "one_hot", "label_smooth", "pad", "interpolate", "upsample", "normalize",
     "cosine_similarity", "pixel_shuffle", "pixel_unshuffle", "channel_shuffle",
-    "unfold", "fold", "bilinear",
+    "unfold", "fold", "bilinear", "rotary_embedding",
 ]
 
 from ...tensor.manipulation import pad  # re-export (paddle exposes under F.pad)
@@ -426,3 +426,29 @@ def diag_embed(input, offset=0, dim1=-2, dim2=-1):
 
 
 __all__.append("diag_embed")
+
+
+def _rotate_half(x, positions, theta):
+    """Raw arrays: ``x`` [..., l, heads, d] rotated by its ``positions``
+    [..., l], a pair (i, i + d/2) of a head's columns by the angle
+    position * theta^(-2i/d): x cos + rotate_half(x) sin, the angles in
+    float32 whatever ``x`` is, the result in ``x``'s dtype."""
+    d = x.shape[-1]
+    inv = jnp.asarray(theta, jnp.float32) ** (
+        -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = positions.astype(jnp.float32)[..., None, None] * inv  # [.., l, 1, d/2]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x32 = x.astype(jnp.float32)
+    lo, hi = x32[..., :d // 2], x32[..., d // 2:]
+    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def rotary_embedding(x, positions, theta=10000.0, name=None):
+    """Rotary position embedding over a head's whole width (the
+    rotate-half pairing): ``x`` [b, l, heads, d], ``positions`` [b, l]
+    whole numbers. Raw arrays inside a traced function come back raw."""
+    if not isinstance(x, Tensor):
+        return _rotate_half(x, positions, theta)
+    pos = positions._value if isinstance(positions, Tensor) else positions
+    return apply_op(lambda a: _rotate_half(a, jnp.asarray(pos), theta), x)

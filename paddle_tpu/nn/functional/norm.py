@@ -265,7 +265,23 @@ def _ln_manual_bwd(epsilon, res, dy):
 _ln_manual.defvjp(_ln_manual_fwd, _ln_manual_bwd)
 
 
-def rms_norm(x, weight=None, epsilon=1e-05, gate=None, name=None):
+def group_rms(a, w, epsilon, groups):
+    """Raw arrays: ``a`` over its root mean square within each of
+    ``groups`` equal parts of the last axis (float32 statistic), times
+    ``w`` (the whole axis wide, or None), in ``a``'s dtype. The parts are
+    sliced and joined, so nothing asks for the groups as an axis."""
+    parts = []
+    for part in jnp.split(a.astype(jnp.float32), groups, axis=-1):
+        parts.append(part * jax.lax.rsqrt(
+            jnp.mean(jnp.square(part), axis=-1, keepdims=True) + epsilon))
+    out = parts[0] if groups == 1 else jnp.concatenate(parts, axis=-1)
+    if w is not None:
+        out = out * w.astype(jnp.float32)
+    return out.astype(a.dtype)
+
+
+def rms_norm(x, weight=None, epsilon=1e-05, gate=None, groups=None,
+             name=None):
     """x / rms(x) * weight over the last axis (as many trailing elements
     as ``weight`` has; all of them without one), the statistic in float32.
     With ``gate`` (``x``'s shape, or its last axes flattened) the result
@@ -274,7 +290,11 @@ def rms_norm(x, weight=None, epsilon=1e-05, gate=None, name=None):
     shape, [..., heads * d] with a head's d values side by side: the sums
     over a head are products with a 0/1 matrix (``ops.linear_attention.
     head_sums``), so that no operation asks for the heads as an axis (on a
-    TPU that is a relayout of the whole tensor, there and back)."""
+    TPU that is a relayout of the whole tensor, there and back).
+
+    With ``groups`` the last axis is normalised in that many equal parts,
+    each by its own statistic, under one weight as wide as the axis (the
+    grouped norm of a state-space mixer); no ``gate`` then."""
 
     def gated(a, w, g):
         from ...ops.linear_attention import head_sums, over_heads
@@ -294,6 +314,8 @@ def rms_norm(x, weight=None, epsilon=1e-05, gate=None, name=None):
         w = rest.pop(0) if weight is not None else None
         if gate is not None:
             return gated(a, w, rest[0])
+        if groups is not None:
+            return group_rms(a, w, epsilon, groups)
         a32 = a.astype(jnp.float32)
         out = a32 * jax.lax.rsqrt(
             jnp.mean(jnp.square(a32), axis=-1, keepdims=True) + epsilon)
@@ -301,6 +323,9 @@ def rms_norm(x, weight=None, epsilon=1e-05, gate=None, name=None):
             out = out * w.astype(jnp.float32)
         return out.astype(a.dtype)
 
+    if gate is not None and groups is not None:
+        raise ValueError("rms_norm: gate and groups are two forms; the "
+                         "grouped one takes its gate before the norm")
     args = [_t(x)] + [t for t in (weight, gate) if t is not None]
     return apply_op(f, *args)
 

@@ -17,8 +17,25 @@ from ..core import rng as rng_mod
 __all__ = [
     "Initializer", "Constant", "Normal", "TruncatedNormal", "Uniform",
     "XavierNormal", "XavierUniform", "KaimingNormal", "KaimingUniform",
-    "Assign", "Dirac", "Orthogonal", "calculate_gain",
+    "Assign", "Dirac", "Orthogonal", "calculate_gain", "LazyGuard",
 ]
+
+_LAZY = [0]  # depth of the LazyGuard contexts that are open
+
+
+class LazyGuard:
+    """``with LazyGuard(): model = Net()``: every parameter created inside
+    holds only its shape and type (a ``jax.ShapeDtypeStruct``), nothing is
+    drawn and no memory is taken, until ``jit.functionalize.set_params`` (or
+    ``set_value``) gives it its value. For a model whose weights come from
+    elsewhere and whose default initialisation would not fit beside them."""
+
+    def __enter__(self):
+        _LAZY[0] += 1
+        return self
+
+    def __exit__(self, *exc):
+        _LAZY[0] -= 1
 
 
 def calculate_gain(nonlinearity, param=None):
@@ -52,6 +69,9 @@ def _fans(shape):
 class Initializer:
     def __call__(self, shape, dtype=None, key=None):
         dtype = dtype_mod.convert_dtype(dtype) or dtype_mod.get_default_dtype()
+        if _LAZY[0]:
+            return jax.ShapeDtypeStruct(tuple(int(s) for s in shape),
+                                        jnp.dtype(dtype))
         if key is None:
             key = rng_mod.next_key()
         return self._generate(tuple(int(s) for s in shape), dtype, key)

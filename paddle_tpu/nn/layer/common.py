@@ -45,10 +45,12 @@ class Linear(Layer):
 
 class SwiGLU(Layer):
     """The gated MLP: down(silu(gate(x)) * up(x)), three matrices and no
-    bias. ``weight_attr`` initialises all three."""
+    bias. ``weight_attr`` initialises all three. ``gate_scale`` and
+    ``out_scale`` are a configuration's fixed multipliers, where it has
+    them: down(silu(gate(x) * gate_scale) * up(x)) * out_scale."""
 
     def __init__(self, hidden_size, intermediate_size, weight_attr=None,
-                 name=None):
+                 gate_scale=None, out_scale=None, name=None):
         super().__init__()
         self.gate_proj = Linear(hidden_size, intermediate_size, weight_attr,
                                 bias_attr=False)
@@ -56,14 +58,18 @@ class SwiGLU(Layer):
                               bias_attr=False)
         self.down_proj = Linear(intermediate_size, hidden_size, weight_attr,
                                 bias_attr=False)
+        self._gate_scale, self._out_scale = gate_scale, out_scale
         # TP: gate and up column-parallel, down row-parallel
         self.gate_proj.weight.tp_spec = (None, "mp")
         self.up_proj.weight.tp_spec = (None, "mp")
         self.down_proj.weight.tp_spec = ("mp", None)
 
     def forward(self, input):
-        return self.down_proj(F.silu(self.gate_proj(input))
-                              * self.up_proj(input))
+        gate = self.gate_proj(input)
+        if self._gate_scale is not None:
+            gate = gate * self._gate_scale
+        out = self.down_proj(F.silu(gate) * self.up_proj(input))
+        return out if self._out_scale is None else out * self._out_scale
 
 
 class Embedding(Layer):

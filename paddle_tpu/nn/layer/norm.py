@@ -155,10 +155,23 @@ class GatedRMSNorm(RMSNorm):
     """RMSNorm(x) * sigmoid(gate), the norm over the last axis of ``x``
     (a head's width, one learned weight shared by the heads); ``gate`` may
     come with the heads flattened, and is the shape the arithmetic runs
-    in (``F.rms_norm``)."""
+    in (``F.rms_norm``).
+
+    With ``groups`` it is a state-space mixer's form instead: the gate
+    comes first and through SiLU, RMSNorm_groups(x * silu(gate)) * weight,
+    the norm over each of ``groups`` equal parts of the last axis and the
+    weight ``hidden_size`` wide."""
+
+    def __init__(self, hidden_size, epsilon=1e-05, weight_attr=None,
+                 groups=None, name=None):
+        super().__init__(hidden_size, epsilon, weight_attr)
+        self._groups = groups
 
     def forward(self, input, gate):
-        return F.rms_norm(input, self.weight, self._epsilon, gate=gate)
+        if self._groups is None:
+            return F.rms_norm(input, self.weight, self._epsilon, gate=gate)
+        return F.rms_norm(input * F.silu(gate), self.weight, self._epsilon,
+                          groups=self._groups)
 
 
 class GroupNorm(Layer):

@@ -378,10 +378,38 @@ def _paged_mask(k_pos, q_positions, kv_lens):
             & (k_pos[None, None, :] < kv_lens[:, None, None]))
 
 
+def _paged_scores(q, k, eq):
+    """q [B, T, H, D] against k [B, S, Hkv, D] -> [B, H, T, S]. With as
+    many key heads as query heads it is the one einsum ``eq``; with fewer
+    (grouped queries: query head i reads key head i // (H / Hkv)) the
+    query heads are split [Hkv, H / Hkv] and the keys are read as they
+    are cached, never copied out to H heads."""
+    B, T, H, D = q.shape
+    Hkv = k.shape[2]
+    if Hkv == H:
+        return jnp.einsum(eq, q, k)
+    s = jnp.einsum("btkgd,bskd->bkgts", q.reshape(B, T, Hkv, H // Hkv, D), k)
+    return s.reshape(B, H, T, k.shape[1])
+
+
+def _paged_pv(p, v, eq):
+    """p [B, H, T, S] over v [B, S, Hkv, D]: what ``eq`` gives for equal
+    head counts ([B, T, H, D] or [B, H, T, D]), with grouped queries by
+    the same split."""
+    B, H, T, S = p.shape
+    Hkv = v.shape[2]
+    if Hkv == H:
+        return jnp.einsum(eq, p, v)
+    out = jnp.einsum("bkgts,bskd->bkgtd", p.reshape(B, Hkv, H // Hkv, T, S),
+                     v).reshape(B, H, T, v.shape[-1])
+    return out if eq.endswith("bhtd") else out.transpose(0, 2, 1, 3)
+
+
 def _paged_gather_impl(q, k_pages, v_pages, block_tables, q_positions,
                        kv_lens, k_scale=None, v_scale=None):
-    """q: [B, T, H, D]; k_pages/v_pages: [N, bs, H, D] (+ [N, bs, H]
-    scales for int8 pools); block_tables: [B, M] int32; q_positions:
+    """q: [B, T, H, D]; k_pages/v_pages: [N, bs, Hkv, D], or with the
+    heads flattened [N, bs, Hkv D] (+ [N, bs, Hkv] scales for int8
+    pools), H a multiple of Hkv; block_tables: [B, M] int32; q_positions:
     [B, T] int32 global positions; kv_lens: [B] int32 valid prefix."""
     B, T, H, D = q.shape
     bs = k_pages.shape[1]
@@ -389,18 +417,18 @@ def _paged_gather_impl(q, k_pages, v_pages, block_tables, q_positions,
     scale = 1.0 / math.sqrt(D)
     k = _paged_widen(k_pages[block_tables],
                      None if k_scale is None else k_scale[block_tables],
-                     jnp.float32).reshape(B, M * bs, H, D)
+                     jnp.float32).reshape(B, M * bs, -1, D)
     v = _paged_widen(v_pages[block_tables],
                      None if v_scale is None else v_scale[block_tables],
-                     jnp.float32).reshape(B, M * bs, H, D)
-    s = jnp.einsum("bthd,bkhd->bhtk", q.astype(jnp.float32) * scale, k)
+                     jnp.float32).reshape(B, M * bs, -1, D)
+    s = _paged_scores(q.astype(jnp.float32) * scale, k, "bthd,bkhd->bhtk")
     k_pos = jnp.arange(M * bs, dtype=jnp.int32)
     mask = _paged_mask(k_pos, q_positions, kv_lens)
     s = jnp.where(mask[:, None], s, _NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - m)
     p = e / jnp.maximum(e.sum(axis=-1, keepdims=True), 1e-30)
-    out = jnp.einsum("bhtk,bkhd->bthd", p, v)
+    out = _paged_pv(p, v, "bhtk,bkhd->bthd")
     return out.astype(q.dtype)
 
 
@@ -420,18 +448,20 @@ def _paged_scan_impl(q, k_pages, v_pages, block_tables, q_positions,
         pids = block_tables[:, i]  # [B]
         kc = _paged_widen(k_pages[pids],
                           None if k_scale is None else k_scale[pids],
-                          jnp.float32)  # [B, bs, H, D]
+                          jnp.float32)  # [B, bs, Hkv, D]
         vc = _paged_widen(v_pages[pids],
                           None if v_scale is None else v_scale[pids],
                           jnp.float32)
-        s = jnp.einsum("bthd,bshd->bhts", qf, kc)
+        if kc.ndim == 3:  # a pool that keeps the heads flattened
+            kc, vc = (t.reshape(B, bs, -1, D) for t in (kc, vc))
+        s = _paged_scores(qf, kc, "bthd,bshd->bhts")
         k_pos = i * bs + jnp.arange(bs, dtype=jnp.int32)
         mask = _paged_mask(k_pos, q_positions, kv_lens)
         s = jnp.where(mask[:, None], s, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
-        acc = acc * corr[..., None] + jnp.einsum("bhts,bshd->bhtd", p, vc)
+        acc = acc * corr[..., None] + _paged_pv(p, vc, "bhts,bshd->bhtd")
         l = l * corr + p.sum(axis=-1)
         return (acc, m_new, l), None
 
@@ -451,8 +481,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, q_positions,
     Args:
         q: [B, T, H, D] query chunk (T=1 plain decode; T=k+1 speculative
             verify; T=chunk_size chunked prefill).
-        k_pages/v_pages: one layer's pool pages [N, bs, H, D] (int8 or
-            float storage).
+        k_pages/v_pages: one layer's pool pages [N, bs, Hkv, D], or
+            [N, bs, Hkv D] with the heads flattened (int8 or float
+            storage); H is a multiple of Hkv (grouped queries), and the
+            pages are read as they lie, never copied out to H heads.
         block_tables: [B, M] int32 page ids (scratch-padded).
         q_positions: [B, T] int32 global position of each query token.
         kv_lens: [B] int32 — number of valid cache positions (tokens of
@@ -472,8 +504,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, q_positions,
     B, T, H, D = q.shape
     bs = k_pages.shape[1]
     M = block_tables.shape[1]
+    Hkv = k_pages.shape[2] if k_pages.ndim == 4 else k_pages.shape[2] // D
     tier = tier_policy.select_paged(T, H, D, M, bs, q.dtype,
-                                    k_scale is not None)
+                                    k_scale is not None, hkv=Hkv)
     get_telemetry().gauge(f"attn/tier.paged.t{T}.d{D}",
                           tier_policy.TIER_IDS.get(tier, -1))
     impl = (_paged_gather_impl if tier == "paged_gather"

@@ -1,6 +1,7 @@
 """Linear attention with a recurrent state: the gated delta rule with a
-per-channel decay (Kimi Delta Attention), and the short causal convolution
-that feeds it. ``chunk_kda`` is one algorithm on two lowerings, chosen by
+per-channel decay (Kimi Delta Attention), the scalar-decay state-space
+recurrence of Mamba-2 (``chunk_ssd``, ``ssd_step``: at the end of this
+file), and the short causal convolution that feeds either. ``chunk_kda`` is one algorithm on two lowerings, chosen by
 rule (``_tier``): on a TPU, at head widths that are multiples of 128, the
 Pallas kernel pair of ``ops/kda_tpu.py``, which holds a chunk's
 intermediates in VMEM, forward and hand-written backward; everywhere else
@@ -47,24 +48,41 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["short_conv", "chunk_kda"]
+__all__ = ["short_conv", "chunk_kda", "chunk_ssd", "ssd_step"]
 
 F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
 _SUB = 16  # rows of a sub-block of A and B, and of the inverse's base case
 
 
-def short_conv(x, w):
+def short_conv(x, w, tail=None, valid=None):
     """Depthwise causal convolution along the sequence: ``x`` [b, l, c],
     ``w`` [c, k]; y_t = sum_j w[:, j] x_{t-(k-1)+j}, zeros before the
-    start (a ``Conv1d(c, c, k, groups=c, padding=k-1)`` cut to l)."""
+    start (a ``Conv1d(c, c, k, groups=c, padding=k-1)`` cut to l).
+
+    With ``tail`` [b, k-1, c], the inputs that came before ``x`` (a cached
+    sequence's carried tail; zeros at its start), they stand before the
+    start instead of zeros, and the call returns ``(y, new_tail)``: the
+    last k-1 inputs of tail + x, or, with ``valid`` [b] (how many leading
+    positions of each row are real), the k-1 inputs before the padding."""
     k = w.shape[-1]
+    l = x.shape[1]
     x32, w32 = x.astype(F32), w.astype(F32)
     y = x32 * w32[:, k - 1]
+    if tail is None:
+        for back in range(1, k):
+            shifted = jnp.pad(x32, ((0, 0), (back, 0), (0, 0)))[:, :l]
+            y = y + shifted * w32[:, k - 1 - back]
+        return y.astype(x.dtype)
+    joined = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    before = joined.astype(F32)
     for back in range(1, k):
-        shifted = jnp.pad(x32, ((0, 0), (back, 0), (0, 0)))[:, :x.shape[1]]
-        y = y + shifted * w32[:, k - 1 - back]
-    return y.astype(x.dtype)
+        y = y + before[:, k - 1 - back:k - 1 - back + l] * w32[:, k - 1 - back]
+    if valid is None:
+        return y.astype(x.dtype), joined[:, l:]
+    at = valid[:, None] + jnp.arange(k - 1, dtype=valid.dtype)
+    return y.astype(x.dtype), jnp.take_along_axis(joined, at[..., None],
+                                                  axis=1)
 
 
 def _mm(eq, a, b, dtype):
@@ -371,3 +389,108 @@ def chunk_kda(q, k, v, g, beta, eps=1e-6, chunk=64, checkpoint=True):
     # heads that would be a relayout, so the heads are flattened outside
     flat = lambda t: t.reshape(*t.shape[:2], -1)  # noqa: E731
     return fn(flat(q), flat(k), flat(v), flat(g), beta).reshape(v.shape)
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2's state-space duality (SSD): a scalar decay a head, no delta term
+# ---------------------------------------------------------------------------
+# Per head, with S in R^{p x n} (p the head's width, n the state's), x_t in
+# R^p, B_t and C_t in R^n shared by the heads of a group, dt_t > 0 and
+# A < 0 scalars:
+#
+#     S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T
+#     y_t = S_t C_t + D x_t
+#
+# ``chunk_ssd`` takes it a chunk of c tokens at a time. With a the chunk's
+# running sum of dt A (every exponent taken is a difference a_i - a_j,
+# j <= i, so <= 0):
+#
+#     Y   = ((C B^T) * exp(a_i - a_j) * dt_j  for j <= i) X  +  exp(a_i) C S_0
+#     S_c = exp(a_c) S_0 + sum_j exp(a_c - a_j) dt_j x_j B_j^T
+#
+# so only S crosses chunks. A position with dt = 0 decays nothing and writes
+# nothing: that is the rule for positions that are not there (a chunk's
+# padded tail, a bucket's padded rows); the caller zeroes their dt, and the
+# state passes them unchanged. The state and every decay are float32; the
+# products take float32 operands at ``HIGHEST`` (they are small beside a
+# block's projections at any length served).
+
+def _ssd_heads(t, heads):
+    """[..., g, n] -> [..., heads, n]: head i reads group i // (heads/g)."""
+    return jnp.repeat(t, heads // t.shape[-2], axis=-2)
+
+
+def chunk_ssd(x, dt, A, B, C, D, chunk=128, initial_state=None):
+    """``x`` [b, l, h, p], ``dt`` [b, l, h] (after its softplus; 0 where a
+    position is padding), ``A`` [h] (< 0), ``B``, ``C`` [b, l, g, n] with g
+    dividing h, ``D`` [h], ``initial_state`` [b, h, p, n] float32 (None:
+    zeros). Returns ``(y [b, l, h, p] in x's dtype, final_state float32)``.
+    Any length: the tail is padded to a whole chunk with dt = 0."""
+    from ..profiler.telemetry import get_telemetry
+
+    tel = get_telemetry()  # trace-time facts, like kda/calls
+    tel.counter("ssm/calls")
+    tel.gauge("ssm/chunk", chunk)
+    tel.gauge("ssm/form.chunked", 1)
+    b, l, h, p = x.shape
+    n = B.shape[-1]
+    x_in = x
+    x, dt, B, C = _whole_chunks(chunk, x.astype(F32), dt.astype(F32),
+                                B.astype(F32), C.astype(F32))
+    nc = x.shape[1] // chunk
+    # [b, nc, c, ...]
+    x, dt, B, C = (t.reshape(b, nc, chunk, *t.shape[2:])
+                   for t in (x, dt, B, C))
+    a = jnp.cumsum(dt * A.astype(F32), axis=2)           # [b, nc, c, h]
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    diff = a[:, :, :, None, :] - a[:, :, None, :, :]     # [b, nc, i, j, h]
+    decay = jnp.where(lower[..., None],
+                      jnp.exp(jnp.where(lower[..., None], diff, 0.0)), 0.0)
+    g = B.shape[-2]
+    scores = _mm("bzign,bzjgn->bzijg", C, B, F32)        # [b, nc, i, j, g]
+    weights = _ssd_heads(scores[..., None], h)[..., 0] if g != h else scores
+    weights = weights * decay * dt[:, :, None, :, :]     # [b, nc, i, j, h]
+    y = _mm("bzijh,bzjhp->bzihp", weights, x, F32)
+    # what each chunk writes into an empty state, and what it keeps of the
+    # state that comes in
+    last = a[:, :, -1:, :]                               # [b, nc, 1, h]
+    wrote = _mm("bzjhp,bzjhn->bzhpn",
+                x * (jnp.exp(last - a) * dt)[..., None], _ssd_heads(B, h),
+                F32)                                     # [b, nc, h, p, n]
+    keep = jnp.exp(last[:, :, 0])                        # [b, nc, h]
+    S0 = (jnp.zeros((b, h, p, n), F32) if initial_state is None
+          else initial_state.astype(F32))
+
+    def step(S, xs):
+        keep_c, wrote_c = xs
+        return keep_c[..., None, None] * S + wrote_c, S
+
+    final, entering = jax.lax.scan(
+        step, S0, (jnp.moveaxis(keep, 1, 0), jnp.moveaxis(wrote, 1, 0)))
+    entering = jnp.moveaxis(entering, 0, 1)              # [b, nc, h, p, n]
+    y = y + _mm("bzihn,bzhpn->bzihp",
+                _ssd_heads(C, h) * jnp.exp(a)[..., None], entering, F32)
+    y = y + x * D.astype(F32)[:, None]
+    y = y.reshape(b, nc * chunk, h, p)[:, :l]
+    return y.astype(x_in.dtype), final
+
+
+def ssd_step(x, dt, A, B, C, D, state):
+    """One token of the same recurrence: ``x`` [b, h, p], ``dt`` [b, h],
+    ``B``, ``C`` [b, g, n], ``state`` [b, h, p, n] float32. Returns ``(y
+    [b, h, p] in x's dtype, new state)``. One pass over the state: it is
+    read, decayed, written to and summed against C elementwise, in
+    float32."""
+    from ..profiler.telemetry import get_telemetry
+
+    tel = get_telemetry()
+    tel.counter("ssm/calls")
+    tel.gauge("ssm/form.step", 1)
+    h = x.shape[1]
+    x32, dt = x.astype(F32), dt.astype(F32)
+    B, C = _ssd_heads(B.astype(F32), h), _ssd_heads(C.astype(F32), h)
+    keep = jnp.exp(dt * A.astype(F32))
+    state = (keep[..., None, None] * state.astype(F32)
+             + (dt[..., None] * x32)[..., None] * B[:, :, None, :])
+    y = jnp.sum(state * C[:, :, None, :], axis=-1) + x32 * D.astype(F32)[:, None]
+    return y.astype(x.dtype), state

@@ -269,14 +269,15 @@ def policy_mode() -> str:
 
 
 def make_paged_key(t: int, h: int, d: int, m: int, bs: int, dtype,
-                   quantized: bool) -> str:
+                   quantized: bool, hkv: Optional[int] = None) -> str:
     """Decode-shape verdict key: query chunk length, heads, head_dim,
     table width x block size (the gathered-context geometry), storage
     dtype. Batch is deliberately absent: both tiers scale ~linearly in
     B, so the ranking is batch-invariant and one verdict (timed at batch
     1) covers every decode bucket."""
     q = "int8" if quantized else str(dtype)
-    return f"{_backend_key()}:paged:t{t}:h{h}:d{d}:m{m}x{bs}:{q}"
+    heads = f"h{h}" if hkv in (None, h) else f"h{h}kv{hkv}"  # grouped queries
+    return f"{_backend_key()}:paged:t{t}:{heads}:d{d}:m{m}x{bs}:{q}"
 
 
 def _paged_heuristic(m: int, bs: int) -> str:
@@ -288,10 +289,11 @@ def _paged_heuristic(m: int, bs: int) -> str:
 
 
 def bench_paged(key: str, t: int, h: int, d: int, m: int, bs: int, dtype,
-                quantized: bool) -> Optional[dict]:
+                quantized: bool, hkv: Optional[int] = None) -> Optional[dict]:
     """Time both paged tiers at [1, t, h, d] queries over an [m*bs]-token
-    paged context and record the winner — forward only (decode is
-    inference; there is no backward to weigh in)."""
+    paged context of ``hkv`` key heads (``h`` where not given) and record
+    the winner — forward only (decode is inference; there is no backward
+    to weigh in)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -302,6 +304,7 @@ def bench_paged(key: str, t: int, h: int, d: int, m: int, bs: int, dtype,
     rng = np.random.RandomState(0)
     with jax.ensure_compile_time_eval():
         q = jnp.asarray(rng.randn(1, t, h, d).astype(np.float32), dtype)
+        h = hkv or h  # the pages' heads from here on
         if quantized:
             k_pages = jnp.asarray(
                 rng.randint(-127, 127, (m + 1, bs, h, d)), jnp.int8)
@@ -360,7 +363,7 @@ def bench_paged(key: str, t: int, h: int, d: int, m: int, bs: int, dtype,
 
 
 def select_paged(t: int, h: int, d: int, m: int, bs: int, dtype,
-                 quantized: bool) -> str:
+                 quantized: bool, hkv: Optional[int] = None) -> str:
     """The paged tier for this decode shape. Forced > cached verdict >
     fresh micro-bench (bench mode) > heuristic. A pure cache hit is one
     dict lookup at trace time — the verdict bakes into the compiled
@@ -369,10 +372,11 @@ def select_paged(t: int, h: int, d: int, m: int, bs: int, dtype,
     if mode in PAGED_TIERS:
         return mode
     if mode == "bench":
-        key = make_paged_key(t, h, d, m, bs, dtype, quantized)
+        key = make_paged_key(t, h, d, m, bs, dtype, quantized, hkv)
         verdict = _registry.verdict(key)
         if verdict is None or verdict.get("tier") not in PAGED_TIERS:
-            verdict = bench_paged(key, t, h, d, m, bs, dtype, quantized)
+            verdict = bench_paged(key, t, h, d, m, bs, dtype, quantized,
+                                  hkv)
         if verdict is not None:
             return verdict["tier"]
     return _paged_heuristic(m, bs)

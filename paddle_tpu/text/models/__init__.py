@@ -10,6 +10,13 @@ from .bert import (  # noqa: F401
     ernie_1_5b,
     ernie_3_0_medium,
 )
+from .falcon_h1 import (  # noqa: F401
+    FalconH1Config,
+    FalconH1ForCausalLM,
+    FalconH1Model,
+    falcon_h1_decode_fns,
+    falcon_h1_tiny,
+)
 from .gpt import (  # noqa: F401
     GPT,
     GPTConfig,

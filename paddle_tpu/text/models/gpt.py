@@ -213,6 +213,18 @@ class GPTForCausalLM(nn.Layer):
             return loss
         return logits
 
+    def decode_spec(self, kv_dtype: str = "float32") -> dict:
+        """What ``inference.serving.TokenServingEngine`` asks a model: the
+        cached forward and the cache it runs over (here K and V pages of as
+        many heads as the queries have, no recurrent state)."""
+        c = self.config
+        return {"forward_chunk": gpt_decode_fns(c, kv_dtype),
+                "num_layers": c.num_layers, "num_heads": c.num_heads,
+                "num_kv_heads": c.num_heads,
+                "head_dim": c.hidden_size // c.num_heads,
+                "max_positions": c.max_position_embeddings,
+                "kv_layout": "stacked", "state": None}
+
     def loss_fn(self, logits, labels):
         with jax.named_scope("head_loss"):
             return F.cross_entropy(
@@ -302,7 +314,7 @@ def gpt_decode_fns(config: GPTConfig, kv_dtype: str = "float32"):
     differing only in T.
 
     Returns ``forward_chunk(params, tokens, q_positions, pages,
-    block_tables, kv_lens) -> (logits [B, T, V], pages)`` where
+    block_tables, kv_lens, slots=None) -> (logits [B, T, V], pages)`` where
     ``params`` is the flat ``jit.functionalize.get_params`` dict of a
     ``GPTForCausalLM`` and ``pages`` is a ``KVCachePool.pages`` pytree
     (paged layout + scratch-page convention documented in
@@ -334,7 +346,7 @@ def gpt_decode_fns(config: GPTConfig, kv_dtype: str = "float32"):
         return (x - mean) * jax.lax.rsqrt(var + eps) * w + b
 
     def forward_chunk(params, tokens, q_positions, pages, block_tables,
-                      kv_lens):
+                      kv_lens, slots=None):  # no recurrent state: no slots
         B, T = tokens.shape
         bs = pages["k"].shape[2]
         # scatter targets: token t of row b lands in table slot

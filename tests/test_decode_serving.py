@@ -8,6 +8,7 @@ import os
 import subprocess
 import sys
 import textwrap
+import time
 
 import numpy as np
 import pytest
@@ -70,6 +71,125 @@ def make_engine(model=None, draft=None, **kw):
     defaults.update(kw)
     return TokenServingEngine(model, TokenServeConfig(**defaults),
                               draft_model=draft), model
+
+
+# ---------------------------------------------------------------------------
+# Recurrent-state slots beside the pages (one cache manager)
+# ---------------------------------------------------------------------------
+STATE = {"ssm": ((2, 4, 8), "float32"), "conv": ((3, 12), "float32")}
+
+
+def state_pool(**kw):
+    d = dict(num_layers=2, num_heads=4, head_dim=8, num_kv_heads=2,
+             num_blocks=8, block_size=4, layout="per_layer", state=STATE,
+             state_slots=2)
+    d.update(kw)
+    return KVCachePool(KVCacheConfig(**d))
+
+
+def falcon_engine(**kw):
+    from paddle_tpu.text.models.falcon_h1 import (FalconH1ForCausalLM,
+                                                  falcon_h1_tiny)
+
+    paddle.seed(4)
+    model = FalconH1ForCausalLM(falcon_h1_tiny(
+        vocab_size=96, hidden_size=32, intermediate_size=64,
+        num_hidden_layers=2, mamba_d_ssm=32, mamba_n_heads=2))
+    model.eval()
+    defaults = dict(capacity=16, decode_buckets=(1, 2), prefill_chunk=8,
+                    kv_blocks=48, kv_block_size=8, max_seq_len=96)
+    defaults.update(kw)
+    return TokenServingEngine(model, TokenServeConfig(**defaults))
+
+
+class TestStateSlots:
+    def test_leaves_and_layout(self):
+        pool = state_pool()
+        # a layer's pages of its own, the key heads flattened; slot 0 is
+        # the scratch slot
+        assert len(pool.pages["k"]) == 2
+        assert pool.pages["k"][0].shape == (8, 4, 16)
+        assert pool.pages["ssm"][1].shape == (3, 2, 4, 8)
+        assert pool.pages["conv"][0].shape == (3, 3, 12)
+        with pytest.raises(ValueError, match="int8"):
+            state_pool(dtype="int8")
+        with pytest.raises(ValueError, match="state_slots"):
+            state_pool(state_slots=0)
+
+    def test_slots_in_the_leak_ledger(self):
+        tel = get_telemetry()
+        pool = state_pool()
+        assert pool.slot(7) == 0  # holds none: the scratch slot
+        assert pool.ensure(7, 5) and pool.ensure(8, 3)
+        assert {pool.slot(7), pool.slot(8)} == {1, 2}
+        assert pool.ensure(7, 9)  # growing keeps the slot
+        acct = pool.accounting()
+        assert acct["used_slots"] == 2 and acct["slot_owners"] == [7, 8]
+        assert pool.state_occupancy() == 1.0
+        assert tel.snapshot()["gauges"]["serve/state_slots_used"] == 2
+        # blocks are left, slots are not: nothing is grabbed
+        used = pool.used_blocks
+        assert not pool.ensure(9, 2)
+        assert pool.used_blocks == used and pool.owned(9) == []
+        pool.release(7)
+        assert pool.ensure(9, 2) and pool.slot(9) in (1, 2)
+        pool.release(8)
+        pool.release(9)
+        pool.release(9)  # idempotent
+        acct = pool.accounting()
+        assert acct["leaked_slots"] == 0 and acct["slot_owners"] == []
+        assert acct["leaked_blocks"] == 0
+        gauges = tel.snapshot()["gauges"]
+        assert gauges["serve/state_slots_total"] == 2
+        assert gauges["serve/state_occupancy"] == 0.0
+
+    def test_a_pool_without_state_has_no_slots(self):
+        pool = KVCachePool(KVCacheConfig(2, 2, 8, num_blocks=8,
+                                         block_size=4))
+        assert pool.ensure(1, 4) and pool.slot(1) == 0
+        assert "leaked_slots" not in pool.accounting()
+        assert pool.state_occupancy() == 0.0
+
+    @pytest.mark.parametrize("ending", ["ok", "deadline", "drained",
+                                        "rejected"])
+    def test_slots_go_back_through_finish_on_every_terminal_status(
+            self, ending):
+        kw = {"rejected": dict(capacity=1, max_running=1,
+                               decode_buckets=(1,)),
+              "drained": dict(drain_grace_s=0.0)}.get(ending, {})
+        eng = falcon_engine(**kw)
+        eng.start()
+        try:
+            prompt = np.arange(9, dtype=np.int32)
+            if ending == "ok":
+                reqs = [eng.submit(prompt, max_new_tokens=4)]
+            elif ending == "deadline":
+                reqs = [eng.submit(prompt, max_new_tokens=80,
+                                   deadline_s=0.05)]
+            elif ending == "drained":
+                reqs = [eng.submit(prompt, max_new_tokens=80)
+                        for _ in range(2)]
+                while not any(r.generated for r in reqs):
+                    time.sleep(0.01)
+                assert eng.pool.accounting()["used_slots"] >= 1
+            else:
+                reqs = [eng.submit(prompt, max_new_tokens=30)
+                        for _ in range(10)]
+                assert any(r.status == RequestStatus.REJECTED for r in reqs)
+            if ending != "drained":
+                for r in reqs:
+                    assert r.wait(300)
+        finally:
+            acct = eng.shutdown()
+        statuses = {r.status for r in reqs}
+        if ending == "ok":
+            assert statuses == {RequestStatus.OK}
+        elif ending == "drained":
+            assert RequestStatus.DRAINED in statuses
+        assert acct["unaccounted"] == [] and acct["double_terminal"] == 0
+        kv = eng.kv_accounting()
+        assert kv["leaked_slots"] == 0 and kv["slot_owners"] == []
+        assert kv["leaked_blocks"] == 0 and kv["owners"] == []
 
 
 # ---------------------------------------------------------------------------
@@ -778,6 +898,16 @@ class TestSchemaContracts:
     def test_occupancy_range(self):
         assert self.validate({"gauge/serve/kv_occupancy": 1.2})
         assert self.validate({"gauge/serve/spec_accept_rate": -0.1})
+
+    def test_state_slot_gauges(self):
+        ok = {"gauge/serve/state_slots_total": 4,
+              "gauge/serve/state_slots_used": 3,
+              "gauge/serve/state_occupancy": 0.75,
+              "counter/serve/state_resets": 9}
+        assert self.validate(ok) is None
+        assert self.validate({**ok, "gauge/serve/state_occupancy": 1.5})
+        assert self.validate({**ok, "gauge/serve/state_slots_used": 5})
+        assert self.validate({**ok, "counter/serve/state_resets": -1})
 
     def test_negative_ttft_rejected(self):
         assert self.validate({"hist/serve/ttft_ms/p50": -3.0})

@@ -116,9 +116,11 @@ non-negative integer, ``categories`` keys ⊆ the closed vocabulary with
 values ≥ 0 summing to ``wall_s`` within the same tolerance.
 
 Token-level serving contracts (``inference.serving.decode``):
-``gauge/serve/kv_occupancy`` ∈ [0, 1] and
-``gauge/serve/spec_accept_rate`` ∈ [0, 1] (both are fractions by
-definition); ``gauge/serve/kv_blocks_{total,used}`` ≥ 0; and within one
+``gauge/serve/kv_occupancy``, ``gauge/serve/state_occupancy`` (the
+recurrent-state slots' share in use) and
+``gauge/serve/spec_accept_rate`` ∈ [0, 1] (fractions by definition);
+``gauge/serve/kv_blocks_{total,used}`` and
+``gauge/serve/state_slots_{total,used}`` ≥ 0; and within one
 record ``kv_blocks_used`` ≤ ``kv_blocks_total`` AND ``kv_occupancy``
 must equal ``used/total`` (small tolerance) — an occupancy gauge that
 disagrees with the block ledger it summarizes means the pool's
@@ -327,13 +329,16 @@ def validate_record(rec, lineno):
                 or name.startswith("hist/serve/draft_ms")
                 or name.startswith("hist/serve/draft_prefill_ms")
                 or name in ("gauge/serve/kv_blocks_total",
-                            "gauge/serve/kv_blocks_used")) \
+                            "gauge/serve/kv_blocks_used",
+                            "gauge/serve/state_slots_total",
+                            "gauge/serve/state_slots_used")) \
                 and float(value) < 0:
             return (f"line {lineno}: scalar {name!r} = {value!r} "
                     f"is negative (serve totals/latencies are >= 0)")
         # token-serving fractions: occupancy of the KV pool and the
         # speculative acceptance rate are [0, 1] by definition
         if name in ("gauge/serve/kv_occupancy",
+                    "gauge/serve/state_occupancy",
                     "gauge/serve/spec_accept_rate") \
                 and not (0 <= float(value) <= 1):
             return (f"line {lineno}: scalar {name!r} = {value!r} "
@@ -462,6 +467,13 @@ def validate_record(rec, lineno):
             return (f"line {lineno}: gauge/serve/kv_occupancy = {occ!r} "
                     f"inconsistent with kv_blocks_used/total = "
                     f"{used!r}/{total!r}")
+    slots_used = scalars.get("gauge/serve/state_slots_used")
+    slots = scalars.get("gauge/serve/state_slots_total")
+    if slots_used is not None and slots is not None \
+            and float(slots_used) > float(slots):
+        return (f"line {lineno}: gauge/serve/state_slots_used = "
+                f"{slots_used!r} exceeds gauge/serve/state_slots_total = "
+                f"{slots!r} (a sequence holds one slot)")
     # cross-field: the admission queue is BOUNDED — its observed depth
     # can never exceed the capacity the same record reports
     depth = scalars.get("gauge/serve/queue_depth")
