@@ -7,9 +7,11 @@ it (sequences finish early, prompts vary 10-100x); this pool instead
 carves one device allocation into fixed-size **blocks** and hands them to
 sequences on demand, vLLM-style:
 
-- device side: ``pages['k'] / pages['v']`` are
-  ``[num_layers, num_blocks, block_size, heads, head_dim]`` arrays; a
-  token at logical position ``p`` of a sequence lives in page
+- device side: ``pages['k'] / pages['v']`` hold, a layer, ``num_blocks``
+  pages of ``block_size`` tokens (how the layers and the heads lie is the
+  **layout**, below: float pages a layer to an array with the heads flat,
+  ``[num_blocks, block_size, kv heads * head_dim]``); a token at logical
+  position ``p`` of a sequence lives in page
   ``block_table[p // block_size]`` at slot ``p % block_size``. The pages
   pytree flows through the jitted decode step (donated — the pool is the
   single largest serving buffer, it must never exist twice).
@@ -40,12 +42,20 @@ that free the blocks free the slot too; nothing is cleared on the host:
 the compiled step starts a sequence's state from zero at the chunk whose
 first position is 0.
 
-**Layout** (``layout=``): ``"stacked"`` keeps K and V as one array each,
-``[layers, blocks, block, kv heads, head_dim]``; ``"per_layer"`` keeps a
-tuple of one array a layer, ``[blocks, block, kv heads * head_dim]``, the
-heads flattened into one minor axis that is whole lanes wide, so that a
-layer's scatter and gather touch that layer's array alone and no step
-slices or relays out the whole pool (PERF.md, section 5). State leaves are
+**Layout** (``layout=``, named by the model's ``decode_spec``):
+``"per_layer"`` keeps a tuple of one array a layer, ``[blocks, block,
+kv heads * head_dim]``, the heads flattened into one minor axis that is
+whole lanes wide, so that a layer's scatter and gather touch that layer's
+array alone and no step slices or relays out the whole pool. Every float
+pool is laid out so (Falcon-H1's since PR 35, GPT's since PR 36).
+``"stacked"`` keeps K and V as one array each, ``[layers, blocks, block,
+kv heads, head_dim]``, and is left for int8 storage alone: its scale a
+token-head (``[layers, blocks, block, kv heads]``) wants the heads as an
+axis of the pages beside it. A step over a stacked pool copies the whole
+pool into a lane-padded layout and back (a head_dim of 64 under 128
+lanes) and writes a layer's slice out again around each scatter: 61 ms of
+every step of GPT-2 345M over 2,304 blocks (PERF.md, section 6, PR 36); no
+cell serves int8, and one layout for both is ROADMAP D10. State leaves are
 always a tuple of one array a layer.
 """
 from __future__ import annotations

@@ -432,46 +432,120 @@ def _paged_gather_impl(q, k_pages, v_pages, block_tables, q_positions,
     return out.astype(q.dtype)
 
 
+_LANES = 128  # the minor tile of the chip: a vector register's lanes
+
+
+def _lane_group(T, H, D, k_pages, k_scale):
+    """Heads that share one 128-lane group of a flat page, or 0 where the
+    scan takes the heads as an axis. A group form is taken for a decode
+    step (one query a row) over float pages with the heads flattened, as
+    many key heads as query heads, a head narrower than the lanes and
+    whole groups of heads (GPT's 16 heads of 64: 2 a group).
+
+    Why, measured on a v5e at GPT-2 345M's widths (PERF.md, section 6,
+    PR 36): a page reshaped to ``[.., heads, 64]`` is padded to 128 lanes
+    and laid out anew, 4.8 us a gathered page of 64 rows, twice an
+    iteration and once a page whatever the queries; cut into groups of 128
+    lanes it keeps the layout it was gathered in, at the price of the
+    neighbour heads' products, paid once a query. One query a row: a step
+    of 28.4 ms against 51.8. A prefill chunk (one row of 256 queries, a
+    page of 16 rows to relay): 16.3 ms against 12.0, so it keeps the heads
+    as an axis. Between the two nothing is measured."""
+    if T != 1 or k_scale is not None or k_pages.ndim != 3 or D >= _LANES \
+            or _LANES % D or k_pages.shape[2] != H * D:
+        return 0
+    r = _LANES // D
+    return 0 if H % r else r
+
+
 def _paged_scan_impl(q, k_pages, v_pages, block_tables, q_positions,
                      kv_lens, k_scale=None, v_scale=None):
     """Online-softmax scan over table slots — the flash recurrence over
     pages. Only one [B, bs, H, D] page pair is live (and, for int8
-    pools, dequantized) per step."""
+    pools, dequantized) per step.
+
+    Two forms of one recurrence, chosen by ``_lane_group`` from the
+    queries, the pages and the heads: with the heads as an axis (``[B, bs,
+    Hkv, D]`` pages: int8 pools, grouped queries, heads a lane group wide
+    or wider, chunks of many queries), or, for a decode step over flat
+    float pages of narrow heads, a lane group at a time: the page is read
+    as ``[B, bs, G, 128]``, each head's query sits in its own lanes of its
+    group with zeros in its neighbours', and the running output is kept
+    flat, ``[B, G, T, 128]``, so nothing in the loop lays a page out anew.
+    The products and their order are those of the first form plus exact
+    zeros."""
     B, T, H, D = q.shape
     bs = k_pages.shape[1]
     M = block_tables.shape[1]
     scale = 1.0 / math.sqrt(D)
     qf = q.astype(jnp.float32) * scale
+    r = _lane_group(T, H, D, k_pages, k_scale)
+
+    if r:
+        G = H // r
+        # own[j, 0, lane]: the lane belongs to its group's j-th head
+        own = (jnp.arange(_LANES)[None, :] // D
+               == jnp.arange(r)[:, None])[:, None, :]
+        qm = jnp.where(own[:, 0], qf.reshape(B, T, G, 1, _LANES), 0.0)
+
+        def to_lanes(x):  # a number a head [B, H, T] -> over its lanes
+            return jnp.where(own, x.reshape(B, G, r, T, 1), 0.0).sum(axis=2)
+
+        def scores(kc):
+            return jnp.einsum("btgjl,bsgl->bgjts", qm,
+                              kc.reshape(B, bs, G, _LANES)
+                              ).reshape(B, H, T, bs)
+
+        def advance(acc, corr, p, vc):
+            o = jnp.einsum("bgjts,bsgl->bgjtl", p.reshape(B, G, r, T, bs),
+                           vc.reshape(B, bs, G, _LANES))
+            return acc * to_lanes(corr) + jnp.where(own, o, 0.0).sum(axis=2)
+
+        acc0 = jnp.zeros((B, G, T, _LANES), jnp.float32)
+    else:
+        def scores(kc):
+            if kc.ndim == 3:  # a pool that keeps the heads flattened
+                kc = kc.reshape(B, bs, -1, D)
+            return _paged_scores(qf, kc, "bthd,bshd->bhts")
+
+        def advance(acc, corr, p, vc):
+            if vc.ndim == 3:
+                vc = vc.reshape(B, bs, -1, D)
+            return (acc * corr[..., None]
+                    + _paged_pv(p, vc, "bhts,bshd->bhtd"))
+
+        acc0 = jnp.zeros((B, H, T, D), jnp.float32)
 
     def body(carry, i):
         acc, m, l = carry
         pids = block_tables[:, i]  # [B]
         kc = _paged_widen(k_pages[pids],
                           None if k_scale is None else k_scale[pids],
-                          jnp.float32)  # [B, bs, Hkv, D]
+                          jnp.float32)  # [B, bs, Hkv, D] or [B, bs, Hkv D]
         vc = _paged_widen(v_pages[pids],
                           None if v_scale is None else v_scale[pids],
                           jnp.float32)
-        if kc.ndim == 3:  # a pool that keeps the heads flattened
-            kc, vc = (t.reshape(B, bs, -1, D) for t in (kc, vc))
-        s = _paged_scores(qf, kc, "bthd,bshd->bhts")
+        s = scores(kc)
         k_pos = i * bs + jnp.arange(bs, dtype=jnp.int32)
         mask = _paged_mask(k_pos, q_positions, kv_lens)
         s = jnp.where(mask[:, None], s, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
-        acc = acc * corr[..., None] + _paged_pv(p, vc, "bhts,bshd->bhtd")
+        acc = advance(acc, corr, p, vc)
         l = l * corr + p.sum(axis=-1)
         return (acc, m_new, l), None
 
-    acc0 = jnp.zeros((B, H, T, D), jnp.float32)
     m0 = jnp.full((B, H, T), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, H, T), jnp.float32)
     (acc, m, l), _ = jax.lax.scan(
         body, (acc0, m0, l0), jnp.arange(M, dtype=jnp.int32))
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)
+    l = jnp.maximum(l, 1e-30)
+    if r:  # [B, G, T, lanes] -> [B, T, H, D]
+        out = (acc / to_lanes(l)).transpose(0, 2, 1, 3).reshape(B, T, H, D)
+    else:
+        out = (acc / l[..., None]).transpose(0, 2, 1, 3)
+    return out.astype(q.dtype)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, q_positions,

@@ -218,12 +218,17 @@ class GPTForCausalLM(nn.Layer):
         cached forward and the cache it runs over (here K and V pages of as
         many heads as the queries have, no recurrent state)."""
         c = self.config
+        # float pages lie a layer to an array with the heads flat, so a
+        # step touches the layer it is in and never the whole pool; int8
+        # pages carry a scale a token-head and keep the stacked arrays
+        # (one layout for all is ROADMAP D10, a simplicity issue's)
+        layout = "stacked" if kv_dtype == "int8" else "per_layer"
         return {"forward_chunk": gpt_decode_fns(c, kv_dtype),
                 "num_layers": c.num_layers, "num_heads": c.num_heads,
                 "num_kv_heads": c.num_heads,
                 "head_dim": c.hidden_size // c.num_heads,
                 "max_positions": c.max_position_embeddings,
-                "kv_layout": "stacked", "state": None}
+                "kv_layout": layout, "state": None}
 
     def loss_fn(self, logits, labels):
         with jax.named_scope("head_loss"):
@@ -316,13 +321,19 @@ def gpt_decode_fns(config: GPTConfig, kv_dtype: str = "float32"):
     Returns ``forward_chunk(params, tokens, q_positions, pages,
     block_tables, kv_lens, slots=None) -> (logits [B, T, V], pages)`` where
     ``params`` is the flat ``jit.functionalize.get_params`` dict of a
-    ``GPTForCausalLM`` and ``pages`` is a ``KVCachePool.pages`` pytree
-    (paged layout + scratch-page convention documented in
-    inference/serving/kv_cache.py). Each layer writes the chunk's K/V
-    into its pages (int8 pools quantize on write via
-    ``quant.quantize_kv``), then attends through
-    ``ops.attention.paged_attention``, whose tier
-    ``ops.tier_policy.select_paged`` measures and selects.
+    ``GPTForCausalLM`` and ``pages`` is a ``KVCachePool.pages`` pytree in
+    the layout ``GPTForCausalLM.decode_spec`` names for ``kv_dtype``
+    (layouts + scratch-page convention documented in
+    inference/serving/kv_cache.py). Float pages are ``per_layer``: K and V
+    leave the qkv split ``hidden_size`` wide and are stored that way, a
+    layer's scatter lands in that layer's own ``[blocks, block, heads *
+    head_dim]`` array and ``ops.attention.paged_attention`` reads it as it
+    lies, so no step slices, copies or relays out the pool (the stacked
+    five-axis array cost 61 ms of every step of GPT-2 345M over 2,304
+    blocks: PERF.md, section 6, PR 36). int8 pages quantize on write via
+    ``quant.quantize_kv`` to a scale a token-head and stay ``stacked``,
+    heads as an axis beside their scales. The tier of ``paged_attention``
+    is ``ops.tier_policy.select_paged``'s.
 
     Numerics match the eval-mode Layer forward (dropout-free, gelu
     approximate, tied lm_head) up to the attention tier's accumulation
@@ -348,7 +359,9 @@ def gpt_decode_fns(config: GPTConfig, kv_dtype: str = "float32"):
     def forward_chunk(params, tokens, q_positions, pages, block_tables,
                       kv_lens, slots=None):  # no recurrent state: no slots
         B, T = tokens.shape
-        bs = pages["k"].shape[2]
+        if not quantized:  # a layer's array is replaced, not the tuple
+            pages = {name: list(leaves) for name, leaves in pages.items()}
+        bs = pages["k"][0].shape[1]  # a layer's [blocks, block, ...]
         # scatter targets: token t of row b lands in table slot
         # pos // bs at offset pos % bs; masked-out tokens (padded rows,
         # padded chunk tails — q_position >= kv_len) are redirected to
@@ -371,9 +384,9 @@ def gpt_decode_fns(config: GPTConfig, kv_dtype: str = "float32"):
             qkv = h @ p["attn.qkv.weight"] + p["attn.qkv.bias"]
             q3, k3, v3 = jnp.split(qkv, 3, axis=-1)
             q3 = q3.reshape(B, T, nh, hd)
-            k3 = k3.reshape(B, T, nh, hd)
-            v3 = v3.reshape(B, T, nh, hd)
             if quantized:
+                k3 = k3.reshape(B, T, nh, hd)
+                v3 = v3.reshape(B, T, nh, hd)
                 kq, ks = quantize_kv(k3)
                 vq, vs = quantize_kv(v3)
                 pages["k"] = pages["k"].at[i, page_idx, slot].set(kq)
@@ -384,9 +397,9 @@ def gpt_decode_fns(config: GPTConfig, kv_dtype: str = "float32"):
                     pages["v_scale"].at[i, page_idx, slot].set(vs)
                 k_sc, v_sc = pages["k_scale"][i], pages["v_scale"][i]
             else:
-                pages["k"] = pages["k"].at[i, page_idx, slot].set(
+                pages["k"][i] = pages["k"][i].at[page_idx, slot].set(
                     k3.astype(store))
-                pages["v"] = pages["v"].at[i, page_idx, slot].set(
+                pages["v"][i] = pages["v"][i].at[page_idx, slot].set(
                     v3.astype(store))
                 k_sc = v_sc = None
             o = paged_attention(q3, pages["k"][i], pages["v"][i],
@@ -400,6 +413,8 @@ def gpt_decode_fns(config: GPTConfig, kv_dtype: str = "float32"):
             x = x + h2 @ p["mlp.proj.weight"] + p["mlp.proj.bias"]
         x = ln(x, params["gpt.ln_f.weight"], params["gpt.ln_f.bias"])
         logits = x @ params["gpt.wte.weight"].T
+        if not quantized:
+            pages = {name: tuple(leaves) for name, leaves in pages.items()}
         return logits, pages
 
     return forward_chunk

@@ -23,6 +23,7 @@ from paddle_tpu.inference.serving import (GenRequest, KVCacheConfig,
                                           TokenServingEngine,
                                           dense_greedy_reference,
                                           run_generation_streams)
+from paddle_tpu.inference.serving.decode import _pool_config
 from paddle_tpu.inference.serving.loadgen import summarize_generation
 from paddle_tpu.jit.functionalize import get_params
 from paddle_tpu.ops import attention as att
@@ -62,6 +63,12 @@ def tiny_draft(seed=3):
     m = GPTForCausalLM(cfg)
     m.eval()
     return m
+
+
+def gpt_pool(model, kv_dtype="float32", blocks=16, block=8):
+    """A pool in the layout the model names for this storage type."""
+    return KVCachePool(_pool_config(model.decode_spec(kv_dtype), blocks,
+                                    block, kv_dtype, 0))
 
 
 def make_engine(model=None, draft=None, **kw):
@@ -302,6 +309,38 @@ class TestPagedAttention:
         o2 = np.asarray(att._paged_scan_impl(*args))
         np.testing.assert_allclose(o1, o2, atol=1e-5)
 
+    @pytest.mark.parametrize("T", [1, 3])
+    @pytest.mark.parametrize("H, D, group", [(4, 64, 2), (8, 32, 4),
+                                             (3, 64, 0), (2, 128, 0)])
+    def test_scan_over_flat_pages_a_lane_group_at_a_time(self, H, D, group,
+                                                         T):
+        """A decode step over flat float pages of heads narrower than 128
+        lanes reads them a lane group at a time (GPT's 16 heads of 64: two
+        a group), never reshaped to heads of 64; a chunk of several queries
+        keeps the heads as an axis. Either way the result is the scan's
+        over the same pages with the heads as an axis, and the gather
+        tier's."""
+        rng = np.random.RandomState(3)
+        B, bs, M, N = 3, 4, 5, 16
+        k = jnp.asarray(rng.randn(N, bs, H * D).astype(np.float32))
+        v = jnp.asarray(rng.randn(N, bs, H * D).astype(np.float32))
+        assert att._lane_group(T, H, D, k, None) == (group if T == 1 else 0)
+        assert att._lane_group(T, H, D, k.reshape(N, bs, H, D), None) == 0
+        q = jnp.asarray(rng.randn(B, T, H, D).astype(np.float32))
+        tables = jnp.asarray(rng.permutation(np.arange(1, N))[:B * M]
+                             .reshape(B, M).astype(np.int32))
+        kv_lens = jnp.asarray(np.array([7, 20, 13], np.int32))
+        q_pos = kv_lens[:, None] - T + jnp.arange(T, dtype=jnp.int32)[None]
+        flat = np.asarray(att._paged_scan_impl(q, k, v, tables, q_pos,
+                                               kv_lens))
+        by_heads = np.asarray(att._paged_scan_impl(
+            q, k.reshape(N, bs, H, D), v.reshape(N, bs, H, D), tables,
+            q_pos, kv_lens))
+        gathered = np.asarray(att._paged_gather_impl(q, k, v, tables, q_pos,
+                                                     kv_lens))
+        np.testing.assert_allclose(flat, by_heads, atol=2e-6)
+        np.testing.assert_allclose(flat, gathered, atol=2e-6)
+
     def test_vs_dense_reference(self):
         import math
         q, k, v, tables, q_pos, kv_lens, _, _ = self.setup_pages()
@@ -397,12 +436,8 @@ class TestPagedTierPolicy:
 # ---------------------------------------------------------------------------
 class TestGPTDecodeFns:
     def run_paged_prefill(self, model, prompt, kv_dtype="float32", C=8):
-        mcfg = model.config
-        fwd = gpt_decode_fns(mcfg, kv_dtype)
-        pool = KVCachePool(KVCacheConfig(
-            mcfg.num_layers, mcfg.num_heads,
-            mcfg.hidden_size // mcfg.num_heads, num_blocks=16, block_size=8,
-            dtype=kv_dtype))
+        fwd = gpt_decode_fns(model.config, kv_dtype)
+        pool = gpt_pool(model, kv_dtype)
         n = len(prompt)
         pool.ensure(1, n)
         table = jnp.asarray(pool.block_table(1, 8)[None])
@@ -432,6 +467,45 @@ class TestGPTDecodeFns:
         np.testing.assert_allclose(paged, ref, atol=1e-4)
         assert np.array_equal(paged.argmax(-1), ref.argmax(-1))
 
+    @pytest.mark.parametrize("kv_dtype, layout", [
+        ("float32", "per_layer"), ("bfloat16", "per_layer"),
+        ("int8", "stacked")])
+    def test_decode_spec_names_the_layout_by_storage_type(self, kv_dtype,
+                                                          layout):
+        """Float pages lie a layer to an array; int8 pages keep a scale a
+        token-head and stay stacked (which ``KVCacheConfig`` insists on)."""
+        model = tiny_model()
+        assert model.decode_spec(kv_dtype)["kv_layout"] == layout
+        assert gpt_pool(model, kv_dtype).config.layout == layout
+
+    @pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16"])
+    def test_float_pages_are_a_layers_array_with_the_heads_flat(self,
+                                                                kv_dtype):
+        model = tiny_model()
+        c = model.config
+        pool = gpt_pool(model, kv_dtype)
+        assert set(pool.pages) == {"k", "v"}
+        for kv in ("k", "v"):
+            assert isinstance(pool.pages[kv], tuple)
+            assert len(pool.pages[kv]) == c.num_layers
+            for layer in pool.pages[kv]:
+                assert layer.shape == (16, 8, c.hidden_size)
+                assert layer.dtype == jnp.dtype(kv_dtype)
+
+    def test_a_step_returns_the_pages_in_the_layout_it_got(self):
+        """The engine hands a step's pages to the next step: a tuple of
+        arrays a layer goes in and comes out, each as wide as it was."""
+        model = tiny_model()
+        pool = gpt_pool(model)
+        zeros = jnp.zeros((1, 8), jnp.int32)  # tokens, positions, table
+        _, pages = jax.jit(gpt_decode_fns(model.config, "float32"))(
+            get_params(model), zeros, zeros, pool.pages, zeros,
+            jnp.zeros((1,), jnp.int32))
+        assert jax.tree_util.tree_structure(pages) \
+            == jax.tree_util.tree_structure(pool.pages)
+        assert [a.shape for a in jax.tree_util.tree_leaves(pages)] \
+            == [a.shape for a in jax.tree_util.tree_leaves(pool.pages)]
+
     def test_int8_kv_close_to_bf16_reference(self):
         """ISSUE satellite: int8 KV storage parity against a wider
         reference — logits must stay close enough that greedy decisions
@@ -450,7 +524,9 @@ class TestGPTDecodeFns:
 # Engine: continuous batching, parity, chunked prefill, eviction, spec
 # ---------------------------------------------------------------------------
 class TestTokenEngine:
-    def test_greedy_parity_with_dense_reference(self):
+    @pytest.mark.parametrize("tier", ["paged_gather", "paged_scan"])
+    def test_greedy_parity_with_dense_reference(self, monkeypatch, tier):
+        monkeypatch.setenv("PADDLE_TPU_ATTN_PAGED_POLICY", tier)
         eng, model = make_engine()
         eng.start()
         try:
@@ -464,6 +540,10 @@ class TestTokenEngine:
                 assert r.status == RequestStatus.OK
                 assert [int(t) for t in r.outputs[0]] \
                     == dense_greedy_reference(model, p, 10)
+            gauges = get_telemetry().snapshot()["gauges"]
+            assert {v for k, v in gauges.items()
+                    if k.startswith("attn/tier.paged")} \
+                == {tier_policy.TIER_IDS[tier]}
         finally:
             acct = eng.shutdown()
         assert acct["unaccounted"] == [] and acct["double_terminal"] == 0
