@@ -40,7 +40,7 @@ from ..core.tensor import Tensor, _is_tracer, apply_op, wrap_raw
 from ..nn import initializer as I
 
 __all__ = ["top_k_gating", "moe_dispatch", "ExpertMLP", "MoELayer",
-           "DroplessMoE", "route_top_k", "held_experts_part",
+           "DroplessMoE", "route_top_k", "held_experts_part", "held_load",
            "publish_moe_stats"]
 
 
@@ -307,6 +307,18 @@ def held_experts_part(x, chosen, weights, w_gate, w_up, w_down, first: int):
         jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0),
         (jnp.sum(here) - pairs).astype(jnp.float32)])
     return y, jax.lax.stop_gradient(stats)
+
+
+def held_load(chosen, first: int, held: int):
+    """int32[2] from the routing ``chosen`` [T, k]: how many of the
+    experts [first, first + held) received at least one pair, and how many
+    pairs were routed to them. What a served step counts (a held expert
+    without a pair has no weights to read); pairs of ids outside all
+    experts (a position that is not there: -1) count nowhere."""
+    local = chosen.astype(jnp.int32).reshape(-1) - first
+    mine = local[None, :] == jnp.arange(held, dtype=jnp.int32)[:, None]
+    return jnp.stack([jnp.sum(jnp.any(mine, axis=1)),
+                      jnp.sum(mine)]).astype(jnp.int32)
 
 
 class DroplessMoE(nn.Layer):

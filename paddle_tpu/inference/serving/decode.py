@@ -22,6 +22,10 @@ prefix every token. This module serves generation natively:
   ONE batched (k+1)-token step; the accepted prefix plus the target's
   correction advance the sequence 1..k+1 tokens per round.
   ``gauge/serve/spec_accept_rate`` tracks the cumulative acceptance.
+  A model whose ``decode_spec()`` offers a draft of its own (a next-token
+  prediction module fed by the target's hidden state) needs no draft
+  model: ``spec_k`` alone turns it on (``_self_spec_round``), its latent
+  rows lie in the target's pool under the target's blocks.
 - **PR 7 lifecycle unchanged**: admission queue, deadline enforcement
   (queue / mid-generation), drain semantics, the exactly-one-terminal
   accounting ledger, and the SIGTERM → drain → exit-77 relaunch path are
@@ -93,7 +97,8 @@ class TokenServeConfig(ServeConfig):
             defaults to the model's position table, clamped to what the
             pool can hold for one sequence.
         spec_k: speculative tokens proposed per round (0 = off; needs a
-            draft model on the engine).
+            draft model on the engine, or a model whose ``decode_spec()``
+            offers a draft of its own).
     """
 
     def __init__(self, capacity: int = 64,
@@ -154,6 +159,9 @@ class GenRequest(Request):
         self.ncache = 0          # tokens whose K/V are in the target cache
         self.draft_ncache = 0    # ditto, draft cache (speculative mode)
         self.evictions = 0
+        # the model's own draft of the token after the pending one
+        # (self-drafting); None until a prefill has made one
+        self.proposal: Optional[int] = None
         self.first_token_at: Optional[float] = None
         self.last_token_at: Optional[float] = None
 
@@ -238,7 +246,7 @@ class DecodeScheduler:
         return self._thread.is_alive()
 
     # -- compiled executables ----------------------------------------------
-    def _make_step(self, fwd, name: str):
+    def _make_step(self, fwd, name: str, takes_hidden: bool = False):
         """One compiled entry: forward a chunk through the cache, return
         the greedy token per position (argmax stays on device — the D2H
         per step is [B, T] int32, not [B, T, V] logits). The cache (arg
@@ -248,19 +256,26 @@ class DecodeScheduler:
         each row's state slot (0, the scratch slot, for padded rows and
         for models without state). The jitted function is named after
         the entry, so that a device trace tells a decode step from a
-        prefill chunk by its module's name."""
+        prefill chunk by its module's name. An entry of the model's own
+        draft (``takes_hidden``) takes the target's hidden states, a
+        device array that never visits the host, before the tokens; a
+        forward that feeds such a draft returns them after the cache."""
+        n = int(takes_hidden)
 
-        def step(params, tokens, qpos, cache, tables, kv_lens, slots):
-            logits, cache = fwd(params, tokens, qpos, cache, tables,
-                                kv_lens, slots)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+        def step(params, *args):
+            # args: [hidden,] tokens, qpos, cache, tables, kv_lens, slots;
+            # out: logits, cache[, hidden]
+            logits, *rest = fwd(params, *args)
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), *rest)
 
         step.__name__ = step.__qualname__ = name.replace(".", "_")
-        # sig_argnums: hash only the drift-capable inputs — flattening
-        # the full params pytree per decode step would put O(leaves)
-        # host work on the token hot path
-        return tracked_jit(step, name=name, sig_argnums=(1, 2, 4, 5, 6),
-                           donate_argnums=(3,))
+        # sig_argnums: hash only the drift-capable inputs (all but the
+        # params and the cache) — flattening the full params pytree per
+        # decode step would put O(leaves) host work on the token hot path
+        cache_at = 3 + n
+        return tracked_jit(
+            step, name=name, donate_argnums=(cache_at,),
+            sig_argnums=tuple(i for i in range(1, 7 + n) if i != cache_at))
 
     def _decode_fn(self, bucket: int):
         fn = self._decode_fns.get(bucket)
@@ -272,7 +287,10 @@ class DecodeScheduler:
     def _verify_fn(self, bucket: int):
         fn = self._verify_fns.get(bucket)
         if fn is None:
-            fn = self._make_step(self._engine._fwd, f"serve.verify.b{bucket}")
+            eng = self._engine
+            fn = self._make_step(
+                eng._fwd_hidden if eng.self_draft else eng._fwd,
+                f"serve.verify.b{bucket}")
             self._verify_fns[bucket] = fn
         return fn
 
@@ -280,21 +298,24 @@ class DecodeScheduler:
         fn = self._draft_fns.get(bucket)
         if fn is None:
             fn = self._make_step(self._engine._draft_fwd,
-                                 f"serve.draft.b{bucket}")
+                                 f"serve.draft.b{bucket}",
+                                 takes_hidden=self._engine.self_draft)
             self._draft_fns[bucket] = fn
         return fn
 
     def _get_prefill_fn(self, draft: bool = False):
+        eng = self._engine
         if draft:
             if self._draft_prefill_fn is None:
                 self._draft_prefill_fn = self._make_step(
-                    self._engine._draft_fwd,
-                    f"serve.draft_prefill.c{self._engine.config.prefill_chunk}")
+                    eng._draft_fwd,
+                    f"serve.draft_prefill.c{eng.config.prefill_chunk}",
+                    takes_hidden=eng.self_draft)
             return self._draft_prefill_fn
         if self._prefill_fn is None:
             self._prefill_fn = self._make_step(
-                self._engine._fwd,
-                f"serve.prefill.c{self._engine.config.prefill_chunk}")
+                eng._fwd_hidden if eng.self_draft else eng._fwd,
+                f"serve.prefill.c{eng.config.prefill_chunk}")
         return self._prefill_fn
 
     def warmup(self) -> Dict[str, float]:
@@ -306,23 +327,35 @@ class DecodeScheduler:
         cfg = eng.config
         out: Dict[str, float] = {}
 
-        def run(label, fn, pool, B, T, fwd_params):
+        def run(label, fn, pool, B, T, fwd_params, hidden=None):
             toks = jnp.zeros((B, T), jnp.int32)
             qpos = jnp.zeros((B, T), jnp.int32)
             tables = jnp.zeros((B, eng._table_width), jnp.int32)
             lens = jnp.zeros((B,), jnp.int32)
+            before = () if hidden is None else (hidden,)
             t0 = time.perf_counter()
-            g, pages = fn(fwd_params, toks, qpos, pool.pages, tables, lens,
-                          jnp.zeros((B,), jnp.int32))
-            np.asarray(g)  # block: measure compile+run
-            pool.pages = pages
+            got = fn(fwd_params, *before, toks, qpos, pool.pages, tables,
+                     lens, jnp.zeros((B,), jnp.int32))
+            np.asarray(got[0])  # block: measure compile+run
+            pool.pages = got[1]
             out[label] = (time.perf_counter() - t0) * 1e3
+            return got
 
         for b in cfg.decode_buckets:
             run(f"decode.b{b}", self._decode_fn(b), eng._pool, b, 1,
                 eng._params)
-        run(f"prefill.c{cfg.prefill_chunk}", self._get_prefill_fn(),
-            eng._pool, 1, cfg.prefill_chunk, eng._params)
+        got = run(f"prefill.c{cfg.prefill_chunk}", self._get_prefill_fn(),
+                  eng._pool, 1, cfg.prefill_chunk, eng._params)
+        if eng.self_draft:
+            # the draft's entries take the hidden states the target's give
+            run(f"draft_prefill.c{cfg.prefill_chunk}",
+                self._get_prefill_fn(draft=True), eng._pool, 1,
+                cfg.prefill_chunk, eng._params, hidden=got[2])
+            for b in cfg.decode_buckets:
+                got = run(f"verify.b{b}", self._verify_fn(b), eng._pool, b,
+                          cfg.spec_k + 1, eng._params)
+                run(f"draft.b{b}", self._draft_fn(b), eng._pool, b,
+                    cfg.spec_k + 1, eng._params, hidden=got[2])
         if eng.spec_enabled:
             for b in cfg.decode_buckets:
                 run(f"verify.b{b}", self._verify_fn(b), eng._pool, b,
@@ -408,7 +441,9 @@ class DecodeScheduler:
                 if prefilling:
                     self._prefill_chunk(prefilling[0])
                 if decoding:
-                    if eng.spec_enabled:
+                    if eng.self_draft:
+                        self._self_spec_round(decoding)
+                    elif eng.spec_enabled:
                         self._spec_round(decoding)
                     else:
                         self._decode_round(decoding)
@@ -482,6 +517,7 @@ class DecodeScheduler:
         eng = self._engine
         eng._pool.release(victim.id)
         victim.ncache = 0
+        victim.proposal = None  # its draft rows went with its blocks
         if eng.spec_enabled:
             eng._draft_pool.release(victim.id)
             victim.draft_ncache = 0
@@ -556,7 +592,7 @@ class DecodeScheduler:
         # of its own (a device run the trace would book to this round)
         toks, qpos, table, lens, slot = jax.device_put(
             (toks, qpos, table, lens, slot))
-        g, pages = self._get_prefill_fn()(
+        g, pages, *hidden = self._get_prefill_fn()(
             eng._params, toks, qpos, eng._pool.pages, table, lens, slot)
         eng._pool.pages = pages
         g_np = np.asarray(g)
@@ -587,6 +623,24 @@ class DecodeScheduler:
             # greedy output IS the first generated token (TTFT stamps
             # here)
             self._append_token(r, int(g_np[0, real - 1]))
+        if eng.self_draft:
+            # the model's own draft follows the chunk: position i takes
+            # the target's hidden state of i and the token at i + 1 (the
+            # one just emitted, at the prompt's end), and its guess after
+            # the last known token is the first proposal
+            start = r.ncache - real
+            nxt = np.zeros((1, C), np.int32)
+            nxt[0, :real] = r.toks[start + 1:start + real + 1]
+            t0 = time.perf_counter()
+            dg, pages = self._get_prefill_fn(draft=True)(
+                eng._params, hidden[0], jax.device_put(nxt), qpos,
+                eng._pool.pages, table, lens, slot)
+            eng._pool.pages = pages
+            if r.pending == 1:
+                r.proposal = int(np.asarray(dg)[0, real - 1])
+            if tel.enabled:
+                tel.observe(f"serve/draft_prefill_ms.c{C}",
+                            (time.perf_counter() - t0) * 1e3)
 
     # -- plain decode ------------------------------------------------------
     def _decode_round(self, decoding: List[GenRequest],
@@ -627,7 +681,89 @@ class DecodeScheduler:
         for i, r in enumerate(group):
             r.trace_event(f"decode.b{bucket}", dur_s=ms / 1e3)
             r.ncache += 1
+            r.proposal = None  # a draft of the model's own has not followed
             self._append_token(r, int(g_np[i, 0]))
+
+    # -- speculative decode from the model's own draft ----------------------
+    def _self_spec_round(self, decoding: List[GenRequest]) -> None:
+        """One round of drafting from the served model's own next-token
+        module (``spec_k`` 1): the target verifies the pending token and
+        the standing proposal in one 2-token step and hands its hidden
+        states, which stay on the device, to the draft entry; that takes
+        (hidden state of position i, token at i + 1), writes its one
+        layer's latent rows under the same blocks and proposes for the
+        next round. Acceptance, rollback (a rejected position's rows are
+        masked by the cache cursor and overwritten when the position is
+        fed again) and the counters are ``_spec_round``'s."""
+        eng = self._engine
+        cfg = eng.config
+        tel = get_telemetry()
+        group, tail = [], []
+        for r in decoding:
+            if r.pending != 1:
+                continue
+            if len(group) >= cfg.max_running:
+                break
+            # no room for the k-ahead write, or no standing proposal (a
+            # sequence that fell back to plain decode stays there)
+            if r.ncache + 2 > eng.max_seq_len or r.proposal is None:
+                tail.append(r)
+                continue
+            if self._ensure_blocks(r, r.ncache + 2, exclude=group):
+                group.append(r)
+        if tail:
+            self._decode_round(tail, protect=group)
+        if not group:
+            return
+        bucket = cfg.bucket_for(len(group))
+        arrays = self._batch_arrays(
+            group, bucket, 2, [[r.toks[-1], r.proposal] for r in group])
+        t0 = time.perf_counter()
+        g, pages, hidden = self._verify_fn(bucket)(
+            eng._params, arrays[0], arrays[1], eng._pool.pages, *arrays[2:])
+        eng._pool.pages = pages
+        g_np = np.asarray(g)
+        ms = (time.perf_counter() - t0) * 1e3
+        if tel.enabled:
+            tel.counter("serve/decode_steps")
+            tel.observe("serve/verify_ms", ms)
+            tel.observe(f"serve/verify_ms.b{bucket}", ms)
+            tel.observe("serve/batch_occupancy", len(group) / bucket)
+        nxt = np.zeros((bucket, 2), np.int32)
+        lens = np.zeros((bucket,), np.int32)
+        accepted = []
+        for i, r in enumerate(group):
+            r.trace_event(f"decode.spec.b{bucket}", dur_s=ms / 1e3)
+            a = int(r.proposal == int(g_np[i, 0]))
+            for t in g_np[i, :1 + a]:
+                if not self._append_token(r, int(t)):
+                    break
+            # the draft sees what the target has confirmed: position
+            # ncache with the token that followed it and, where the
+            # proposal was accepted, the next with the target's own
+            nxt[i] = g_np[i]
+            lens[i] = r.ncache + 1 + a
+            r.ncache = min(r.ncache + 1 + a, len(r.toks) - 1)
+            accepted.append(a)
+        t0 = time.perf_counter()
+        nxt, lens = jax.device_put((nxt, lens))
+        dg, pages = self._draft_fn(bucket)(
+            eng._params, hidden, nxt, arrays[1], eng._pool.pages, arrays[2],
+            lens, arrays[4])
+        eng._pool.pages = pages
+        dg_np = np.asarray(dg)
+        for i, (r, a) in enumerate(zip(group, accepted)):
+            r.proposal = int(dg_np[i, a])
+        self._spec_proposed += len(group)
+        self._spec_accepted += sum(accepted)
+        if tel.enabled:
+            ms = (time.perf_counter() - t0) * 1e3
+            tel.observe("serve/draft_ms", ms)
+            tel.observe(f"serve/draft_ms.b{bucket}", ms)
+            tel.counter("serve/spec_proposed", len(group))
+            tel.counter("serve/spec_accepted", sum(accepted))
+            tel.gauge("serve/spec_accept_rate",
+                      self._spec_accepted / max(self._spec_proposed, 1))
 
     # -- speculative decode ------------------------------------------------
     def _spec_round(self, decoding: List[GenRequest]) -> None:
@@ -851,7 +987,8 @@ def _pool_config(spec: dict, num_blocks: int, block_size: int, kv_dtype: str,
         spec["num_layers"], spec["num_heads"], spec["head_dim"],
         num_blocks=num_blocks, block_size=block_size, dtype=kv_dtype,
         num_kv_heads=spec["num_kv_heads"], layout=spec["kv_layout"],
-        state=spec["state"], state_slots=state_slots)
+        state=spec["state"], state_slots=state_slots,
+        counters=spec.get("counters"))
 
 
 class TokenServingEngine(ServingEngine):
@@ -901,10 +1038,24 @@ class TokenServingEngine(ServingEngine):
         self.max_seq_len = max_seq
         self._pool = KVCachePool(pool_cfg)
         self._table_width = pool_cfg.blocks_for(max_seq)
+        self._publish_counters = spec.get("publish_counters")
         self.spec_enabled = draft_model is not None and cfg.spec_k > 0
-        if cfg.spec_k > 0 and draft_model is None:
-            raise ValueError("spec_k > 0 needs a draft_model")
-        if self.spec_enabled:
+        own = spec.get("draft")
+        # the model's own draft: no second model, no second pool
+        self.self_draft = (cfg.spec_k > 0 and draft_model is None
+                           and own is not None)
+        if cfg.spec_k > 0 and draft_model is None and own is None:
+            raise ValueError("spec_k > 0 needs a draft_model, or a model "
+                             "whose decode_spec() offers a draft of its own")
+        if self.self_draft:
+            if cfg.spec_k > own["max_k"]:
+                raise ValueError(
+                    f"the model's own draft proposes {own['max_k']} token(s) "
+                    f"a round; spec_k is {cfg.spec_k}")
+            self._fwd_hidden = own["forward_hidden"]
+            self._draft_fwd = own["forward_draft"]
+            self._draft_params = self._draft_pool = None
+        elif self.spec_enabled:
             dspec = draft_model.decode_spec(cfg.kv_dtype)
             if dspec["state"]:
                 raise ValueError("a draft model that holds recurrent state "
@@ -974,11 +1125,21 @@ class TokenServingEngine(ServingEngine):
         engine for its ledger (``ops_server.set_serving_engine``), and
         with it would keep the weights and the whole pool resident."""
         acct = super().shutdown()
+        self.publish_counters()
         self._params = self._draft_params = None
         for pool in (self._pool, self._draft_pool):
             if pool is not None:
                 pool.pages = None
         return acct
+
+    def publish_counters(self) -> dict:
+        """What the compiled steps have counted in the cache pytree (a
+        served expert layer's experts hit and pairs routed), fetched now
+        and published as the model's ``decode_spec()`` says. The caller's
+        fetch: no step makes one. Also made at shutdown."""
+        if self._publish_counters is None or not self._pool.pages:
+            return {}
+        return self._publish_counters(self._pool.read_counters())
 
     def kv_accounting(self) -> dict:
         out = self._pool.accounting()

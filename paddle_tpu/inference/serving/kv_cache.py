@@ -57,6 +57,18 @@ lanes) and writes a layer's slice out again around each scatter: 61 ms of
 every step of GPT-2 345M over 2,304 blocks (PERF.md, section 6, PR 36); no
 cell serves int8, and one layout for both is ROADMAP D10. State leaves are
 always a tuple of one array a layer.
+``"latent"`` is latent attention's: no K and V pages at all but one leaf,
+``pages['latent']``, a tuple of one array a layer ``[blocks, block,
+head_dim]`` with ``head_dim`` the cached row's width (the compressed
+latent and the rotated shared key side by side, 512 + 64, which the model
+pads to whole 128-lane groups: 640), one row a token whatever the heads. It is handed out, released and evicted by the same
+blocks as pages of K and V are: the host side does not know the layouts
+apart.
+
+**Counters** (``counters=``): small arrays a compiled step adds to and
+nothing on the host reads a step (a served expert layer's experts hit and
+pairs routed): ``pages[<name>]`` one array each, zero at the start. They
+ride in the donated pytree; ``read_counters`` fetches them when asked.
 """
 from __future__ import annotations
 
@@ -74,7 +86,7 @@ __all__ = ["KVCacheConfig", "KVCachePool", "SCRATCH_PAGE"]
 SCRATCH_PAGE = 0
 
 _STORE_DTYPES = ("float32", "bfloat16", "int8")
-_LAYOUTS = ("stacked", "per_layer")
+_LAYOUTS = ("stacked", "per_layer", "latent")
 
 
 class KVCacheConfig:
@@ -93,9 +105,11 @@ class KVCacheConfig:
             dot (defaults to float32 off-int8 storage dtype).
         num_kv_heads: heads of K and V where they are fewer than the
             query heads (grouped queries); the pages hold these.
-        layout: 'stacked' | 'per_layer' (module docstring).
+        layout: 'stacked' | 'per_layer' | 'latent' (module docstring).
         state: recurrent-state leaves, ``{name: (shape a slot and layer,
             dtype)}``, or None; ``state_slots`` sequences can hold one.
+        counters: ``{name: (shape, dtype)}`` of arrays the compiled steps
+            add to, or None.
     """
 
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
@@ -105,15 +119,16 @@ class KVCacheConfig:
                  num_kv_heads: Optional[int] = None,
                  layout: str = "stacked",
                  state: Optional[Dict[str, tuple]] = None,
-                 state_slots: int = 0):
+                 state_slots: int = 0,
+                 counters: Optional[Dict[str, tuple]] = None):
         if dtype not in _STORE_DTYPES:
             raise ValueError(f"kv dtype {dtype!r} not in {_STORE_DTYPES}")
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (page 0 is scratch)")
         if layout not in _LAYOUTS:
             raise ValueError(f"kv layout {layout!r} not in {_LAYOUTS}")
-        if layout == "per_layer" and dtype == "int8":
-            raise ValueError("the per_layer layout flattens the heads; int8 "
+        if layout != "stacked" and dtype == "int8":
+            raise ValueError(f"the {layout} layout flattens the heads; int8 "
                              "pages keep a scale a head and stay 'stacked'")
         if state and state_slots < 1:
             raise ValueError("a recurrent-state pool needs state_slots >= 1")
@@ -123,6 +138,7 @@ class KVCacheConfig:
         self.layout = layout
         self.state = dict(state or {})
         self.state_slots = int(state_slots) if self.state else 0
+        self.counters = dict(counters or {})
         self.head_dim = int(head_dim)
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
@@ -148,11 +164,12 @@ class KVCachePool:
         self.config = config
         c = config
         store = jnp.int8 if c.dtype == "int8" else jnp.dtype(c.dtype)
-        if c.layout == "per_layer":
+        if c.layout in ("per_layer", "latent"):
             shape = (c.num_blocks, c.block_size, c.num_kv_heads * c.head_dim)
+            leaves = ("latent",) if c.layout == "latent" else ("k", "v")
             self.pages: Dict[str, object] = {
                 kv: tuple(jnp.zeros(shape, store)
-                          for _ in range(c.num_layers)) for kv in ("k", "v")}
+                          for _ in range(c.num_layers)) for kv in leaves}
         else:
             shape = (c.num_layers, c.num_blocks, c.block_size,
                      c.num_kv_heads, c.head_dim)
@@ -168,6 +185,10 @@ class KVCachePool:
             self.pages[name] = tuple(
                 jnp.zeros((c.state_slots + 1, *slot_shape), jnp.dtype(dtype))
                 for _ in range(c.num_layers))
+        for name, (shape_, dtype) in c.counters.items():
+            if name in self.pages:
+                raise ValueError(f"counter {name!r} is another leaf's name")
+            self.pages[name] = jnp.zeros(shape_, jnp.dtype(dtype))
         self._lock = threading.Lock()
         self._free: List[int] = list(range(1, c.num_blocks))
         self._owned: Dict[int, List[int]] = {}  # request id -> block ids
@@ -286,6 +307,15 @@ class KVCachePool:
                            used_slots=held, leaked_slots=held,
                            slot_owners=sorted(self._slot_of))
             return out
+
+    def read_counters(self) -> dict:
+        """The counters' values now, as numpy arrays: the caller's fetch
+        (it waits for the last step dispatched). Empty once the pages are
+        gone."""
+        if not self.pages:
+            return {}
+        return {name: np.asarray(self.pages[name])
+                for name in self.config.counters}
 
     # -- device-facing helpers ---------------------------------------------
     def block_table(self, owner: int, width: int) -> np.ndarray:

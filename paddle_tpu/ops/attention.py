@@ -590,6 +590,156 @@ def paged_attention(q, k_pages, v_pages, block_tables, q_positions,
 
 
 # ---------------------------------------------------------------------------
+# Latent (MLA) attention over a paged latent cache
+# ---------------------------------------------------------------------------
+# A cached token is one row a layer, [c | rotated k_r] (512 + 64 columns,
+# stored with zeros up to whole lanes: 640): one key shared by all the
+# heads, whose first columns are also the value once W_kvb is folded into
+# the query and the output. Two forms of one
+# walk over the row's table, chosen by ``_mla_form`` from the queries a row:
+# - 'mla_absorbed' (a decode or verify step): q_n W_k is made once a query
+#   and scored against the latent rows as they lie; the output leaves the
+#   latent space through W_v. 2 (576 + 512) operations a head, query and
+#   cached row, and no key or value a head is ever made.
+# - 'mla_expanded' (a prefill chunk): a group of cached rows is taken up
+#   through W_kvb to keys and values a head (2 * 512 * 256 operations a
+#   head and cached row, whatever the queries) and scored as plain
+#   attention, 2 (192 + 128) a head, query and row.
+# By operations alone the forms cross at 262,144 / (2,176 - 640) = 171
+# queries a row. Read on a v5e at the served widths (128 heads, rows stored
+# 640 wide, a table of 320 slots; my chip runs, PR 37, a probe that called
+# this function alone, not kept, medians of 8 calls): a decode step of 64
+# rows, one query each, 1.10 / 1.66 / 2.76 ms absorbed at 512 / 2,048 /
+# 5,120 cached rows a sequence (2.69 with lengths as the cell mixes them:
+# the walk goes to the longest row) against 36.7 ms expanded at 2,048; two
+# queries a row (a verify step) 1.93 ms absorbed; a chunk of 512 queries
+# 1.81 ms expanded against 3.02 absorbed at 512 cached rows and 8.63
+# against 12.40 at 4,608. Between 2 and 512 queries a row nothing is
+# served and nothing was measured: the rule takes the measured ends and
+# puts the line just under the crossing of the operations.
+_MLA_EXPAND_MIN_T = 128
+# cached rows a step of the walk: the loop runs to the longest row's last
+# group and no further, so a table slot that no row has filled costs nothing
+# past it. The absorbed form reads alike at 256, 512 and 1,024 (2.82 / 2.76 /
+# 2.69 ms at 5,120 cached); the expanded one, which makes keys and values a
+# head of every group, reads 8.63 / 11.40 / 12.48 ms at 4,608 cached
+_MLA_GROUP = {"mla_absorbed": 512, "mla_expanded": 256}
+
+
+def _mla_form(T: int) -> str:
+    return "mla_expanded" if T >= _MLA_EXPAND_MIN_T else "mla_absorbed"
+
+
+def mla_paged_attention(q_nope, q_rope, w_kvb, latent_pages, block_tables,
+                        q_positions, kv_lens, v_dim: int, form: str = None):
+    """Latent attention of a query chunk against a paged latent cache.
+
+    Args:
+        q_nope: [B, T, H, nope] the queries' columns that meet k_n.
+        q_rope: [B, T, H, rope] their rotated columns, which meet k_r.
+        w_kvb: [latent, H * (nope + v_dim)]: the up-projection of the
+            latent to a head's k_n and v.
+        latent_pages: one layer's pages [N, bs, width], width at least
+            latent + rope: a row is [c | rotated k_r | zeros], and the
+            queries are met with zeros past their own columns, so that no
+            product slices a row off its lanes.
+        block_tables, q_positions, kv_lens: as ``paged_attention``.
+        form: 'mla_absorbed' | 'mla_expanded'; None: ``_mla_form``'s rule.
+
+    Returns [B, T, H, v_dim] in the queries' type. Products take their
+    operands as they are stored (bfloat16 pages are never widened) and
+    accumulate in float32; the softmax is float32."""
+    from ..profiler.telemetry import get_telemetry
+    from . import tier_policy
+
+    B, T, H, nope = q_nope.shape
+    width = latent_pages.shape[-1]
+    latent = w_kvb.shape[0]
+    if q_rope.shape[-1] + latent > width:
+        raise ValueError(f"a row of {width} holds no latent of {latent} "
+                         f"beside {q_rope.shape[-1]} rotated columns")
+    rope = q_rope.shape[-1]
+    # the rotated columns and the row's padding: one lane-aligned slice
+    q_rope = jnp.pad(q_rope, ((0, 0),) * 3 + ((0, width - latent - rope),))
+    bs = latent_pages.shape[1]
+    form = form or _mla_form(T)
+    if form not in _MLA_GROUP:
+        raise ValueError(f"mla_paged_attention: no form {form!r}")
+    tel = get_telemetry()
+    tel.counter("attn/calls")
+    tel.gauge(f"attn/tier.mla.t{T}", tier_policy.TIER_IDS[form])
+    slots = max(1, min(block_tables.shape[1], _MLA_GROUP[form] // bs))
+    pad = -block_tables.shape[1] % slots
+    if pad:  # whole groups: the scratch page, which kv_lens masks
+        block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
+    group = slots * bs
+    steps = (jnp.max(kv_lens).astype(jnp.int32) + group - 1) // group
+    scale = 1.0 / math.sqrt(nope + rope)
+    w = w_kvb.reshape(latent, H, nope + v_dim)
+    f32 = jnp.float32
+    if form == "mla_absorbed":
+        q_lat = jnp.einsum("bthn,chn->bthc", q_nope, w[..., :nope],
+                           preferred_element_type=f32)
+        qf = (jnp.concatenate([q_lat, q_rope.astype(f32)], axis=-1)
+              * scale).astype(q_nope.dtype)
+
+        def scores(rows):
+            return jnp.einsum("bthw,bsw->bhts", qf, rows,
+                              preferred_element_type=f32), rows[..., :latent]
+
+        def weigh(p, values):
+            return jnp.einsum("bhts,bsc->bhtc", p.astype(values.dtype),
+                              values, preferred_element_type=f32)
+
+        out_width = latent
+    else:
+        qn = (q_nope.astype(f32) * scale).astype(q_nope.dtype)
+        qr = (q_rope.astype(f32) * scale).astype(q_rope.dtype)
+
+        def scores(rows):
+            kv = jnp.einsum("bsc,chk->bshk", rows[..., :latent], w,
+                            preferred_element_type=f32).astype(rows.dtype)
+            s = (jnp.einsum("bthn,bshn->bhts", qn, kv[..., :nope],
+                            preferred_element_type=f32)
+                 + jnp.einsum("bthr,bsr->bhts", qr, rows[..., latent:],
+                              preferred_element_type=f32))
+            return s, kv[..., nope:]
+
+        def weigh(p, values):
+            return jnp.einsum("bhts,bshv->bhtv", p.astype(values.dtype),
+                              values, preferred_element_type=f32)
+
+        out_width = v_dim
+
+    def body(i, carry):
+        acc, m, l = carry
+        pids = jax.lax.dynamic_slice_in_dim(block_tables, i * slots, slots,
+                                            axis=1)
+        rows = latent_pages[pids].reshape(B, group, width)
+        s, values = scores(rows)
+        k_pos = i * group + jnp.arange(group, dtype=jnp.int32)
+        s = jnp.where(_paged_mask(k_pos, q_positions, kv_lens)[:, None], s,
+                      _NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        corr = jnp.exp(m - m_new)
+        return (acc * corr[..., None] + weigh(p, values), m_new,
+                l * corr + p.sum(axis=-1))
+
+    acc, _, l = jax.lax.fori_loop(
+        jnp.int32(0), steps, body,
+        (jnp.zeros((B, H, T, out_width), f32),
+         jnp.full((B, H, T), _NEG_INF, f32), jnp.zeros((B, H, T), f32)))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    if form == "mla_absorbed":
+        out = jnp.einsum("bhtc,chv->bthv", out.astype(q_nope.dtype),
+                         w[..., nope:], preferred_element_type=f32)
+    else:
+        out = out.transpose(0, 2, 1, 3)
+    return out.astype(q_nope.dtype)
+
+
+# ---------------------------------------------------------------------------
 # Materialized XLA attention (TPU fast path for moderate sequence lengths)
 # ---------------------------------------------------------------------------
 # One chunk body serves every call, causal or not, biased or not: a call

@@ -65,7 +65,10 @@ class TierCompileError(RuntimeError):
 # serving KV-cache pool — ops.attention.paged_attention); they join the
 # same id space so one gauge family covers train and serve dispatch.
 TIER_IDS = {"xla": 0, "flash_tpu": 1, "pallas": 2, "blockwise": 3, "ring": 4,
-            "paged_gather": 5, "paged_scan": 6}
+            "paged_gather": 5, "paged_scan": 6,
+            # the two forms of latent attention over a paged latent cache
+            # (ops.attention.mla_paged_attention): a rule on the call
+            "mla_absorbed": 7, "mla_expanded": 8}
 
 # decode-path tiers: both are always feasible (pure-XLA gather/scan), so
 # selection is purely a measurement or heuristic question, never a gate

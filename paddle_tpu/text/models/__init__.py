@@ -31,3 +31,10 @@ from .kimi_linear import (  # noqa: F401
     KimiLinearModel,
     kimi_linear_tiny,
 )
+from .pangu_ultra_moe import (  # noqa: F401
+    PanguUltraMoEConfig,
+    PanguUltraMoEForCausalLM,
+    PanguUltraMoEModel,
+    pangu_decode_fns,
+    pangu_ultra_moe_tiny,
+)

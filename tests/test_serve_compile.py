@@ -88,7 +88,7 @@ def instructions(text):
             bool(m.group(1))
 
 
-def pool_sized_writes(text):
+def pool_sized_writes(text, blocks=BLOCKS, layer=LAYER_OF_THE_POOL):
     """Instructions whose result holds a bfloat16 array over all the
     pool's blocks, a layer of it or more, and that are neither the scatter
     nor a fusion around it (both update the donated pages where they
@@ -100,7 +100,7 @@ def pool_sized_writes(text):
         shapes = [[int(d) for d in dims.split(",") if d]
                   for dims in re.findall(r"bf16\[([0-9,]*)\]", typ)]
         if op in _PASSES_ON or not any(
-                BLOCKS in shape and math.prod(shape) >= LAYER_OF_THE_POOL
+                blocks in shape and math.prod(shape) >= layer
                 for shape in shapes):
             continue
         if op == "scatter":
@@ -192,3 +192,59 @@ def test_a_served_step_writes_no_whole_layer_of_the_pool(
         # table to float32, 0.7 GB at 64 rows: the table's, not the pool's)
         assert compiled.memory_analysis().temp_size_in_bytes \
             < 2 * LAYER_OF_THE_POOL
+
+
+# -- the latent pool of a served latent-attention expert model ------------------
+
+@pytest.mark.parametrize("entry, rows, tokens", [
+    ("serve.decode.b64", 64, 1), ("serve.prefill.c512", 1, 512)])
+def test_a_latent_step_copies_neither_its_pool_nor_its_weights(
+        one_chip, entry, rows, tokens):
+    """openPangu-Ultra-MoE's served step at the widths and the pool of
+    ``openpangu-ultra-moe-718b.serve-closed-reason`` (7,680 wide, 128
+    heads, 16 of 256 experts, 16,384 blocks of 16 rows stored 640 wide, a
+    table of 320 slots), cut to one dense and one expert layer. With rows
+    576 wide the runtime laid the blocks along the lanes and every step
+    copied each layer's array into the row-major layout and back; without
+    the barrier after W_qb's product the compiler laid that weight out anew
+    a step (PERF.md section 6, PR 37)."""
+    from paddle_tpu.text.models.pangu_ultra_moe import (
+        PanguUltraMoEConfig, PanguUltraMoEForCausalLM)
+
+    blocks, table = 16384, 320
+    shaped = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    with paddle.LazyGuard():  # shapes only: the weights are never made
+        model = PanguUltraMoEForCausalLM(PanguUltraMoEConfig(
+            vocab_rows_held=19200, layers_held=2, dense_layers_held=1,
+            experts_held=range(16), nextn_held=0))
+    spec = model.decode_spec("bfloat16")
+    assert spec["head_dim"] == 640
+    params = {name: shaped(p.shape, jnp.bfloat16)
+              for name, p in get_params(model).items()}
+    pool = _pool_config(spec, blocks, BLOCK, "bfloat16", 0)
+    pages = jax.tree_util.tree_map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(lambda: KVCachePool(pool).pages))
+    assert [a.shape for a in pages["latent"]] == [(blocks, BLOCK, 640)] * 2
+
+    def step(params, toks, qpos, cache, tables, kv_lens, slots):
+        logits, cache = spec["forward_chunk"](params, toks, qpos, cache,
+                                              tables, kv_lens, slots)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+
+    ints = lambda *shape: shaped(shape, jnp.int32)  # noqa: E731
+    compiled = compile_for_the_chip(
+        step, params, ints(rows, tokens), ints(rows, tokens), pages,
+        ints(rows, table), ints(rows), ints(rows), donate_argnums=(3,))
+    text = compiled.as_text()
+    assert "scatter" in text, "the reader would pass an empty module"
+    found = pool_sized_writes(text, blocks, blocks * BLOCK * 640)
+    assert not found, f"{entry}:\n" + "\n".join(found)
+    # W_qb (1,536 x 128 heads of 192) is read where it lies
+    assert not re.search(r"= bf16\[128,192,1536\]", text)
+    assert not re.search(r"= bf16\[1536,24576\]\S* copy\(", text)
+    # beside the donated pages a step keeps a group of gathered rows a
+    # sequence and a chunk's scores: far under one layer of the pool
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < blocks * BLOCK * 640 * 2
