@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...ops.attention import table_slots_live
 from ...profiler import device_profile as _device_profile
 from ...profiler.retrace import tracked_jit
 from ...profiler.telemetry import get_telemetry
@@ -563,8 +564,20 @@ class DecodeScheduler:
             lens[i] = nc[i] + T
             tables[i] = pool.block_table(r.id, eng._table_width)
             slots[i] = pool.slot(r.id)
+        self._count_table_slots(lens)
         # one call hands all five to the device
         return tuple(jax.device_put((toks, qpos, tables, lens, slots)))
+
+    def _count_table_slots(self, lens: np.ndarray) -> None:
+        """A step's table: the slots its longest row fills, which is what
+        the paged scan walks, and the table's width; their ratio is the
+        share of the table the walk reads."""
+        tel = get_telemetry()
+        if tel.enabled:
+            eng = self._engine
+            tel.counter("serve/table_slots_live", int(table_slots_live(
+                lens, eng.config.kv_block_size, eng._table_width, xp=np)))
+            tel.counter("serve/table_slots", eng._table_width)
 
     # -- prefill -----------------------------------------------------------
     def _prefill_chunk(self, r: GenRequest) -> None:
@@ -584,6 +597,7 @@ class DecodeScheduler:
         lens = np.asarray([r.ncache + real], np.int32)
         table = eng._pool.block_table(r.id, eng._table_width)[None]
         slot = np.asarray([eng._pool.slot(r.id)], np.int32)
+        self._count_table_slots(lens)
         if r.ncache == 0 and eng._pool.config.state and tel.enabled:
             # the chunk that starts at position 0 starts the state again
             tel.counter("serve/state_resets")

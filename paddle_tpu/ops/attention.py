@@ -354,9 +354,9 @@ def _ring_sharded(q, k, v, causal, blhd):
 # choice of kernel here that is still measured, ROADMAP D2b):
 # - 'paged_gather': one gather of the whole context then one fused
 #   masked softmax — fastest while the context is score-tensor-small;
-# - 'paged_scan': lax.scan over pages with online softmax — O(block)
-#   live memory, int8 pages dequantize one page at a time (the actual
-#   HBM win of int8 storage).
+# - 'paged_scan': a loop over pages with online softmax, up to the
+#   longest row's last page — O(block) live memory, int8 pages dequantize
+#   one page at a time (the actual HBM win of int8 storage).
 # Positions are logical: token p of a sequence lives in table slot
 # p // block_size at offset p % block_size, so slot index IS position.
 
@@ -458,11 +458,23 @@ def _lane_group(T, H, D, k_pages, k_scale):
     return 0 if H % r else r
 
 
+def table_slots_live(kv_lens, block_size: int, width: int, xp=jnp):
+    """Table slots that the round's longest row fills: where the paged
+    scan stops. ``xp`` is ``jnp`` for the traced lengths of a step, ``np``
+    for the scheduler's own, which counts them
+    (``counter/serve/table_slots_live``)."""
+    return xp.minimum((xp.max(kv_lens) + block_size - 1) // block_size,
+                      width)
+
+
 def _paged_scan_impl(q, k_pages, v_pages, block_tables, q_positions,
                      kv_lens, k_scale=None, v_scale=None):
     """Online-softmax scan over table slots — the flash recurrence over
     pages. Only one [B, bs, H, D] page pair is live (and, for int8
-    pools, dequantized) per step.
+    pools, dequantized) per step. The walk stops at the slot that holds
+    the longest row's last position (``table_slots_live``): a slot past it
+    is masked in every row, so skipping it changes no live row's output
+    (it was ``acc * 1 + 0``) and its pages are never read.
 
     Two forms of one recurrence, chosen by ``_lane_group`` from the
     queries, the pages and the heads: with the heads as an axis (``[B, bs,
@@ -516,7 +528,7 @@ def _paged_scan_impl(q, k_pages, v_pages, block_tables, q_positions,
 
         acc0 = jnp.zeros((B, H, T, D), jnp.float32)
 
-    def body(carry, i):
+    def body(i, carry):
         acc, m, l = carry
         pids = block_tables[:, i]  # [B]
         kc = _paged_widen(k_pages[pids],
@@ -534,12 +546,13 @@ def _paged_scan_impl(q, k_pages, v_pages, block_tables, q_positions,
         corr = jnp.exp(m - m_new)
         acc = advance(acc, corr, p, vc)
         l = l * corr + p.sum(axis=-1)
-        return (acc, m_new, l), None
+        return acc, m_new, l
 
     m0 = jnp.full((B, H, T), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, H, T), jnp.float32)
-    (acc, m, l), _ = jax.lax.scan(
-        body, (acc0, m0, l0), jnp.arange(M, dtype=jnp.int32))
+    steps = table_slots_live(kv_lens.astype(jnp.int32), bs, M)
+    acc, _, l = jax.lax.fori_loop(jnp.int32(0), steps, body,
+                                  (acc0, m0, l0))
     l = jnp.maximum(l, 1e-30)
     if r:  # [B, G, T, lanes] -> [B, T, H, D]
         out = (acc / to_lanes(l)).transpose(0, 2, 1, 3).reshape(B, T, H, D)

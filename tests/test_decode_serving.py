@@ -341,6 +341,97 @@ class TestPagedAttention:
         np.testing.assert_allclose(flat, by_heads, atol=2e-6)
         np.testing.assert_allclose(flat, gathered, atol=2e-6)
 
+    # the scan's forms: (query heads, key heads, head width, pages flat,
+    # int8 pages); at T = 1 the first takes lane groups (two heads of 64)
+    SCAN_FORMS = {"lane_group": (4, 4, 64, True, False),
+                  "heads_axis": (2, 2, 8, False, False),
+                  "grouped": (4, 2, 8, True, False),
+                  "int8": (2, 2, 8, False, True)}
+
+    def walk_case(self, form, T, seed=11):
+        """Rows of 7 and 13 cached positions and a padded row (kv_len 0,
+        the scratch page's table) over a table of 8 slots of 4: the longest
+        row fills 4 slots. Each live row's pages are its own; every slot
+        past the fourth points at a page of its own too."""
+        H, Hkv, D, flat, int8 = self.SCAN_FORMS[form]
+        rng = np.random.RandomState(seed)
+        bs, M = 4, 8
+        N = 1 + 3 * M
+        shape = (N, bs, Hkv * D) if flat else (N, bs, Hkv, D)
+        k = jnp.asarray(rng.randn(*shape).astype(np.float32))
+        v = jnp.asarray(rng.randn(*shape).astype(np.float32))
+        tables = np.zeros((3, M), np.int32)
+        tables[:2] = 1 + rng.permutation(2 * M).reshape(2, M)
+        kv_lens = np.array([7, 13, 0], np.int32)
+        q_pos = np.maximum(kv_lens[:, None] - T, 0) + np.arange(T)[None]
+        q = jnp.asarray(rng.randn(3, T, H, D).astype(np.float32))
+        ks = vs = None
+        if int8:
+            k, ks = quantize_kv(k)
+            v, vs = quantize_kv(v)
+        return (q, k, v, tables, q_pos.astype(np.int32), kv_lens, ks, vs,
+                N, M)
+
+    @staticmethod
+    def whole_walk(q, k, v, tables, q_pos, kv_lens, ks, vs, N, M):
+        """The same call with a row of the table's full length added: the
+        walk then goes over every slot, as the parent's scan did."""
+        T = q.shape[1]
+        bs = k.shape[1]
+        full = np.arange(N - M, N, dtype=np.int32)[None]
+        return att._paged_scan_impl(
+            jnp.concatenate([q, q[:1]]), k, v,
+            jnp.asarray(np.concatenate([tables, full])),
+            jnp.asarray(np.concatenate(
+                [q_pos, (M * bs - T + np.arange(T, dtype=np.int32))[None]])),
+            jnp.asarray(np.append(kv_lens, np.int32(M * bs))), ks, vs)[:3]
+
+    @pytest.mark.parametrize("T", [1, 5])
+    @pytest.mark.parametrize("form", list(SCAN_FORMS))
+    def test_skipped_slots_change_nothing(self, form, T):
+        """The scan stops at the longest row's last slot (4 of 8 here):
+        the live rows are bit for bit what a walk over the whole table
+        gives, and within rounding the gather tier's."""
+        (q, k, v, tables, q_pos, kv_lens, ks, vs, N, M) = case = \
+            self.walk_case(form, T)
+        H, _, D, _, _ = self.SCAN_FORMS[form]
+        assert att._lane_group(T, H, D, k, ks) == (
+            2 if form == "lane_group" and T == 1 else 0)
+        assert int(att.table_slots_live(kv_lens, k.shape[1], M,
+                                        xp=np)) == 4
+        args = (q, k, v, jnp.asarray(tables), jnp.asarray(q_pos),
+                jnp.asarray(kv_lens), ks, vs)
+        bounded = np.asarray(att._paged_scan_impl(*args))
+        whole = np.asarray(self.whole_walk(*case))
+        np.testing.assert_array_equal(bounded[:2], whole[:2])
+        gathered = np.asarray(att._paged_gather_impl(*args))
+        np.testing.assert_allclose(bounded[:2], gathered[:2], atol=1e-5)
+
+    @pytest.mark.parametrize("form", list(SCAN_FORMS))
+    def test_pages_past_the_longest_row_are_never_read(self, form):
+        """Every page a table points at past the longest row's last slot
+        holds NaN: a masked slot the walk still visited would carry it
+        into the output (0 * NaN), so the live rows' being finite, and
+        equal to the clean call's, says no such slot was visited."""
+        (q, k, v, tables, q_pos, kv_lens, ks, vs, _, M) = \
+            self.walk_case(form, 1)
+        live = int(att.table_slots_live(kv_lens, k.shape[1], M, xp=np))
+        past = np.unique(tables[:, live:])
+        past = past[past != 0]  # the scratch page lies inside the walk
+        assert past.size == 2 * (M - live)
+        if ks is None:
+            k_bad, v_bad = k.at[past].set(np.nan), v.at[past].set(np.nan)
+            ks_bad, vs_bad = ks, vs
+        else:  # int8 pages: a NaN scale makes the page NaN
+            k_bad, v_bad = k, v
+            ks_bad, vs_bad = ks.at[past].set(np.nan), vs.at[past].set(np.nan)
+        rest = (jnp.asarray(tables), jnp.asarray(q_pos), jnp.asarray(kv_lens))
+        clean = np.asarray(att._paged_scan_impl(q, k, v, *rest, ks, vs))
+        poisoned = np.asarray(att._paged_scan_impl(q, k_bad, v_bad, *rest,
+                                                   ks_bad, vs_bad))
+        assert np.isfinite(poisoned[:2]).all()
+        np.testing.assert_array_equal(poisoned[:2], clean[:2])
+
     def test_vs_dense_reference(self):
         import math
         q, k, v, tables, q_pos, kv_lens, _, _ = self.setup_pages()
@@ -665,6 +756,41 @@ class TestTokenEngine:
         # it waits a round; a's cursor and blocks are untouched
         assert a.ncache == 16 and eng._pool.owned(a.id)
         assert b.ncache == 3 and not eng._pool.owned(b.id)
+
+    def test_table_slot_counters(self):
+        """Each prefill chunk and each decode round counts the slots its
+        longest row fills (what the paged scan walks) and the table's
+        width: a table of 8 slots of 8, a prompt of 20 in chunks of 8 and
+        one of 5, then one decode round over both, padded to a bucket of
+        4."""
+        eng, _ = make_engine(kv_block_size=8, max_seq_len=64,
+                             decode_buckets=(4,))
+        assert eng._table_width == 8
+        sched, tel = eng._scheduler, get_telemetry()
+        rng = np.random.RandomState(9)
+        long_r = GenRequest(1, rng.randint(0, 96, 20).astype(np.int32), 4)
+        short_r = GenRequest(2, rng.randint(0, 96, 5).astype(np.int32), 4)
+
+        def added(run):
+            before = [tel.counter_value(f"serve/table_slots{s}")
+                      for s in ("_live", "")]
+            run()
+            return [tel.counter_value(f"serve/table_slots{s}") - b
+                    for s, b in zip(("_live", ""), before)]
+
+        try:
+            # chunks cache 8, 16 and 20 positions: 1, 2 and 3 slots
+            for live in (1, 2, 3):
+                assert added(lambda: sched._prefill_chunk(long_r)) \
+                    == [live, 8]
+            assert added(lambda: sched._prefill_chunk(short_r)) == [1, 8]
+            assert long_r.pending == short_r.pending == 1
+            # rows of 21 and 6 positions and two padded rows of 0
+            assert added(lambda: sched._decode_round([long_r, short_r])) \
+                == [3, 8]
+            assert tel.counter_value("serve/decode_steps") == 1
+        finally:
+            eng.shutdown()
 
     def test_submit_validation(self):
         eng, _ = make_engine()
