@@ -46,6 +46,16 @@ Telemetry (schema-gated): counters ``serve/kv_blocks_{alloc,free}``,
 ``serve.prefill.c<N>``, ``serve.verify.b<N>``, ``serve.draft.b<N>``) is
 cost-analyzed by the PR 5 attribution layer and mapped to its own
 histogram, so decode-step MFU is a first-class column.
+
+Spans (``profiler.spans.Span``: ``pt.serve.*`` in a device trace, and the
+flight recorder): ``serve.iter`` around every iteration with work in
+flight, its step the scheduler's ``batch_index``; inside it one phase open
+innermost at every moment (``serve.admit``, ``blocks``, ``arrays``,
+``dispatch``, ``fetch``, ``tokens``, ``retire``), the passes that run a
+compiled entry in round spans that hold phases only (``serve.prefill_chunk``,
+``decode_round``, ``verify_round``, ``draft_round``). So an idle stretch of
+the device is booked to the host phase that held it
+(``hlo_attrib.idle_by_span``). All host code, outside every compiled entry.
 """
 from __future__ import annotations
 
@@ -61,6 +71,7 @@ import numpy as np
 from ...ops.attention import table_slots_live
 from ...profiler import device_profile as _device_profile
 from ...profiler.retrace import tracked_jit
+from ...profiler.spans import Span
 from ...profiler.telemetry import get_telemetry
 from ...resilience.inject import active_injector
 from ...resilience.preemption import preemption_requested
@@ -398,63 +409,20 @@ class DecodeScheduler:
                             eng._finish(r, RequestStatus.DRAINED,
                                         detail="drained before prefill")
                         return
-                # admission: fill free slots from the queue (drain stops
-                # this — a prompt admitted mid-drain could never finish)
-                while not eng.draining and len(running) < cfg.max_running:
-                    ready, expired = eng._queue.take(
-                        1, timeout=0.0 if running else cfg.idle_poll_s)
-                    for r in expired:
-                        eng._finish(r, RequestStatus.DEADLINE_EXCEEDED,
-                                    detail="deadline expired in queue")
-                    if not ready:
-                        break
-                    ready[0].trace_event(  # sampled: queue wait ends here
-                        "queue",
-                        dur_s=time.monotonic() - ready[0].submitted_at)
-                    running.append(ready[0])
-                if tel.enabled:
-                    tel.gauge("serve/queue_depth", len(eng._queue))
-                    tel.gauge("serve/running", len(running))
                 if not running:
-                    continue
-                # mid-generation deadline shedding: the slot frees and
-                # the partial text is discarded (stale results are never
-                # delivered as success)
-                now = time.monotonic()
-                for r in list(running):
-                    if r.deadline is not None and now >= r.deadline:
-                        self._retire(r, RequestStatus.DEADLINE_EXCEEDED,
-                                     detail="deadline expired "
-                                            "mid-generation")
-                        running.remove(r)
-                if not running:
-                    continue
-                inj = active_injector()
-                if inj is not None:
-                    for r in running:  # injected straggler stalls the round
-                        inj.slow_req(r.id)
-                # device-profile capture boundary: one scheduler round
+                    # nothing in flight: the wait for a first prompt is
+                    # no iteration's, and opens no span
+                    self._admit(cfg.idle_poll_s)
+                    if not running:
+                        self._publish_gauges(tel)
+                        continue
+                # device-profile capture boundary: one scheduler iteration
                 # (≤1 prefill chunk + one decode step for every running
-                # sequence) is this loop's "step"
+                # sequence) is this loop's "step"; a capture holds whole
+                # iterations, each under its own serve.iter span
                 _device_profile.step_boundary("serve.decode")
-                prefilling = [r for r in running if r.pending > 1]
-                decoding = [r for r in running if r.pending == 1]
-                if prefilling:
-                    self._prefill_chunk(prefilling[0])
-                if decoding:
-                    if eng.self_draft:
-                        self._self_spec_round(decoding)
-                    elif eng.spec_enabled:
-                        self._spec_round(decoding)
-                    else:
-                        self._decode_round(decoding)
-                for r in list(running):
-                    if self._done_generating(r):
-                        self._retire(r, RequestStatus.OK)
-                        running.remove(r)
-                self.batch_index += 1
-                if inj is not None:
-                    inj.maybe_sigterm(self.batch_index)
+                with Span("serve.iter", cat="serve", step=self.batch_index):
+                    self._iteration()
         except BaseException:
             # same contract as the PR 7 scheduler: a crash must not
             # strand accepted requests — latch drain first (post-crash
@@ -470,6 +438,78 @@ class DecodeScheduler:
             raise
         finally:
             self._stopped.set()
+
+    def _iteration(self) -> None:
+        """One iteration with work in flight, under ``serve.iter``. At
+        every moment of it one phase span is open innermost on this
+        thread (``serve.admit``, ``serve.blocks``, ``serve.arrays``,
+        ``serve.dispatch``, ``serve.fetch``, ``serve.tokens``,
+        ``serve.retire``); a round span (``serve.prefill_chunk``,
+        ``serve.decode_round``, ``serve.verify_round``,
+        ``serve.draft_round``) holds phases only. So an idle stretch of
+        the device in a trace is booked to the host phase that held it."""
+        eng = self._engine
+        running = self._running
+        with Span("serve.admit", cat="serve"):
+            self._admit(0.0)
+            self._publish_gauges(get_telemetry())
+            # mid-generation deadline shedding: the slot frees and the
+            # partial text is discarded (stale results are never
+            # delivered as success)
+            now = time.monotonic()
+            for r in list(running):
+                if r.deadline is not None and now >= r.deadline:
+                    self._retire(r, RequestStatus.DEADLINE_EXCEEDED,
+                                 detail="deadline expired mid-generation")
+                    running.remove(r)
+            if not running:
+                return
+            inj = active_injector()
+            if inj is not None:
+                for r in running:  # injected straggler stalls the round
+                    inj.slow_req(r.id)
+            prefilling = [r for r in running if r.pending > 1]
+            decoding = [r for r in running if r.pending == 1]
+        if prefilling:
+            self._prefill_chunk(prefilling[0])
+        if decoding:
+            if eng.self_draft:
+                self._self_spec_round(decoding)
+            elif eng.spec_enabled:
+                self._spec_round(decoding)
+            else:
+                self._decode_round(decoding)
+        with Span("serve.retire", cat="serve"):
+            for r in list(running):
+                if self._done_generating(r):
+                    self._retire(r, RequestStatus.OK)
+                    running.remove(r)
+            self.batch_index += 1
+            if inj is not None:
+                inj.maybe_sigterm(self.batch_index)
+
+    def _admit(self, wait_s: float) -> None:
+        """Fill free slots from the queue, waiting up to ``wait_s`` for a
+        first prompt where nothing runs (drain stops this — a prompt
+        admitted mid-drain could never finish)."""
+        eng = self._engine
+        running = self._running
+        while not eng.draining and len(running) < eng.config.max_running:
+            ready, expired = eng._queue.take(
+                1, timeout=0.0 if running else wait_s)
+            for r in expired:
+                eng._finish(r, RequestStatus.DEADLINE_EXCEEDED,
+                            detail="deadline expired in queue")
+            if not ready:
+                break
+            ready[0].trace_event(  # sampled: queue wait ends here
+                "queue", dur_s=time.monotonic() - ready[0].submitted_at)
+            running.append(ready[0])
+
+    def _publish_gauges(self, tel) -> None:
+        if tel.enabled:
+            tel.gauge("serve/queue_depth", len(self._engine._queue))
+            tel.gauge("serve/running", len(self._running))
 
     # -- helpers -----------------------------------------------------------
     def _done_generating(self, r: GenRequest) -> bool:
@@ -581,80 +621,103 @@ class DecodeScheduler:
 
     # -- prefill -----------------------------------------------------------
     def _prefill_chunk(self, r: GenRequest) -> None:
+        with Span("serve.prefill_chunk", cat="serve"):
+            self._prefill_chunk_phases(r)
+
+    def _prefill_chunk_phases(self, r: GenRequest) -> None:
         eng = self._engine
         cfg = eng.config
         tel = get_telemetry()
         C = cfg.prefill_chunk
         real = min(C, r.pending)
-        if not self._ensure_blocks(r, r.ncache + real):
-            return  # pool exhausted even after evictions; retry next round
-        if eng.spec_enabled and not self._ensure_blocks(
-                r, r.draft_ncache + real, draft=True):
-            return
-        chunk = r.toks[r.ncache:r.ncache + real] + [0] * (C - real)
-        toks = np.asarray(chunk, np.int32)[None]
-        qpos = (r.ncache + np.arange(C, dtype=np.int32))[None]
-        lens = np.asarray([r.ncache + real], np.int32)
-        table = eng._pool.block_table(r.id, eng._table_width)[None]
-        slot = np.asarray([eng._pool.slot(r.id)], np.int32)
-        self._count_table_slots(lens)
-        if r.ncache == 0 and eng._pool.config.state and tel.enabled:
-            # the chunk that starts at position 0 starts the state again
-            tel.counter("serve/state_resets")
-        t0 = time.perf_counter()
-        # numpy to the device in one call: no array is made by a program
-        # of its own (a device run the trace would book to this round)
-        toks, qpos, table, lens, slot = jax.device_put(
-            (toks, qpos, table, lens, slot))
-        g, pages, *hidden = self._get_prefill_fn()(
-            eng._params, toks, qpos, eng._pool.pages, table, lens, slot)
-        eng._pool.pages = pages
-        g_np = np.asarray(g)
-        ms = (time.perf_counter() - t0) * 1e3
-        r.trace_event(f"prefill.c{C}", dur_s=ms / 1e3)
-        if tel.enabled:
-            tel.counter("serve/prefill_chunks")
-            tel.observe("serve/prefill_ms", ms)
-            tel.observe(f"serve/prefill_ms.c{C}", ms)
+        with Span("serve.blocks", cat="serve"):
+            if not self._ensure_blocks(r, r.ncache + real):
+                return  # pool exhausted even after evictions; next round
+            if eng.spec_enabled and not self._ensure_blocks(
+                    r, r.draft_ncache + real, draft=True):
+                return
+        with Span("serve.arrays", cat="serve"):
+            chunk = r.toks[r.ncache:r.ncache + real] + [0] * (C - real)
+            toks = np.asarray(chunk, np.int32)[None]
+            qpos = (r.ncache + np.arange(C, dtype=np.int32))[None]
+            lens = np.asarray([r.ncache + real], np.int32)
+            table = eng._pool.block_table(r.id, eng._table_width)[None]
+            slot = np.asarray([eng._pool.slot(r.id)], np.int32)
+            self._count_table_slots(lens)
+            if r.ncache == 0 and eng._pool.config.state and tel.enabled:
+                # the chunk that starts at position 0 starts the state
+                # again
+                tel.counter("serve/state_resets")
+            t0 = time.perf_counter()
+            # numpy to the device in one call: no array is made by a
+            # program of its own (a device run the trace would book to
+            # this round)
+            toks, qpos, table, lens, slot = jax.device_put(
+                (toks, qpos, table, lens, slot))
+        with Span("serve.dispatch", cat="serve"):
+            g, pages, *hidden = self._get_prefill_fn()(
+                eng._params, toks, qpos, eng._pool.pages, table, lens, slot)
+            eng._pool.pages = pages
+        with Span("serve.fetch", cat="serve"):
+            g_np = np.asarray(g)
+            ms = (time.perf_counter() - t0) * 1e3
+        with Span("serve.tokens", cat="serve"):
+            r.trace_event(f"prefill.c{C}", dur_s=ms / 1e3)
+            if tel.enabled:
+                tel.counter("serve/prefill_chunks")
+                tel.observe("serve/prefill_ms", ms)
+                tel.observe(f"serve/prefill_ms.c{C}", ms)
         if eng.spec_enabled:
             # the draft cache follows the target's chunk schedule so
             # proposing never needs a separate prompt pass
-            dtable = eng._draft_pool.block_table(r.id, eng._table_width)[None]
-            dlens = np.asarray([r.draft_ncache + real], np.int32)
-            t0 = time.perf_counter()
-            dg, dpages = self._get_prefill_fn(draft=True)(
-                eng._draft_params, toks, qpos, eng._draft_pool.pages,
-                jnp.asarray(dtable), jnp.asarray(dlens), slot)
-            eng._draft_pool.pages = dpages
-            np.asarray(dg)
-            if tel.enabled:
-                tel.observe(f"serve/draft_prefill_ms.c{C}",
-                            (time.perf_counter() - t0) * 1e3)
-            r.draft_ncache += real
-        r.ncache += real
-        if r.pending == 0:
-            # the chunk covered every known token: the last position's
-            # greedy output IS the first generated token (TTFT stamps
-            # here)
-            self._append_token(r, int(g_np[0, real - 1]))
+            with Span("serve.arrays", cat="serve"):
+                dtable = eng._draft_pool.block_table(
+                    r.id, eng._table_width)[None]
+                dlens = np.asarray([r.draft_ncache + real], np.int32)
+                t0 = time.perf_counter()
+                dtable, dlens = jnp.asarray(dtable), jnp.asarray(dlens)
+            with Span("serve.dispatch", cat="serve"):
+                dg, dpages = self._get_prefill_fn(draft=True)(
+                    eng._draft_params, toks, qpos, eng._draft_pool.pages,
+                    dtable, dlens, slot)
+                eng._draft_pool.pages = dpages
+            with Span("serve.fetch", cat="serve"):
+                np.asarray(dg)
+            with Span("serve.tokens", cat="serve"):
+                if tel.enabled:
+                    tel.observe(f"serve/draft_prefill_ms.c{C}",
+                                (time.perf_counter() - t0) * 1e3)
+                r.draft_ncache += real
+        with Span("serve.tokens", cat="serve"):
+            r.ncache += real
+            if r.pending == 0:
+                # the chunk covered every known token: the last position's
+                # greedy output IS the first generated token (TTFT stamps
+                # here)
+                self._append_token(r, int(g_np[0, real - 1]))
         if eng.self_draft:
             # the model's own draft follows the chunk: position i takes
             # the target's hidden state of i and the token at i + 1 (the
             # one just emitted, at the prompt's end), and its guess after
             # the last known token is the first proposal
-            start = r.ncache - real
-            nxt = np.zeros((1, C), np.int32)
-            nxt[0, :real] = r.toks[start + 1:start + real + 1]
-            t0 = time.perf_counter()
-            dg, pages = self._get_prefill_fn(draft=True)(
-                eng._params, hidden[0], jax.device_put(nxt), qpos,
-                eng._pool.pages, table, lens, slot)
-            eng._pool.pages = pages
+            with Span("serve.arrays", cat="serve"):
+                start = r.ncache - real
+                nxt = np.zeros((1, C), np.int32)
+                nxt[0, :real] = r.toks[start + 1:start + real + 1]
+                t0 = time.perf_counter()
+                nxt = jax.device_put(nxt)
+            with Span("serve.dispatch", cat="serve"):
+                dg, pages = self._get_prefill_fn(draft=True)(
+                    eng._params, hidden[0], nxt, qpos,
+                    eng._pool.pages, table, lens, slot)
+                eng._pool.pages = pages
             if r.pending == 1:
-                r.proposal = int(np.asarray(dg)[0, real - 1])
+                with Span("serve.fetch", cat="serve"):
+                    r.proposal = int(np.asarray(dg)[0, real - 1])
             if tel.enabled:
-                tel.observe(f"serve/draft_prefill_ms.c{C}",
-                            (time.perf_counter() - t0) * 1e3)
+                with Span("serve.tokens", cat="serve"):
+                    tel.observe(f"serve/draft_prefill_ms.c{C}",
+                                (time.perf_counter() - t0) * 1e3)
 
     # -- plain decode ------------------------------------------------------
     def _decode_round(self, decoding: List[GenRequest],
@@ -664,39 +727,49 @@ class DecodeScheduler:
         round's own batch — the speculative path passes its
         already-ensured group, whose members must not lose their blocks
         to the tail's allocations after their feeds were decided."""
+        with Span("serve.decode_round", cat="serve"):
+            self._decode_round_phases(decoding, protect)
+
+    def _decode_round_phases(self, decoding: List[GenRequest],
+                             protect) -> None:
         eng = self._engine
         tel = get_telemetry()
         group = []
-        for r in decoding:
-            if r.pending != 1:
-                continue  # evicted by a neighbor's allocation this round
-            if len(group) >= eng.config.max_running:
-                break
-            if self._ensure_blocks(r, r.ncache + 1,
-                                   exclude=group + list(protect)):
-                group.append(r)
+        with Span("serve.blocks", cat="serve"):
+            for r in decoding:
+                if r.pending != 1:
+                    continue  # evicted by a neighbor's allocation
+                if len(group) >= eng.config.max_running:
+                    break
+                if self._ensure_blocks(r, r.ncache + 1,
+                                       exclude=group + list(protect)):
+                    group.append(r)
         if not group:
             return
-        bucket = eng.config.bucket_for(len(group))
-        arrays = self._batch_arrays(group, bucket, 1,
-                                    [[r.toks[-1]] for r in group])
-        t0 = time.perf_counter()
-        g, pages = self._decode_fn(bucket)(eng._params, arrays[0],
-                                           arrays[1], eng._pool.pages,
-                                           *arrays[2:])
-        eng._pool.pages = pages
-        g_np = np.asarray(g)
-        ms = (time.perf_counter() - t0) * 1e3
-        if tel.enabled:
-            tel.counter("serve/decode_steps")
-            tel.observe("serve/decode_ms", ms)
-            tel.observe(f"serve/decode_ms.b{bucket}", ms)
-            tel.observe("serve/batch_occupancy", len(group) / bucket)
-        for i, r in enumerate(group):
-            r.trace_event(f"decode.b{bucket}", dur_s=ms / 1e3)
-            r.ncache += 1
-            r.proposal = None  # a draft of the model's own has not followed
-            self._append_token(r, int(g_np[i, 0]))
+        with Span("serve.arrays", cat="serve"):
+            bucket = eng.config.bucket_for(len(group))
+            arrays = self._batch_arrays(group, bucket, 1,
+                                        [[r.toks[-1]] for r in group])
+        with Span("serve.dispatch", cat="serve"):
+            t0 = time.perf_counter()
+            g, pages = self._decode_fn(bucket)(eng._params, arrays[0],
+                                               arrays[1], eng._pool.pages,
+                                               *arrays[2:])
+            eng._pool.pages = pages
+        with Span("serve.fetch", cat="serve"):
+            g_np = np.asarray(g)
+            ms = (time.perf_counter() - t0) * 1e3
+        with Span("serve.tokens", cat="serve"):
+            if tel.enabled:
+                tel.counter("serve/decode_steps")
+                tel.observe("serve/decode_ms", ms)
+                tel.observe(f"serve/decode_ms.b{bucket}", ms)
+                tel.observe("serve/batch_occupancy", len(group) / bucket)
+            for i, r in enumerate(group):
+                r.trace_event(f"decode.b{bucket}", dur_s=ms / 1e3)
+                r.ncache += 1
+                r.proposal = None  # no draft of the model's own followed
+                self._append_token(r, int(g_np[i, 0]))
 
     # -- speculative decode from the model's own draft ----------------------
     def _self_spec_round(self, decoding: List[GenRequest]) -> None:
@@ -713,71 +786,88 @@ class DecodeScheduler:
         cfg = eng.config
         tel = get_telemetry()
         group, tail = [], []
-        for r in decoding:
-            if r.pending != 1:
-                continue
-            if len(group) >= cfg.max_running:
-                break
-            # no room for the k-ahead write, or no standing proposal (a
-            # sequence that fell back to plain decode stays there)
-            if r.ncache + 2 > eng.max_seq_len or r.proposal is None:
-                tail.append(r)
-                continue
-            if self._ensure_blocks(r, r.ncache + 2, exclude=group):
-                group.append(r)
+        # the group is formed before the tail's decode round, outside any
+        # round: its blocks phase lies in the iteration itself
+        with Span("serve.blocks", cat="serve"):
+            for r in decoding:
+                if r.pending != 1:
+                    continue
+                if len(group) >= cfg.max_running:
+                    break
+                # no room for the k-ahead write, or no standing proposal
+                # (a sequence that fell back to plain decode stays there)
+                if r.ncache + 2 > eng.max_seq_len or r.proposal is None:
+                    tail.append(r)
+                    continue
+                if self._ensure_blocks(r, r.ncache + 2, exclude=group):
+                    group.append(r)
         if tail:
             self._decode_round(tail, protect=group)
         if not group:
             return
         bucket = cfg.bucket_for(len(group))
-        arrays = self._batch_arrays(
-            group, bucket, 2, [[r.toks[-1], r.proposal] for r in group])
-        t0 = time.perf_counter()
-        g, pages, hidden = self._verify_fn(bucket)(
-            eng._params, arrays[0], arrays[1], eng._pool.pages, *arrays[2:])
-        eng._pool.pages = pages
-        g_np = np.asarray(g)
-        ms = (time.perf_counter() - t0) * 1e3
-        if tel.enabled:
-            tel.counter("serve/decode_steps")
-            tel.observe("serve/verify_ms", ms)
-            tel.observe(f"serve/verify_ms.b{bucket}", ms)
-            tel.observe("serve/batch_occupancy", len(group) / bucket)
-        nxt = np.zeros((bucket, 2), np.int32)
-        lens = np.zeros((bucket,), np.int32)
-        accepted = []
-        for i, r in enumerate(group):
-            r.trace_event(f"decode.spec.b{bucket}", dur_s=ms / 1e3)
-            a = int(r.proposal == int(g_np[i, 0]))
-            for t in g_np[i, :1 + a]:
-                if not self._append_token(r, int(t)):
-                    break
-            # the draft sees what the target has confirmed: position
-            # ncache with the token that followed it and, where the
-            # proposal was accepted, the next with the target's own
-            nxt[i] = g_np[i]
-            lens[i] = r.ncache + 1 + a
-            r.ncache = min(r.ncache + 1 + a, len(r.toks) - 1)
-            accepted.append(a)
-        t0 = time.perf_counter()
-        nxt, lens = jax.device_put((nxt, lens))
-        dg, pages = self._draft_fn(bucket)(
-            eng._params, hidden, nxt, arrays[1], eng._pool.pages, arrays[2],
-            lens, arrays[4])
-        eng._pool.pages = pages
-        dg_np = np.asarray(dg)
-        for i, (r, a) in enumerate(zip(group, accepted)):
-            r.proposal = int(dg_np[i, a])
-        self._spec_proposed += len(group)
-        self._spec_accepted += sum(accepted)
-        if tel.enabled:
-            ms = (time.perf_counter() - t0) * 1e3
-            tel.observe("serve/draft_ms", ms)
-            tel.observe(f"serve/draft_ms.b{bucket}", ms)
-            tel.counter("serve/spec_proposed", len(group))
-            tel.counter("serve/spec_accepted", sum(accepted))
-            tel.gauge("serve/spec_accept_rate",
-                      self._spec_accepted / max(self._spec_proposed, 1))
+        with Span("serve.verify_round", cat="serve"):
+            with Span("serve.arrays", cat="serve"):
+                arrays = self._batch_arrays(
+                    group, bucket, 2,
+                    [[r.toks[-1], r.proposal] for r in group])
+            with Span("serve.dispatch", cat="serve"):
+                t0 = time.perf_counter()
+                g, pages, hidden = self._verify_fn(bucket)(
+                    eng._params, arrays[0], arrays[1], eng._pool.pages,
+                    *arrays[2:])
+                eng._pool.pages = pages
+            with Span("serve.fetch", cat="serve"):
+                g_np = np.asarray(g)
+                ms = (time.perf_counter() - t0) * 1e3
+            with Span("serve.tokens", cat="serve"):
+                if tel.enabled:
+                    tel.counter("serve/decode_steps")
+                    tel.observe("serve/verify_ms", ms)
+                    tel.observe(f"serve/verify_ms.b{bucket}", ms)
+                    tel.observe("serve/batch_occupancy", len(group) / bucket)
+                nxt = np.zeros((bucket, 2), np.int32)
+                lens = np.zeros((bucket,), np.int32)
+                accepted = []
+                for i, r in enumerate(group):
+                    r.trace_event(f"decode.spec.b{bucket}", dur_s=ms / 1e3)
+                    a = int(r.proposal == int(g_np[i, 0]))
+                    for t in g_np[i, :1 + a]:
+                        if not self._append_token(r, int(t)):
+                            break
+                    # the draft sees what the target has confirmed:
+                    # position ncache with the token that followed it and,
+                    # where the proposal was accepted, the next with the
+                    # target's own
+                    nxt[i] = g_np[i]
+                    lens[i] = r.ncache + 1 + a
+                    r.ncache = min(r.ncache + 1 + a, len(r.toks) - 1)
+                    accepted.append(a)
+        with Span("serve.draft_round", cat="serve"):
+            with Span("serve.arrays", cat="serve"):
+                t0 = time.perf_counter()
+                nxt, lens = jax.device_put((nxt, lens))
+            with Span("serve.dispatch", cat="serve"):
+                dg, pages = self._draft_fn(bucket)(
+                    eng._params, hidden, nxt, arrays[1], eng._pool.pages,
+                    arrays[2], lens, arrays[4])
+                eng._pool.pages = pages
+            with Span("serve.fetch", cat="serve"):
+                dg_np = np.asarray(dg)
+            with Span("serve.tokens", cat="serve"):
+                for i, (r, a) in enumerate(zip(group, accepted)):
+                    r.proposal = int(dg_np[i, a])
+                self._spec_proposed += len(group)
+                self._spec_accepted += sum(accepted)
+                if tel.enabled:
+                    ms = (time.perf_counter() - t0) * 1e3
+                    tel.observe("serve/draft_ms", ms)
+                    tel.observe(f"serve/draft_ms.b{bucket}", ms)
+                    tel.counter("serve/spec_proposed", len(group))
+                    tel.counter("serve/spec_accepted", sum(accepted))
+                    tel.gauge(
+                        "serve/spec_accept_rate",
+                        self._spec_accepted / max(self._spec_proposed, 1))
 
     # -- speculative decode ------------------------------------------------
     def _spec_round(self, decoding: List[GenRequest]) -> None:
@@ -791,149 +881,188 @@ class DecodeScheduler:
         k = cfg.spec_k
         group = []
         tail = []  # too close to max_seq_len for k-ahead writes
-        for r in decoding:
-            if r.pending != 1:
-                continue
-            if len(group) >= cfg.max_running:
-                break
-            # the verify step writes positions ncache..ncache+k: a
-            # sequence within k tokens of max_seq_len cannot take a spec
-            # round (the writes would overflow its block table / position
-            # range) — it finishes its last tokens on the plain decode
-            # path instead
-            if r.ncache + 1 + k > eng.max_seq_len:
-                tail.append(r)
-                continue
-            # target writes k+1 entries; draft catches up + writes k
-            if not self._ensure_blocks(r, r.ncache + 1 + k,
-                                       exclude=group):
-                continue
-            if not self._ensure_blocks(r, len(r.toks) - 1 + k, draft=True,
-                                       exclude=group):
-                continue
-            group.append(r)
+        # formed before the tail's decode round, in the iteration itself
+        with Span("serve.blocks", cat="serve"):
+            for r in decoding:
+                if r.pending != 1:
+                    continue
+                if len(group) >= cfg.max_running:
+                    break
+                # the verify step writes positions ncache..ncache+k: a
+                # sequence within k tokens of max_seq_len cannot take a
+                # spec round (the writes would overflow its block table /
+                # position range) — it finishes its last tokens on the
+                # plain decode path instead
+                if r.ncache + 1 + k > eng.max_seq_len:
+                    tail.append(r)
+                    continue
+                # target writes k+1 entries; draft catches up + writes k
+                if not self._ensure_blocks(r, r.ncache + 1 + k,
+                                           exclude=group):
+                    continue
+                if not self._ensure_blocks(r, len(r.toks) - 1 + k,
+                                           draft=True, exclude=group):
+                    continue
+                group.append(r)
         if tail:
             # the tail's allocations must not evict spec-group members
             # whose feeds were already decided from their ensured blocks
             self._decode_round(tail, protect=group)
         if not group:
             return
+        bucket = cfg.bucket_for(len(group))
+        with Span("serve.draft_round", cat="serve"):
+            proposals = self._spec_drafts(group, bucket)
+        with Span("serve.verify_round", cat="serve"):
+            self._spec_verify(group, bucket, proposals)
+
+    def _spec_drafts(self, group: List[GenRequest], bucket: int):
+        """The draft model's pass of a speculative round: its cache caught
+        up with what the target has confirmed, then ``spec_k`` proposals
+        for every sequence of the group."""
+        eng = self._engine
+        cfg = eng.config
+        tel = get_telemetry()
         # draft catch-up, gap == 1 (the steady state after a fully
         # accepted round): ONE batched T=1 draft step for all of them —
         # not a chunk-padded per-sequence prefill on the hot path
         gap1 = [r for r in group if len(r.toks) - 1 - r.draft_ncache == 1]
         if gap1:
-            b1 = cfg.bucket_for(len(gap1))
-            arrays = self._batch_arrays(
-                gap1, b1, 1, [[r.toks[r.draft_ncache]] for r in gap1],
-                draft=True)
-            t0 = time.perf_counter()
-            dg, dpages = self._draft_fn(b1)(
-                eng._draft_params, arrays[0], arrays[1],
-                eng._draft_pool.pages, *arrays[2:])
-            eng._draft_pool.pages = dpages
-            np.asarray(dg)  # catch-up: only the cache write matters
-            if tel.enabled:
-                ms = (time.perf_counter() - t0) * 1e3
-                tel.observe("serve/draft_ms", ms)
-                tel.observe(f"serve/draft_ms.b{b1}", ms)
-            for r in gap1:
-                r.draft_ncache += 1
+            with Span("serve.arrays", cat="serve"):
+                b1 = cfg.bucket_for(len(gap1))
+                arrays = self._batch_arrays(
+                    gap1, b1, 1, [[r.toks[r.draft_ncache]] for r in gap1],
+                    draft=True)
+            with Span("serve.dispatch", cat="serve"):
+                t0 = time.perf_counter()
+                dg, dpages = self._draft_fn(b1)(
+                    eng._draft_params, arrays[0], arrays[1],
+                    eng._draft_pool.pages, *arrays[2:])
+                eng._draft_pool.pages = dpages
+            with Span("serve.fetch", cat="serve"):
+                np.asarray(dg)  # catch-up: only the cache write matters
+            with Span("serve.tokens", cat="serve"):
+                if tel.enabled:
+                    ms = (time.perf_counter() - t0) * 1e3
+                    tel.observe("serve/draft_ms", ms)
+                    tel.observe(f"serve/draft_ms.b{b1}", ms)
+                for r in gap1:
+                    r.draft_ncache += 1
         # chunked catch-up for larger gaps (post-eviction re-prefill)
+        C = cfg.prefill_chunk
         for r in group:
             while len(r.toks) - 1 - r.draft_ncache > 0:
-                gap = len(r.toks) - 1 - r.draft_ncache
-                real = min(cfg.prefill_chunk, gap)
-                chunk = r.toks[r.draft_ncache:r.draft_ncache + real] \
-                    + [0] * (cfg.prefill_chunk - real)
-                qpos = (r.draft_ncache
-                        + np.arange(cfg.prefill_chunk, dtype=np.int32))[None]
-                dtable = eng._draft_pool.block_table(
-                    r.id, eng._table_width)[None]
-                dlens = np.asarray([r.draft_ncache + real], np.int32)
-                t0 = time.perf_counter()
-                dg, dpages = self._get_prefill_fn(draft=True)(
-                    eng._draft_params,
-                    jnp.asarray(np.asarray(chunk, np.int32)[None]),
-                    jnp.asarray(qpos), eng._draft_pool.pages,
-                    jnp.asarray(dtable), jnp.asarray(dlens),
-                    jnp.zeros((1,), jnp.int32))
-                eng._draft_pool.pages = dpages
-                np.asarray(dg)
-                if tel.enabled:
-                    tel.observe(
-                        f"serve/draft_prefill_ms.c{cfg.prefill_chunk}",
-                        (time.perf_counter() - t0) * 1e3)
-                r.draft_ncache += real
-        bucket = cfg.bucket_for(len(group))
-        # phase 1: k sequential draft steps propose greedily (each step
-        # timed into the serve/draft_ms.b<N> hist its serve.draft.b<N>
-        # entry owns, so the draft's decode-step MFU is attributed like
-        # the target's)
+                with Span("serve.arrays", cat="serve"):
+                    gap = len(r.toks) - 1 - r.draft_ncache
+                    real = min(C, gap)
+                    chunk = r.toks[r.draft_ncache:r.draft_ncache + real] \
+                        + [0] * (C - real)
+                    qpos = (r.draft_ncache
+                            + np.arange(C, dtype=np.int32))[None]
+                    dtable = eng._draft_pool.block_table(
+                        r.id, eng._table_width)[None]
+                    dlens = np.asarray([r.draft_ncache + real], np.int32)
+                    t0 = time.perf_counter()
+                    args = (jnp.asarray(np.asarray(chunk, np.int32)[None]),
+                            jnp.asarray(qpos), jnp.asarray(dtable),
+                            jnp.asarray(dlens), jnp.zeros((1,), jnp.int32))
+                with Span("serve.dispatch", cat="serve"):
+                    dg, dpages = self._get_prefill_fn(draft=True)(
+                        eng._draft_params, args[0], args[1],
+                        eng._draft_pool.pages, *args[2:])
+                    eng._draft_pool.pages = dpages
+                with Span("serve.fetch", cat="serve"):
+                    np.asarray(dg)
+                with Span("serve.tokens", cat="serve"):
+                    if tel.enabled:
+                        tel.observe(f"serve/draft_prefill_ms.c{C}",
+                                    (time.perf_counter() - t0) * 1e3)
+                    r.draft_ncache += real
+        # k sequential draft steps propose greedily (each step timed into
+        # the serve/draft_ms.b<N> hist its serve.draft.b<N> entry owns, so
+        # the draft's decode-step MFU is attributed like the target's)
         proposals = [[] for _ in group]
         feed = [[r.toks[-1]] for r in group]
-        for _ in range(k):
-            arrays = self._batch_arrays(group, bucket, 1, feed, draft=True)
-            t0 = time.perf_counter()
-            dg, dpages = self._draft_fn(bucket)(
-                eng._draft_params, arrays[0], arrays[1],
-                eng._draft_pool.pages, *arrays[2:])
-            eng._draft_pool.pages = dpages
-            dg_np = np.asarray(dg)
-            if tel.enabled:
-                ms = (time.perf_counter() - t0) * 1e3
-                tel.observe("serve/draft_ms", ms)
-                tel.observe(f"serve/draft_ms.b{bucket}", ms)
-            for i, r in enumerate(group):
-                r.draft_ncache += 1
-                proposals[i].append(int(dg_np[i, 0]))
-            feed = [[p[-1]] for p in proposals]
-        # phase 2: one batched (k+1)-token target verification
-        arrays = self._batch_arrays(
-            group, bucket, k + 1,
-            [[r.toks[-1]] + proposals[i] for i, r in enumerate(group)])
-        t0 = time.perf_counter()
-        g, pages = self._verify_fn(bucket)(eng._params, arrays[0],
-                                           arrays[1], eng._pool.pages,
-                                           *arrays[2:])
-        eng._pool.pages = pages
-        g_np = np.asarray(g)
-        ms = (time.perf_counter() - t0) * 1e3
-        for r in group:  # sampled traces: one spec round = one decode slice
-            r.trace_event(f"decode.spec.b{bucket}", dur_s=ms / 1e3)
-        if tel.enabled:
-            tel.counter("serve/decode_steps")
-            tel.observe("serve/verify_ms", ms)
-            tel.observe(f"serve/verify_ms.b{bucket}", ms)
-            tel.observe("serve/batch_occupancy", len(group) / bucket)
-        # phase 3: accept the longest matching prefix + the correction
-        round_accepted = 0
-        for i, r in enumerate(group):
-            len_old = len(r.toks)
-            a = 0
-            while a < k and proposals[i][a] == int(g_np[i, a]):
-                a += 1
-            new_toks = proposals[i][:a] + [int(g_np[i, a])]
-            for t in new_toks:
-                if not self._append_token(r, t):
-                    break
-            # target cache advanced over the pending token + a accepted
-            # proposals; rejected entries are overwritten when their
-            # positions are legitimately re-fed (and masked until then)
-            r.ncache = min(r.ncache + 1 + a, len(r.toks) - 1)
-            # draft entries beyond the accepted prefix are rolled back
-            # the same way (a == k leaves the draft one token behind —
-            # next round's catch-up chunk covers it)
-            r.draft_ncache = min(len_old + min(a, k - 1), r.draft_ncache)
-            self._spec_proposed += k
-            self._spec_accepted += a
-            round_accepted += a
-        if tel.enabled:
-            tel.counter("serve/spec_proposed", k * len(group))
-            tel.counter("serve/spec_accepted", round_accepted)
-            tel.gauge("serve/spec_accept_rate",
-                      self._spec_accepted / max(self._spec_proposed, 1))
+        for _ in range(cfg.spec_k):
+            with Span("serve.arrays", cat="serve"):
+                arrays = self._batch_arrays(group, bucket, 1, feed,
+                                            draft=True)
+            with Span("serve.dispatch", cat="serve"):
+                t0 = time.perf_counter()
+                dg, dpages = self._draft_fn(bucket)(
+                    eng._draft_params, arrays[0], arrays[1],
+                    eng._draft_pool.pages, *arrays[2:])
+                eng._draft_pool.pages = dpages
+            with Span("serve.fetch", cat="serve"):
+                dg_np = np.asarray(dg)
+            with Span("serve.tokens", cat="serve"):
+                if tel.enabled:
+                    ms = (time.perf_counter() - t0) * 1e3
+                    tel.observe("serve/draft_ms", ms)
+                    tel.observe(f"serve/draft_ms.b{bucket}", ms)
+                for i, r in enumerate(group):
+                    r.draft_ncache += 1
+                    proposals[i].append(int(dg_np[i, 0]))
+                feed = [[p[-1]] for p in proposals]
+        return proposals
 
+    def _spec_verify(self, group: List[GenRequest], bucket: int,
+                     proposals: List[List[int]]) -> None:
+        """The target's pass of a speculative round: one batched
+        (k+1)-token verification, then the longest matching prefix of
+        every sequence's proposals and the target's correction."""
+        eng = self._engine
+        tel = get_telemetry()
+        k = eng.config.spec_k
+        with Span("serve.arrays", cat="serve"):
+            arrays = self._batch_arrays(
+                group, bucket, k + 1,
+                [[r.toks[-1]] + proposals[i] for i, r in enumerate(group)])
+        with Span("serve.dispatch", cat="serve"):
+            t0 = time.perf_counter()
+            g, pages = self._verify_fn(bucket)(eng._params, arrays[0],
+                                               arrays[1], eng._pool.pages,
+                                               *arrays[2:])
+            eng._pool.pages = pages
+        with Span("serve.fetch", cat="serve"):
+            g_np = np.asarray(g)
+            ms = (time.perf_counter() - t0) * 1e3
+        with Span("serve.tokens", cat="serve"):
+            for r in group:  # sampled traces: one spec round = one slice
+                r.trace_event(f"decode.spec.b{bucket}", dur_s=ms / 1e3)
+            if tel.enabled:
+                tel.counter("serve/decode_steps")
+                tel.observe("serve/verify_ms", ms)
+                tel.observe(f"serve/verify_ms.b{bucket}", ms)
+                tel.observe("serve/batch_occupancy", len(group) / bucket)
+            round_accepted = 0
+            for i, r in enumerate(group):
+                len_old = len(r.toks)
+                a = 0
+                while a < k and proposals[i][a] == int(g_np[i, a]):
+                    a += 1
+                new_toks = proposals[i][:a] + [int(g_np[i, a])]
+                for t in new_toks:
+                    if not self._append_token(r, t):
+                        break
+                # target cache advanced over the pending token + a
+                # accepted proposals; rejected entries are overwritten
+                # when their positions are legitimately re-fed (and masked
+                # until then)
+                r.ncache = min(r.ncache + 1 + a, len(r.toks) - 1)
+                # draft entries beyond the accepted prefix are rolled back
+                # the same way (a == k leaves the draft one token behind —
+                # next round's catch-up chunk covers it)
+                r.draft_ncache = min(len_old + min(a, k - 1),
+                                     r.draft_ncache)
+                self._spec_proposed += k
+                self._spec_accepted += a
+                round_accepted += a
+            if tel.enabled:
+                tel.counter("serve/spec_proposed", k * len(group))
+                tel.counter("serve/spec_accepted", round_accepted)
+                tel.gauge("serve/spec_accept_rate",
+                          self._spec_accepted / max(self._spec_proposed, 1))
 
 def dense_greedy_reference(model, prompt: Sequence[int], max_new: int,
                            eos_id: Optional[int] = None) -> List[int]:

@@ -18,7 +18,12 @@ writes, joined against the compiled HLO already held by
   under each ``jax.named_scope`` of the compiled step,
   ``hlo_attrib.SCOPES``) — merged into every ``to_jsonl`` record
   as a top-level ``"profile"`` object and into the chrome export as
-  device-op slices realigned with the PR 5 host spans,
+  device-op slices on the host spans' clock (placed by a ``pt.*``
+  span that both the trace and the span records hold); the report's
+  ``idle_by_span_ms`` books the window's idle device time to the
+  innermost ``pt.*`` span open on the host at each moment (``in_step``
+  inside a compiled run, ``outside_program_spans`` under none), also in
+  ``/debug/profile``,
 - ``gauge/bottleneck/<entry>`` verdicts (via ``profiler.bottleneck``).
 
 Step boundaries are hooked where the engines already heartbeat:
@@ -62,6 +67,7 @@ __all__ = [
     "request_capture", "step_boundary", "capture_state", "last_report",
     "configure", "reset", "publish", "jsonl_payload", "chrome_events",
     "acquire_device_trace", "release_device_trace", "device_trace_owner",
+    "clock_offset_us",
 ]
 
 logger = logging.getLogger("paddle_tpu.profiler")
@@ -111,14 +117,15 @@ def device_trace_owner() -> Optional[str]:
 # -- capture state machine ----------------------------------------------------
 
 class _Capture:
-    __slots__ = ("steps_total", "logdir", "cleanup", "t_start",
+    __slots__ = ("steps_total", "logdir", "cleanup", "t_begin", "t_start",
                  "trigger_entry", "trigger_seen", "entry_steps", "started")
 
     def __init__(self, steps_total: int, logdir: str, cleanup: bool):
         self.steps_total = max(int(steps_total), 1)
         self.logdir = logdir
         self.cleanup = cleanup
-        self.t_start = 0.0
+        self.t_begin = 0.0  # just before the trace was asked to start
+        self.t_start = 0.0  # once it had started: the wall's origin
         self.trigger_entry: Optional[str] = None
         self.trigger_seen = 0
         self.entry_steps: Dict[str, int] = {}
@@ -283,7 +290,14 @@ def _start_locked(entry: str) -> None:
 
         os.makedirs(cap.logdir, exist_ok=True)
         _drain_devices()
-        jax.profiler.start_trace(cap.logdir)
+        # no Python tracer: it records every Python call and so slows the
+        # host's own code about fourfold, and the idle time the report
+        # books to the program's spans would be the tracer's; nothing here
+        # reads those events
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        cap.t_begin = time.perf_counter()
+        jax.profiler.start_trace(cap.logdir, profiler_options=options)
     except Exception as e:  # noqa: BLE001 — profiling never kills a run
         release_device_trace("device_profile")
         get_telemetry().counter("profile/capture_failed")
@@ -358,16 +372,25 @@ def _finish_capture(cap: _Capture, wall_ms: float, tel) -> None:
     steps = {e: n * xla_cost.cost_registry().steps_per_call(e)
              for e, n in cap.entry_steps.items()}
     texts = xla_cost.hlo_texts()
+    # the trace's clock against the spans' (perf_counter): the capture's
+    # wall placed on the trace, where a span both records hold says how
+    offset = clock_offset_us(trace, cap.t_begin * 1e6,
+                             (cap.t_start + wall_ms / 1e3) * 1e6)
+    window = None
+    if offset is not None:
+        lo = cap.t_start * 1e6 - offset
+        window = (lo, lo + wall_ms * 1e3)
     report = hlo_attrib.attribute_trace(
         trace, texts, steps=steps, wall_ms=wall_ms,
         trigger_entry=cap.trigger_entry,
-        default_steps=max(steps.get(cap.trigger_entry or "", 1), 1))
+        default_steps=max(steps.get(cap.trigger_entry or "", 1), 1),
+        window_us=window)
     if report is None:
         tel.counter("profile/capture_failed")
         return
     tel.counter("profile/captures")
     _last_report = report.to_dict(top_k=_top_k)
-    _last_chrome = _chrome_from_trace(trace, cap, report)
+    _last_chrome = _chrome_from_trace(trace, report, offset)
     publish(tel)
     try:
         # join the per-op device ms against the compiled-HLO collective
@@ -397,31 +420,79 @@ def _finish_capture(cap: _Capture, wall_ms: float, tel) -> None:
         report.device_total_ms, report.host_gap_ms)
 
 
-def _chrome_from_trace(trace: dict, cap: _Capture,
-                       report, max_events: int = 512) -> list:
-    """Device-op slices for the chrome export, realigned onto the host
-    perf_counter epoch the PR 5 spans use (trace timestamps live on
-    XLA's own clock): the earliest device event maps to the capture's
-    start boundary. Top-N by duration, bounded."""
+def clock_offset_us(trace: dict, since_us: float,
+                    until_us: float) -> Optional[float]:
+    """The spans' clock (``time.perf_counter`` in µs) less the trace's,
+    from one ``pt.*`` span that both the trace and this process's own
+    records (the window store, the flight recorder) hold, matched by name
+    and step; only records opened in [``since_us``, ``until_us``] are
+    taken. A span the trace and the records each hold once with its own
+    step (``serve.iter``'s batch index) names itself; else the trace's
+    first span is the first of its name the process opened after the
+    trace was asked to start. None where no span matches."""
+    from . import spans as _spans
+
+    traced = sorted(hlo_attrib.program_spans(trace), key=lambda s: s["ts"])
+    if not traced:
+        return None
+    opened: Dict[int, tuple] = {}  # span id -> (name, step, ts_us)
+    for name, _cat, ts, _dur, _tid, sid, _par, step in \
+            _spans.window_store().snapshot():
+        opened[sid] = ("pt." + name, step, ts)
+    for ev in _spans.flight_recorder().tail():
+        if ev[0] == "B":
+            opened[ev[6]] = ("pt." + ev[1], ev[8], ev[3])
+    host: Dict[str, list] = {}
+    for name, step, ts in opened.values():
+        if since_us <= ts <= until_us:
+            host.setdefault(name, []).append((ts, step))
+    for recs in host.values():
+        recs.sort()
+
+    def key(s):
+        return s["name"], s["step"]
+
+    count: Dict[tuple, int] = {}
+    for s in traced:
+        count[key(s)] = count.get(key(s), 0) + 1
+    for s in traced:
+        if s["step"] is None or count[key(s)] != 1:
+            continue
+        match = [ts for ts, step in host.get(s["name"], ())
+                 if step == s["step"]]
+        if len(match) == 1:
+            return match[0] - s["ts"]
+    first = traced[0]
+    match = [ts for ts, step in host.get(first["name"], ())
+             if first["step"] is None or step == first["step"]]
+    return match[0] - first["ts"] if match else None
+
+
+def _chrome_from_trace(trace: dict, report, offset_us: Optional[float],
+                       max_events: int = 512) -> list:
+    """Device-op slices for the chrome export, on the perf_counter clock
+    the host spans use: the trace's own times moved by ``offset_us``
+    (``clock_offset_us``). Top-N by duration, bounded. None where no span
+    placed the two clocks: a slice put by a guess would sit beside the
+    wrong host span."""
+    if offset_us is None:
+        logger.info("device_profile: no pt.* span in both the trace and "
+                    "the span records — device slices left out of the "
+                    "chrome export")
+        return []
     events = hlo_attrib.device_events(
         trace, known_names=set().union(
             *(set(a.by_op) for a in report.entries.values())) or None)
     events = sorted(events, key=lambda e: -e.get("dur", 0))[:max_events]
-    if not events:
-        return []
     from .spans import rank_pid
 
-    t0 = min(e.get("ts", 0) for e in events)
-    base_us = cap.t_start * 1e6
     pid = rank_pid()  # rank-scoped like every chrome export (merge-safe)
-    out = []
-    for e in events:
-        out.append({"name": e.get("name", "?"), "ph": "X",
-                    "ts": base_us + (e.get("ts", 0) - t0),
-                    "dur": e.get("dur", 0), "pid": pid,
-                    "tid": "device ops", "cat": "device",
-                    "args": {"entry": report.dominant_entry}})
-    return out
+    return [{"name": e.get("name", "?"), "ph": "X",
+             "ts": e.get("ts", 0) + offset_us,
+             "dur": e.get("dur", 0), "pid": pid,
+             "tid": "device ops", "cat": "device",
+             "args": {"entry": report.dominant_entry}}
+            for e in events]
 
 
 def publish(telemetry=None) -> Dict[str, dict]:

@@ -25,8 +25,10 @@ per-op and per-source-line tables, per-category totals (compute /
 collective / h2d-d2h transfer), per-scope totals (the ``jax.named_scope``
 names of the compiled step, :data:`SCOPES`, read from each instruction's
 ``op_name``), the host gap (wall time the device sat
-idle inside the window), and per-entry fractions whose sum is ≤ 1 by
-construction. ``device_profile`` drives it live; the CLI wrapper keeps
+idle inside the window: the wall less the union of the operations), that
+idle time booked to the program's own ``pt.*`` span that held the host
+at each moment (``idle_by_span_ms``), and per-entry fractions whose sum
+is ≤ 1 by construction. ``device_profile`` drives it live; the CLI wrapper keeps
 the old script's interface for post-hoc use.
 
 Failure contract: parsing is **best-effort** — a malformed / empty /
@@ -38,6 +40,7 @@ from __future__ import annotations
 import dataclasses
 import glob
 import gzip
+import heapq
 import json
 import logging
 import os
@@ -53,6 +56,7 @@ __all__ = [
     "AttributionReport", "EntryAttribution", "attribute_trace",
     "load_trace", "newest_trace_path", "device_events",
     "HloRegistry", "hlo_registry", "CATEGORIES", "SCOPES", "scope_of",
+    "program_spans", "module_runs", "idle_by_span",
 ]
 
 logger = logging.getLogger("paddle_tpu.profiler")
@@ -66,6 +70,12 @@ CATEGORIES = ("compute", "collective", "transfer")
 # time under none of them is booked to ``unscoped``
 SCOPES = ("embed", "self_attn", "attention", "mlp", "head_loss", "optimizer")
 UNSCOPED = "unscoped"
+# the program's own host spans in a trace (``spans.Span`` opens them), and
+# the two names ``idle_by_span_ms`` books the device's idle time to where
+# no span of the host holds it
+SPAN_PREFIX = "pt."
+IN_STEP = "in_step"
+OUTSIDE_SPANS = "outside_program_spans"
 # what JAX wraps around a scope's name when it transforms the function
 _TRANSFORMS = frozenset(("jvp", "transpose", "vmap", "checkpoint"))
 
@@ -185,12 +195,16 @@ def _load_xplane(path: str) -> dict:
     every operation. An operation is named by its HLO instruction: the
     event's ``hlo_op`` stat where the runtime sets it (XLA:CPU's thunk
     events), else the head of the HLO line a TPU op event is named by
-    (``%fusion.5 = bf16[...] fusion(...)``). Of the host's planes only
-    operations are kept: the Python tracer's events join nothing."""
+    (``%fusion.5 = bf16[...] fusion(...)``). Of the host's planes the
+    operations are kept, and the program's own spans (``pt.*``, opened
+    by ``spans.Span`` only) in a list of their own, ``programSpans``:
+    they are never device events. The Python tracer's events join
+    nothing."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
     events: List[dict] = []
+    spans: List[dict] = []
     for pid, plane in enumerate(data.planes, start=1):
         on_device = plane.name.startswith("/device:")
         events.append({"ph": "M", "name": "process_name", "pid": pid,
@@ -202,6 +216,12 @@ def _load_xplane(path: str) -> dict:
                 if not on_device and e.name.startswith("$"):
                     continue  # the Python tracer's frames, the bulk
                 stats = dict(e.stats)
+                if not on_device and e.name.startswith(SPAN_PREFIX):
+                    spans.append({"name": e.name, "ts": e.start_ns / 1e3,
+                                  "dur": e.duration_ns / 1e3,
+                                  "pid": pid, "tid": tid,
+                                  "step": stats.get("step")})
+                    continue
                 op = stats.get("hlo_op")
                 if op is None:
                     if not on_device:
@@ -209,12 +229,13 @@ def _load_xplane(path: str) -> dict:
                     m = _HLO_LINE_NAME.match(e.name)
                     op = m.group(1) if m else e.name
                 args = {"hlo_op": str(op)}
-                if "hlo_module" in stats:
-                    args["hlo_module"] = str(stats["hlo_module"])
+                for key in ("hlo_module", "run_id"):
+                    if key in stats:
+                        args[key] = str(stats[key])
                 events.append({"ph": "X", "name": str(op), "pid": pid,
                                "tid": tid, "ts": e.start_ns / 1e3,
                                "dur": e.duration_ns / 1e3, "args": args})
-    return {"traceEvents": events}
+    return {"traceEvents": events, "programSpans": spans}
 
 
 def load_trace(path_or_logdir: str) -> Optional[dict]:
@@ -281,6 +302,137 @@ def device_events(trace: dict,
         elif "hlo_op" in (e.get("args") or {}) or (
                 known_names and e.get("name") in known_names):
             out.append(e)
+    return out
+
+
+def program_spans(trace: dict) -> List[dict]:
+    """The program's own ``pt.*`` spans of a trace, ``{"name", "ts",
+    "dur", "pid", "tid", "step"}`` in µs on the trace's clock: the list an
+    ``.xplane.pb`` was read into, or a JSON trace's complete events of
+    that name."""
+    if "programSpans" in trace:
+        return list(trace["programSpans"])
+    return [{"name": e["name"], "ts": e.get("ts", 0), "dur": e.get("dur", 0),
+             "pid": e.get("pid"), "tid": e.get("tid"),
+             "step": (e.get("args") or {}).get("step")}
+            for e in trace.get("traceEvents") or []
+            if e.get("ph") == "X"
+            and str(e.get("name", "")).startswith(SPAN_PREFIX)]
+
+
+def module_runs(trace: dict, events: List[dict]) -> List[Tuple[float, float]]:
+    """The compiled programs' runs, ``(start, end)`` in µs: the events of
+    a device's ``XLA Modules`` lane (the TPU layout), else the operations
+    grouped by the run they carry (``hlo_module`` and ``run_id``, XLA:CPU's
+    stats)."""
+    procs, lanes = {}, set()
+    for e in trace.get("traceEvents") or []:
+        if e.get("ph") != "M":
+            continue
+        if e.get("name") == "process_name":
+            procs[e["pid"]] = str(e.get("args", {}).get("name", ""))
+        elif (e.get("name") == "thread_name"
+              and e.get("args", {}).get("name") == "XLA Modules"):
+            lanes.add((e["pid"], e.get("tid")))
+    runs = [(e["ts"], e["ts"] + e.get("dur", 0))
+            for e in trace.get("traceEvents") or []
+            if e.get("ph") == "X" and (e.get("pid"), e.get("tid")) in lanes
+            and "/device" in procs.get(e.get("pid"), "").lower()]
+    if runs:
+        return sorted(runs)
+    by_run: Dict[tuple, List[float]] = {}
+    for e in events:
+        args = e.get("args") or {}
+        if "run_id" not in args:
+            continue
+        key = (e.get("pid"), args.get("hlo_module"), args["run_id"])
+        s, t = e.get("ts", 0), e.get("ts", 0) + e.get("dur", 0)
+        lo_hi = by_run.setdefault(key, [s, t])
+        lo_hi[0], lo_hi[1] = min(lo_hi[0], s), max(lo_hi[1], t)
+    return sorted((lo, hi) for lo, hi in by_run.values())
+
+
+def union(intervals, lo: float = float("-inf"),
+          hi: float = float("inf")) -> List[List[float]]:
+    """The merged parts of ``intervals`` that lie inside [lo, hi]."""
+    merged: List[List[float]] = []
+    for s, e in sorted((max(s, lo), min(e, hi)) for s, e in intervals):
+        if e <= s:
+            continue
+        if merged and s <= merged[-1][1]:
+            merged[-1][1] = max(merged[-1][1], e)
+        else:
+            merged.append([s, e])
+    return merged
+
+
+def _complement(merged, lo: float, hi: float) -> List[Tuple[float, float]]:
+    edges = [lo] + [x for iv in merged for x in iv] + [hi]
+    return [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
+
+
+def _innermost(spans: List[dict]) -> List[Tuple[float, float, str]]:
+    """The stretches where some span is open, cut where any span opens or
+    closes, each named by the innermost span open over it (the one
+    opened last)."""
+    ivs = sorted((s["ts"], s["ts"] + s["dur"], s["name"]) for s in spans
+                 if s.get("dur", 0) > 0)
+    points = sorted({p for s, e, _ in ivs for p in (s, e)})
+    out: List[Tuple[float, float, str]] = []
+    heap: list = []  # (-start, end, name): the latest opened on top
+    i = 0
+    for a, b in zip(points, points[1:]):
+        while i < len(ivs) and ivs[i][0] <= a:
+            heapq.heappush(heap, (-ivs[i][0], ivs[i][1], ivs[i][2]))
+            i += 1
+        while heap and heap[0][1] <= a:
+            heapq.heappop(heap)  # closed: dropped once it is on top
+        if not heap:
+            continue
+        name = heap[0][2]
+        if out and out[-1][2] == name and out[-1][1] == a:
+            out[-1] = (out[-1][0], b, name)
+        else:
+            out.append((a, b, name))
+    return out
+
+
+def _label(pieces, segments, default) -> List[Tuple[float, float, str]]:
+    """``pieces`` (sorted, disjoint) cut by ``segments`` (sorted, disjoint
+    ``(start, end, name)``): each part named by the segment over it, or
+    ``default`` where none is."""
+    out, j = [], 0
+    for a, b in pieces:
+        while j < len(segments) and segments[j][1] <= a:
+            j += 1
+        t, k = a, j
+        while k < len(segments) and segments[k][0] < b:
+            s, e = max(segments[k][0], a), min(segments[k][1], b)
+            if s > t:
+                out.append((t, s, default))
+            out.append((s, e, segments[k][2]))
+            t, k = e, k + 1
+        if b > t:
+            out.append((t, b, default))
+    return out
+
+
+def idle_by_span(busy, runs, spans: List[dict], lo: float,
+                 hi: float) -> Dict[str, float]:
+    """Every idle stretch of [lo, hi] (no interval of ``busy`` open) in
+    ms: the part inside a compiled run (``runs``) goes to :data:`IN_STEP`,
+    the rest, piece by piece, to the innermost span of ``spans`` open on
+    the host over it, or to :data:`OUTSIDE_SPANS`. All in µs on one
+    clock; the values sum to the idle time of [lo, hi]."""
+    idle = _complement(union(busy, lo, hi), lo, hi)
+    parts = _label(idle, [(s, e, IN_STEP) for s, e in union(runs, lo, hi)],
+                   None)
+    host = [(a, b) for a, b, name in parts if name is None]
+    parts = [p for p in parts if p[2] is not None] + _label(
+        host, _innermost(spans), OUTSIDE_SPANS)
+    out: Dict[str, float] = {}
+    for a, b, name in parts:
+        out[name] = out.get(name, 0.0) + (b - a) / 1e3
     return out
 
 
@@ -356,6 +508,12 @@ class AttributionReport:
     unattributed_ms: float = 0.0
     steps: Dict[str, int] = dataclasses.field(default_factory=dict)
     trigger_entry: Optional[str] = None
+    # the union of the device operations' intervals (None: the summed
+    # time stands in), and the window's idle time booked to the host span
+    # that held the device (``idle_by_span``)
+    device_busy_ms: Optional[float] = None
+    idle_by_span_ms: Dict[str, float] = dataclasses.field(
+        default_factory=dict)
 
     @property
     def dominant_entry(self) -> Optional[str]:
@@ -365,9 +523,14 @@ class AttributionReport:
 
     @property
     def host_gap_ms(self) -> float:
+        """Wall time in which no device operation ran: the wall less the
+        union of the operations' intervals, so that operations which
+        overlap (parallel thunks, concurrent streams) count once."""
         if self.wall_ms <= 0:
             return 0.0
-        return max(self.wall_ms - self.device_total_ms, 0.0)
+        busy = (self.device_total_ms if self.device_busy_ms is None
+                else self.device_busy_ms)
+        return max(self.wall_ms - busy, 0.0)
 
     def _scale(self) -> float:
         """Device-time → wall-fraction normalizer. When device lanes
@@ -419,6 +582,8 @@ class AttributionReport:
             "wall_ms": round(self.wall_ms, 6),
             "device_total_ms": round(self.device_total_ms, 6),
             "host_gap_ms": round(self.host_gap_ms, 6),
+            "idle_by_span_ms": {k: round(v, 6) for k, v in sorted(
+                self.idle_by_span_ms.items(), key=lambda kv: -kv[1])},
             "unattributed_ms": round(self.unattributed_ms, 6),
             "trigger_entry": self.trigger_entry,
             "dominant_entry": self.dominant_entry,
@@ -446,7 +611,9 @@ def attribute_trace(trace: dict, hlo_by_entry: Dict[str, str],
                     steps: Optional[Dict[str, int]] = None,
                     wall_ms: float = 0.0,
                     trigger_entry: Optional[str] = None,
-                    default_steps: int = 1) -> Optional[AttributionReport]:
+                    default_steps: int = 1,
+                    window_us: Optional[Tuple[float, float]] = None
+                    ) -> Optional[AttributionReport]:
     """Join one trace with per-entry HLO texts.
 
     ``steps`` maps entry → step-boundary count inside the window (the
@@ -456,6 +623,14 @@ def attribute_trace(trace: dict, hlo_by_entry: Dict[str, str],
     rows (TPU lanes carry runtime ops the HLO never names). Returns
     ``None`` (warning logged) when the trace yields no device events —
     an empty window is a capture problem, not a 0-of-everything report.
+
+    ``window_us`` is the capture's window on the trace's clock (the
+    caller places it by a span both clocks hold); without it the window
+    runs from the first operation's start to the last one's end. Its idle
+    stretches are booked by ``idle_by_span`` into ``idle_by_span_ms``, and
+    what the window leaves of ``host_gap_ms`` goes to
+    :data:`OUTSIDE_SPANS`: the values sum to ``host_gap_ms`` wherever the
+    wall holds the window.
     """
     if trace is None:
         return None
@@ -512,6 +687,18 @@ def attribute_trace(trace: dict, hlo_by_entry: Dict[str, str],
             _att(dominant).add(f"<unattributed:{stem}>", "?", "?", cat,
                                dur_ms)
             report.unattributed_ms += dur_ms
+    busy = [(e.get("ts", 0), e.get("ts", 0) + e.get("dur", 0))
+            for e in events]
+    report.device_busy_ms = sum(b - a for a, b in union(busy)) / 1e3
+    if report.wall_ms > 0:
+        lo, hi = window_us or (min(a for a, _ in busy),
+                               max(b for _, b in busy))
+        idle = idle_by_span(busy, module_runs(trace, events),
+                            program_spans(trace), lo, hi)
+        rest = report.host_gap_ms - sum(idle.values())
+        if rest > 0:
+            idle[OUTSIDE_SPANS] = idle.get(OUTSIDE_SPANS, 0.0) + rest
+        report.idle_by_span_ms = idle
     return report
 
 

@@ -28,8 +28,10 @@ and carried no hierarchy. This module is the structured replacement:
 
 - **Profiler annotations** — every span also opens a
   ``jax.profiler.TraceAnnotation`` named ``pt.<span name>`` (``pt.step``,
-  ``pt.h2d``, ``pt.compute``, ``pt.compile``, the serve engines' spans
-  too), so a trace taken by ``jax.profiler`` shows what the program's
+  ``pt.h2d``, ``pt.compute``, ``pt.compile``, and the token scheduler's
+  ``pt.serve.*``: an iteration, its rounds and the host phases inside
+  them, ``inference/serving/decode.py``; the one-shot ``ServingEngine``
+  opens none), so a trace taken by ``jax.profiler`` shows what the program's
   host code was doing on the device trace's own clock, and an idle gap of
   the device can be put down to the batch's ``device_put`` or to the
   jitted call. A span constructed with a step number hands it to the
@@ -100,6 +102,8 @@ def rank_process_metadata(pid: Optional[int] = None) -> List[dict]:
 
 _ids = itertools.count(1)  # process-unique span ids (GIL-atomic next())
 _tls = threading.local()   # per-thread stack of open spans
+_clock = time.perf_counter
+_get_ident = threading.get_ident
 
 
 def _env_int(name: str, default: int) -> int:
@@ -194,9 +198,10 @@ class FlightRecorder:
 
     def record(self, phase, name, cat, ts_us, dur_us, tid, span_id,
                parent_id, step) -> None:
-        with self._lock:
-            self._ring.append((phase, name, cat, ts_us, dur_us, tid,
-                               span_id, parent_id, step))
+        # one append to a bounded deque is atomic under the GIL, and so is
+        # the copy ``tail`` makes: no lock on the path every span takes
+        self._ring.append((phase, name, cat, ts_us, dur_us, tid,
+                           span_id, parent_id, step))
 
     def tail(self, n: Optional[int] = None) -> List[tuple]:
         with self._lock:
@@ -281,51 +286,57 @@ class Span:
 
     def __init__(self, name: str, cat: str = "host",
                  step: Optional[int] = None):
+        # span_id, parent_id, tid and ts_us are set on entry, dur_us on exit
         self.name = name
         self.cat = cat
         self.step = step
-        self.span_id = None
-        self.parent_id = None
-        self.tid = None
-        self.ts_us = None
-        self.dur_us = None
         # its own step only: an inherited one would stamp every h2d and
         # compute with the number their step span already carries
         self._annotation = (TraceAnnotation("pt." + name) if step is None
                             else TraceAnnotation("pt." + name, step=step))
 
+    # the scheduler of a served model opens about a dozen spans an
+    # iteration, so both ends are written for few Python steps: the ring's
+    # append inlined, the clock read once an end, no lock taken
     def __enter__(self) -> "Span":
         st = _stack()
-        parent = st[-1] if st else None
-        self.span_id = next(_ids)
-        self.parent_id = parent.span_id if parent is not None else 0
-        if self.step is None and parent is not None:
-            self.step = parent.step
-        self.tid = threading.get_ident()
+        if st:
+            parent = st[-1]
+            self.parent_id = parent.span_id
+            if self.step is None:
+                self.step = parent.step
+        else:
+            self.parent_id = 0
+        self.span_id = sid = next(_ids)
+        self.tid = tid = _get_ident()
         st.append(self)
-        self._t0 = time.perf_counter()
-        self.ts_us = self._t0 * 1e6
-        _flight.record("B", self.name, self.cat, self.ts_us, 0.0, self.tid,
-                       self.span_id, self.parent_id, self.step)
+        self._t0 = t0 = _clock()
+        self.ts_us = t0 * 1e6
+        _flight._ring.append(("B", self.name, self.cat, self.ts_us, 0.0, tid,
+                              sid, self.parent_id, self.step))
         self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
         self._annotation.__exit__(*exc)
-        t1 = time.perf_counter()
-        self.dur_us = (t1 - self._t0) * 1e6
+        t1 = _clock()
+        self.dur_us = dur = (t1 - self._t0) * 1e6
         st = _stack()
         # tolerate a torn stack (an enclosing span leaked by an exception
         # path that bypassed __exit__): unwind to self so one bad scope
         # cannot corrupt parentage for the rest of the process
-        while st and st[-1] is not self:
+        if st and st[-1] is self:
             st.pop()
-        if st:
-            st.pop()
-        _flight.record("E", self.name, self.cat, t1 * 1e6, self.dur_us,
-                       self.tid, self.span_id, self.parent_id, self.step)
+        else:
+            while st and st[-1] is not self:
+                st.pop()
+            if st:
+                st.pop()
+        _flight._ring.append(("E", self.name, self.cat, t1 * 1e6, dur,
+                              self.tid, self.span_id, self.parent_id,
+                              self.step))
         if _window_active:
-            _window.add((self.name, self.cat, self.ts_us, self.dur_us,
+            _window.add((self.name, self.cat, self.ts_us, dur,
                          self.tid, self.span_id, self.parent_id, self.step))
         return False
 

@@ -328,6 +328,180 @@ class TestLiveCapture:
         assert device_profile.jsonl_payload() is None
 
 
+def _span_trace():
+    """A hand-made trace of two served iterations, in µs: device runs over
+    [100, 200) (operations [100, 140) and [150, 200): 10 idle inside it)
+    and [300, 380); the scheduler's ``pt.serve.*`` spans on the host.
+    Over the window [50, 450): 230 µs idle, booked below."""
+    host = [("pt.serve.iter", 40, 260, 7), ("pt.serve.dispatch", 60, 90),
+            ("pt.serve.fetch", 90, 210), ("pt.serve.tokens", 210, 240),
+            ("pt.serve.retire", 240, 260), ("pt.serve.iter", 270, 420, 8),
+            ("pt.serve.admit", 270, 290), ("pt.serve.dispatch", 290, 300),
+            ("pt.serve.fetch", 300, 400), ("pt.serve.tokens", 400, 410)]
+    ev = [{"ph": "M", "pid": 1, "name": "process_name",
+           "args": {"name": "/device:TPU:0"}},
+          {"ph": "M", "pid": 1, "tid": 1, "name": "thread_name",
+           "args": {"name": "XLA Modules"}},
+          {"ph": "M", "pid": 1, "tid": 2, "name": "thread_name",
+           "args": {"name": "XLA Ops"}},
+          {"ph": "M", "pid": 2, "name": "process_name",
+           "args": {"name": "/host:CPU"}}]
+    for s, e in ((100, 200), (300, 380)):
+        ev.append({"ph": "X", "pid": 1, "tid": 1, "name": "jit_step",
+                   "ts": s, "dur": e - s})
+    for name, s, e in (("dot.3", 100, 140), ("tanh.4", 150, 200),
+                       ("dot.3", 300, 380)):
+        ev.append({"ph": "X", "pid": 1, "tid": 2, "name": name, "ts": s,
+                   "dur": e - s})
+    for name, s, e, *step in host:
+        ev.append({"ph": "X", "pid": 2, "tid": 1, "name": name, "ts": s,
+                   "dur": e - s,
+                   "args": {"step": step[0]} if step else {}})
+    return {"traceEvents": ev}
+
+
+# idle µs of the window [50, 450) by the innermost span over it: [50, 100)
+# iter 10, dispatch 30, fetch 10; [140, 150) in the run; [200, 300) fetch
+# 10, tokens 30, retire 20, none 10, admit 20, dispatch 10; [380, 450)
+# fetch 20, tokens 10, iter 10, none 30
+SPAN_IDLE_US = {"pt.serve.iter": 20, "pt.serve.dispatch": 40,
+                "pt.serve.fetch": 40, "pt.serve.tokens": 40,
+                "pt.serve.retire": 20, "pt.serve.admit": 20,
+                hlo_attrib.IN_STEP: 10, hlo_attrib.OUTSIDE_SPANS: 40}
+
+
+class TestIdleBySpan:
+    def _report(self, **kw):
+        return hlo_attrib.attribute_trace(
+            _span_trace(), {"serve.decode": _golden_hlo()},
+            steps={"serve.decode": 2}, trigger_entry="serve.decode", **kw)
+
+    def test_gaps_go_to_the_step_the_innermost_span_or_none(self):
+        rep = self._report(wall_ms=0.4, window_us=(50, 450))
+        # the spans are no device events: 170 µs of operations
+        assert rep.device_total_ms == pytest.approx(0.170)
+        assert rep.device_busy_ms == pytest.approx(0.170)
+        assert rep.host_gap_ms == pytest.approx(0.230)
+        assert rep.idle_by_span_ms == pytest.approx(
+            {k: v / 1e3 for k, v in SPAN_IDLE_US.items()})
+        assert sum(rep.idle_by_span_ms.values()) == pytest.approx(
+            rep.host_gap_ms, rel=0.01)
+        out = rep.to_dict()
+        assert out["idle_by_span_ms"]["pt.serve.dispatch"] == 0.04
+        assert list(out["idle_by_span_ms"])[0] in ("pt.serve.dispatch",
+                                                   "pt.serve.fetch",
+                                                   "pt.serve.tokens")
+
+    def test_wall_the_window_does_not_place_is_outside_every_span(self):
+        # no window: the trace's operations bound it, [100, 380): the
+        # 120 µs of the wall around them are booked to no span
+        rep = self._report(wall_ms=0.4)
+        assert rep.host_gap_ms == pytest.approx(0.230)
+        assert sum(rep.idle_by_span_ms.values()) == pytest.approx(
+            rep.host_gap_ms, rel=0.01)
+        assert rep.idle_by_span_ms[hlo_attrib.OUTSIDE_SPANS] \
+            == pytest.approx(0.010 + 0.120)
+
+    def test_host_gap_counts_overlapping_operations_once(self):
+        trace = _span_trace()
+        trace["traceEvents"].append({"ph": "X", "pid": 1, "tid": 2,
+                                     "name": "tanh.4", "ts": 160,
+                                     "dur": 20})
+        rep = hlo_attrib.attribute_trace(
+            trace, {"serve.decode": _golden_hlo()}, wall_ms=0.4,
+            window_us=(50, 450))
+        assert rep.device_total_ms == pytest.approx(0.190)
+        assert rep.host_gap_ms == pytest.approx(0.230)  # not 0.210
+
+    def test_chrome_slices_sit_on_the_spans_clock(self):
+        from paddle_tpu.profiler import spans
+
+        with spans.Span("serve.iter", step=7) as it:
+            pass
+        trace = _span_trace()
+        offset = device_profile.clock_offset_us(trace, it.ts_us - 1,
+                                                it.ts_us + 1)
+        assert offset == pytest.approx(it.ts_us - 40)
+        rep = self._report(wall_ms=0.4)
+        slices = device_profile._chrome_from_trace(trace, rep, offset)
+        assert sorted(e["ts"] - it.ts_us for e in slices) \
+            == pytest.approx([60, 110, 260])
+        # no span in both records: no slice placed by a guess
+        assert device_profile.clock_offset_us(trace, 0, 1) is None
+        assert device_profile._chrome_from_trace(trace, rep, None) == []
+
+    def test_a_span_without_a_step_matches_the_first_opened(self):
+        from paddle_tpu.profiler import spans
+
+        since = spans._clock() * 1e6
+        with spans.Span("h2d") as first:
+            pass
+        with spans.Span("h2d"):
+            pass
+        trace = {"traceEvents": [
+            {"ph": "X", "pid": 2, "tid": 1, "name": "pt.h2d", "ts": 15,
+             "dur": 1},
+            {"ph": "X", "pid": 2, "tid": 1, "name": "pt.h2d", "ts": 25,
+             "dur": 1}]}
+        assert device_profile.clock_offset_us(
+            trace, since, spans._clock() * 1e6) \
+            == pytest.approx(first.ts_us - 15)
+
+    def test_an_xplane_keeps_the_programs_spans_apart(self, tmp_path):
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.profiler import spans
+
+        f = jax.jit(lambda x: jnp.tanh(x @ x).sum())
+        x = jnp.ones((32, 32))
+        f(x).block_until_ready()
+        jax.profiler.start_trace(str(tmp_path))
+        with spans.Span("serve.iter", step=3):
+            with spans.Span("serve.fetch"):
+                f(x).block_until_ready()
+        jax.profiler.stop_trace()
+        trace = hlo_attrib.load_trace(str(tmp_path))
+        got = {(s["name"], s["step"])
+               for s in hlo_attrib.program_spans(trace)}
+        assert got == {("pt.serve.iter", 3), ("pt.serve.fetch", None)}
+        ops = hlo_attrib.device_events(trace)
+        assert ops and not [e for e in ops
+                            if e["name"].startswith(hlo_attrib.SPAN_PREFIX)]
+        assert len(hlo_attrib.module_runs(trace, ops)) == 1
+
+    def test_a_capture_runs_without_the_python_tracer(self, monkeypatch):
+        import jax
+
+        asked = []
+        start = jax.profiler.start_trace
+        monkeypatch.setattr(
+            jax.profiler, "start_trace",
+            lambda d, **kw: asked.append(kw) or start(d, **kw))
+        step, inp, lab = _tiny_step()
+        step(inp, lab)
+        assert device_profile.request_capture(steps=1)
+        for _ in range(2):
+            step(inp, lab)
+        assert device_profile.last_report() is not None
+        assert [kw["profiler_options"].python_tracer_level
+                for kw in asked] == [0]
+
+    def test_a_live_capture_books_its_idle_time(self):
+        step, inp, lab = _tiny_step()
+        step(inp, lab)
+        assert device_profile.request_capture(steps=2)
+        for _ in range(3):
+            step(inp, lab)
+        rep = device_profile.last_report()
+        idle = rep["idle_by_span_ms"]
+        assert idle and sum(idle.values()) == pytest.approx(
+            rep["host_gap_ms"], rel=0.01, abs=1e-3)
+        assert set(idle) <= {"pt.step", "pt.h2d", "pt.compute",
+                             "pt.compile", hlo_attrib.IN_STEP,
+                             hlo_attrib.OUTSIDE_SPANS}
+
+
 class TestOpsServerTrigger:
     def test_post_arms_get_reports(self):
         from paddle_tpu.profiler.ops_server import OpsServer
