@@ -11,6 +11,19 @@ prefix every token. This module serves generation natively:
   same bounded-compile scheme as the PR 7 scheduler) and at most ONE
   prefill chunk, so a newly admitted 10k-token prompt costs running
   decodes at most one chunk of latency, never a full prefill stall.
+- **One decode step in flight**: an iteration dispatches its prefill chunk
+  and its decode step before it fetches the tokens of the iteration
+  before, so the host's books, the next feed and its dispatch run while
+  the chip works (as the train loop keeps one step in flight). A step
+  takes a row's input token from a small device store that every decode
+  step and prefill chunk writes (the last token it emitted for a
+  sequence, keyed by the sequence's first page), so a token the host has
+  not fetched yet makes no round trip. The host stays the record, one
+  iteration late: a row whose budget the dispatched steps reach is not
+  fed again, a row may run one step past its ``eos_id``, and a row
+  evicted or ended while its step is in flight drops that step's token.
+  A speculative round needs the acceptance on the host before its next
+  input, so it fetches whatever is in flight first.
 - **Paged KV cache** (``kv_cache.KVCachePool``): per-sequence block
   tables over a fixed pool; blocks allocate as sequences grow and free
   at EVERY terminal transition (the engine's ``_finish`` funnel owns the
@@ -36,7 +49,11 @@ prefix every token. This module serves generation natively:
 Telemetry (schema-gated): counters ``serve/kv_blocks_{alloc,free}``,
 ``serve/decode_steps``, ``serve/prefill_chunks``, ``serve/kv_evictions``,
 ``serve/tokens_generated``, ``serve/spec_{proposed,accepted}``,
-``serve/state_resets`` (chunks that started a recurrent state again); gauges
+``serve/state_resets`` (chunks that started a recurrent state again),
+``serve/steps_overlapped`` (decode steps dispatched while the decode step
+before them was still unfetched), ``serve/pipeline_drains`` (fetches that
+left no decode step in flight: a speculative round, an empty running set,
+the drain); gauges
 ``serve/kv_occupancy`` ∈ [0,1], ``serve/kv_blocks_{total,used}``,
 ``serve/state_occupancy`` ∈ [0,1], ``serve/state_slots_{total,used}``,
 ``serve/spec_accept_rate`` ∈ [0,1], ``serve/running``; histograms
@@ -83,6 +100,10 @@ from .request import Request, RequestStatus
 __all__ = ["TokenServeConfig", "GenRequest", "TokenServingEngine",
            "DecodeScheduler", "dense_greedy_reference",
            "paged_prefill_logits"]
+
+# a feed token that the step takes from the device's token store: the one
+# the sequence's last step emitted, which the host has not fetched yet
+ON_DEVICE = -1
 
 
 class TokenServeConfig(ServeConfig):
@@ -170,6 +191,8 @@ class GenRequest(Request):
         self.generated: List[int] = []
         self.ncache = 0          # tokens whose K/V are in the target cache
         self.draft_ncache = 0    # ditto, draft cache (speculative mode)
+        # tokens steps in flight have emitted and the host has not fetched
+        self.unfetched = 0
         self.evictions = 0
         # the model's own draft of the token after the pending one
         # (self-drafting); None until a prefill has made one
@@ -179,9 +202,10 @@ class GenRequest(Request):
 
     @property
     def pending(self) -> int:
-        """Known tokens not yet in cache — 1 means decode-eligible
-        (exactly the next token to feed), >1 means (re)prefilling."""
-        return len(self.toks) - self.ncache
+        """Tokens known (on the host, or emitted by a step in flight) and
+        not yet in cache — 1 means decode-eligible (exactly the next token
+        to feed), >1 means (re)prefilling."""
+        return len(self.toks) + self.unfetched - self.ncache
 
     def ttft_ms(self) -> Optional[float]:
         if self.first_token_at is None:
@@ -218,6 +242,66 @@ class GenRequest(Request):
         return out
 
 
+class _Step:
+    """A dispatched prefill chunk or decode step whose tokens the host has
+    not fetched: ``name`` (``decode.b<N>``, ``prefill.c<C>``), ``rows``
+    (each request with its evictions at the dispatch: a row evicted since
+    drops its token), the device array ``tokens`` (a row's is column
+    ``col``, and is the request's next where ``emits``: every decode
+    step, a prefill chunk that ends its prompt), the dispatch's time
+    ``t0`` and iteration ``batch``."""
+
+    __slots__ = ("name", "rows", "tokens", "col", "emits", "t0", "batch")
+
+    def __init__(self, name, rows, tokens, col, emits, t0, batch):
+        self.name, self.rows, self.tokens = name, rows, tokens
+        self.col, self.emits, self.t0, self.batch = col, emits, t0, batch
+
+
+def greedy_step(fwd, takes_hidden: bool = False, store: bool = False):
+    """What a compiled entry of the scheduler runs: forward a chunk through
+    the cache and return the greedy token per position (argmax stays on
+    device — the D2H per step is [B, T] int32, not [B, T, V] logits).
+    ``slots`` [B] names each row's state slot (0, the scratch slot, for
+    padded rows and for models without state). An entry of the model's own
+    draft (``takes_hidden``) takes the target's hidden states, a device
+    array that never visits the host, before the tokens; a forward that
+    feeds such a draft returns them after the cache.
+
+    An entry of the pipelined rounds (``store``: a decode step, the
+    target's prefill chunk) also takes the token store last and returns it
+    after the cache: a row fed ``ON_DEVICE`` reads its token from the store
+    at its first page, and every row writes there the token of its last
+    real position (padded rows: the scratch page)."""
+    n = int(takes_hidden)
+
+    def step(params, *args):
+        # args: [hidden,] tokens, qpos, cache, tables, kv_lens, slots
+        # [, store]; out: tokens, cache[, store][, hidden]
+        if not store:
+            logits, *rest = fwd(params, *args)
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), *rest)
+        *args, tokens_of = args
+        tokens, qpos, cache, tables, lens, slots = args[n:]
+        key = tables[:, 0]
+        tokens = jnp.where(tokens < 0, tokens_of[key][:, None], tokens)
+        logits, cache, *rest = fwd(params, *args[:n], tokens, qpos, cache,
+                                   tables, lens, slots)
+        out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if out.shape[1] == 1:
+            # a decode step's one position is its last, read statically: an
+            # index from kv_lens here kept two of Falcon-H1's gathered
+            # contexts out of the v5e's fast memory (97 MB more
+            # temporaries, 2.9 ms a step)
+            emitted = out[:, 0]
+        else:
+            last = jnp.clip(lens - qpos[:, 0] - 1, 0, out.shape[1] - 1)
+            emitted = jnp.take_along_axis(out, last[:, None], axis=1)[:, 0]
+        return (out, cache, tokens_of.at[key].set(emitted), *rest)
+
+    return step
+
+
 class DecodeScheduler:
     """The decode loop — one thread owns the device and the pool.
 
@@ -225,7 +309,8 @@ class DecodeScheduler:
     waiting prompts into the running set while slots exist) → deadline
     shedding → ONE prefill chunk for the oldest prefilling sequence →
     ONE decode (or speculative) round for every decode-eligible
-    sequence → retire finished sequences. Work per iteration is bounded
+    sequence → the tokens of the steps the iteration before dispatched
+    → retire finished sequences. Work per iteration is bounded
     (≤ 1 chunk + ≤ 1 decode round), which is what makes admission unable
     to starve decodes.
     """
@@ -237,6 +322,14 @@ class DecodeScheduler:
         self._stopped = threading.Event()
         self.batch_index = 0
         self._running: List[GenRequest] = []
+        self._inflight: List[_Step] = []  # dispatched, not fetched, in order
+        self._newest: Optional[_Step] = None  # the last decode step, unfetched
+        self._fetched_at = 0.0  # when the last fetch returned
+        # the token store: the last token a step emitted for the sequence
+        # whose first page is the index (a page is one sequence's at a
+        # time); numpy to the device, no program of its own
+        self._tokens = jax.device_put(
+            np.zeros((engine._pool.config.num_blocks,), np.int32))
         self._decode_fns: Dict[int, object] = {}
         self._verify_fns: Dict[int, object] = {}
         self._draft_fns: Dict[int, object] = {}
@@ -258,41 +351,33 @@ class DecodeScheduler:
         return self._thread.is_alive()
 
     # -- compiled executables ----------------------------------------------
-    def _make_step(self, fwd, name: str, takes_hidden: bool = False):
-        """One compiled entry: forward a chunk through the cache, return
-        the greedy token per position (argmax stays on device — the D2H
-        per step is [B, T] int32, not [B, T, V] logits). The cache (arg
-        3: the pool's pages and, where the model carries recurrent state,
-        its state leaves) is donated: the pool is the largest serving
-        buffer and must never exist twice on device. ``slots`` [B] names
-        each row's state slot (0, the scratch slot, for padded rows and
-        for models without state). The jitted function is named after
-        the entry, so that a device trace tells a decode step from a
-        prefill chunk by its module's name. An entry of the model's own
-        draft (``takes_hidden``) takes the target's hidden states, a
-        device array that never visits the host, before the tokens; a
-        forward that feeds such a draft returns them after the cache."""
-        n = int(takes_hidden)
-
-        def step(params, *args):
-            # args: [hidden,] tokens, qpos, cache, tables, kv_lens, slots;
-            # out: logits, cache[, hidden]
-            logits, *rest = fwd(params, *args)
-            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), *rest)
-
+    def _make_step(self, fwd, name: str, takes_hidden: bool = False,
+                   store: bool = False):
+        """One compiled entry of ``greedy_step(fwd, takes_hidden, store)``.
+        The cache (arg 3: the pool's pages and, where the model carries
+        recurrent state, its state leaves) is donated: the pool is the
+        largest serving buffer and must never exist twice on device; so is
+        the token store. The jitted function is named after the entry, so
+        that a device trace tells a decode step from a prefill chunk by its
+        module's name."""
+        step = greedy_step(fwd, takes_hidden, store)
         step.__name__ = step.__qualname__ = name.replace(".", "_")
         # sig_argnums: hash only the drift-capable inputs (all but the
-        # params and the cache) — flattening the full params pytree per
-        # decode step would put O(leaves) host work on the token hot path
+        # params, the cache and the store) — flattening the full params
+        # pytree per decode step would put O(leaves) host work on the
+        # token hot path
+        n = int(takes_hidden)
         cache_at = 3 + n
+        donate = (cache_at, 7 + n) if store else (cache_at,)
         return tracked_jit(
-            step, name=name, donate_argnums=(cache_at,),
+            step, name=name, donate_argnums=donate,
             sig_argnums=tuple(i for i in range(1, 7 + n) if i != cache_at))
 
     def _decode_fn(self, bucket: int):
         fn = self._decode_fns.get(bucket)
         if fn is None:
-            fn = self._make_step(self._engine._fwd, f"serve.decode.b{bucket}")
+            fn = self._make_step(self._engine._fwd, f"serve.decode.b{bucket}",
+                                 store=True)
             self._decode_fns[bucket] = fn
         return fn
 
@@ -327,7 +412,7 @@ class DecodeScheduler:
         if self._prefill_fn is None:
             self._prefill_fn = self._make_step(
                 eng._fwd_hidden if eng.self_draft else eng._fwd,
-                f"serve.prefill.c{eng.config.prefill_chunk}")
+                f"serve.prefill.c{eng.config.prefill_chunk}", store=True)
         return self._prefill_fn
 
     def warmup(self) -> Dict[str, float]:
@@ -339,35 +424,39 @@ class DecodeScheduler:
         cfg = eng.config
         out: Dict[str, float] = {}
 
-        def run(label, fn, pool, B, T, fwd_params, hidden=None):
+        def run(label, fn, pool, B, T, fwd_params, hidden=None,
+                store=False):
             toks = jnp.zeros((B, T), jnp.int32)
             qpos = jnp.zeros((B, T), jnp.int32)
             tables = jnp.zeros((B, eng._table_width), jnp.int32)
             lens = jnp.zeros((B,), jnp.int32)
             before = () if hidden is None else (hidden,)
+            after = (self._tokens,) if store else ()
             t0 = time.perf_counter()
             got = fn(fwd_params, *before, toks, qpos, pool.pages, tables,
-                     lens, jnp.zeros((B,), jnp.int32))
+                     lens, jnp.zeros((B,), jnp.int32), *after)
             np.asarray(got[0])  # block: measure compile+run
             pool.pages = got[1]
+            if store:
+                self._tokens = got[2]
             out[label] = (time.perf_counter() - t0) * 1e3
             return got
 
         for b in cfg.decode_buckets:
             run(f"decode.b{b}", self._decode_fn(b), eng._pool, b, 1,
-                eng._params)
+                eng._params, store=True)
         got = run(f"prefill.c{cfg.prefill_chunk}", self._get_prefill_fn(),
-                  eng._pool, 1, cfg.prefill_chunk, eng._params)
+                  eng._pool, 1, cfg.prefill_chunk, eng._params, store=True)
         if eng.self_draft:
             # the draft's entries take the hidden states the target's give
             run(f"draft_prefill.c{cfg.prefill_chunk}",
                 self._get_prefill_fn(draft=True), eng._pool, 1,
-                cfg.prefill_chunk, eng._params, hidden=got[2])
+                cfg.prefill_chunk, eng._params, hidden=got[-1])
             for b in cfg.decode_buckets:
                 got = run(f"verify.b{b}", self._verify_fn(b), eng._pool, b,
                           cfg.spec_k + 1, eng._params)
                 run(f"draft.b{b}", self._draft_fn(b), eng._pool, b,
-                    cfg.spec_k + 1, eng._params, hidden=got[2])
+                    cfg.spec_k + 1, eng._params, hidden=got[-1])
         if eng.spec_enabled:
             for b in cfg.decode_buckets:
                 run(f"verify.b{b}", self._verify_fn(b), eng._pool, b,
@@ -398,18 +487,24 @@ class DecodeScheduler:
                     # in-flight generation may keep decoding inside the
                     # grace window (short generations finish with full
                     # text); at expiry — or once nothing is running —
-                    # everything left goes DRAINED with partial text and
-                    # every block returns to the pool
-                    if not running or time.monotonic() >= drain_deadline:
+                    # the steps in flight are fetched, what they finished
+                    # goes OK, everything left DRAINED with partial text,
+                    # and every block returns to the pool
+                    if (not running and not self._inflight) \
+                            or time.monotonic() >= drain_deadline:
+                        self._drain()
                         for r in running:
-                            self._retire(r, RequestStatus.DRAINED,
-                                         detail="drained mid-generation")
+                            if self._done_generating(r):
+                                self._retire(r, RequestStatus.OK)
+                            else:
+                                self._retire(r, RequestStatus.DRAINED,
+                                             detail="drained mid-generation")
                         running.clear()
                         for r in eng._queue.pop_all():
                             eng._finish(r, RequestStatus.DRAINED,
                                         detail="drained before prefill")
                         return
-                if not running:
+                if not running and not self._inflight:
                     # nothing in flight: the wait for a first prompt is
                     # no iteration's, and opens no span
                     self._admit(cfg.idle_poll_s)
@@ -447,7 +542,10 @@ class DecodeScheduler:
         ``serve.retire``); a round span (``serve.prefill_chunk``,
         ``serve.decode_round``, ``serve.verify_round``,
         ``serve.draft_round``) holds phases only. So an idle stretch of
-        the device in a trace is booked to the host phase that held it."""
+        the device in a trace is booked to the host phase that held it.
+        The tokens of the steps the iteration before dispatched are
+        fetched after this one's rounds have dispatched theirs, in fetch
+        and tokens phases of the iteration itself."""
         eng = self._engine
         running = self._running
         with Span("serve.admit", cat="serve"):
@@ -455,15 +553,13 @@ class DecodeScheduler:
             self._publish_gauges(get_telemetry())
             # mid-generation deadline shedding: the slot frees and the
             # partial text is discarded (stale results are never
-            # delivered as success)
+            # delivered as success; a step in flight drops its token)
             now = time.monotonic()
             for r in list(running):
                 if r.deadline is not None and now >= r.deadline:
                     self._retire(r, RequestStatus.DEADLINE_EXCEEDED,
                                  detail="deadline expired mid-generation")
                     running.remove(r)
-            if not running:
-                return
             inj = active_injector()
             if inj is not None:
                 for r in running:  # injected straggler stalls the round
@@ -472,6 +568,11 @@ class DecodeScheduler:
             decoding = [r for r in running if r.pending == 1]
         if prefilling:
             self._prefill_chunk(prefilling[0])
+        if decoding and (eng.self_draft or eng.spec_enabled):
+            # a speculative round needs the acceptance on the host before
+            # its next input: what is in flight is fetched first
+            self._drain()
+            decoding = [r for r in decoding if not self._done_generating(r)]
         if decoding:
             if eng.self_draft:
                 self._self_spec_round(decoding)
@@ -479,6 +580,7 @@ class DecodeScheduler:
                 self._spec_round(decoding)
             else:
                 self._decode_round(decoding)
+        self._fetch(sum(s.batch < self.batch_index for s in self._inflight))
         with Span("serve.retire", cat="serve"):
             for r in list(running):
                 if self._done_generating(r):
@@ -549,6 +651,52 @@ class DecodeScheduler:
         get_telemetry().counter("serve/tokens_generated")
         return True
 
+    def _dispatched(self, step: _Step) -> None:
+        """Put ``step`` in flight; a decode step dispatched while the one
+        before it is unfetched is counted overlapped."""
+        if step.name.startswith("decode"):
+            if self._newest is not None:
+                get_telemetry().counter("serve/steps_overlapped")
+            self._newest = step
+        self._inflight.append(step)
+
+    def _drain(self) -> None:
+        """Fetch every step in flight: before a round that needs the
+        tokens on the host, and at the drain."""
+        self._fetch(len(self._inflight))
+
+    def _fetch(self, n: int) -> None:
+        """Fetch the tokens of the ``n`` oldest steps in flight, in the
+        order of their dispatch, and hand each row's to the host's record
+        (``_append_token``); a row whose request has ended, or whose cache
+        was evicted, since the dispatch drops it. ``serve/decode_ms`` and
+        ``serve/prefill_ms`` time stretches of the host's clock that do not
+        overlap: a step from the later of its dispatch and the return of the
+        fetch before its own, its tokens handed to the record (the host's
+        work before that ran while the chip did), to its own tokens'
+        return. A fetch that leaves no decode step in flight is a drain."""
+        steps, self._inflight = self._inflight[:n], self._inflight[n:]
+        tel = get_telemetry()
+        for s in steps:
+            with Span("serve.fetch", cat="serve"):
+                got = np.asarray(s.tokens)
+                ms = (time.perf_counter() - max(s.t0, self._fetched_at)) * 1e3
+            with Span("serve.tokens", cat="serve"):
+                if s is self._newest:
+                    self._newest = None
+                    tel.counter("serve/pipeline_drains")
+                if tel.enabled:
+                    kind, size = s.name.split(".")
+                    tel.observe(f"serve/{kind}_ms", ms)
+                    tel.observe(f"serve/{kind}_ms.{size}", ms)
+                for (r, evictions), tok in zip(s.rows, got[:, s.col]):
+                    r.trace_event(s.name, dur_s=ms / 1e3)
+                    if s.emits and not r.done() \
+                            and r.evictions == evictions:
+                        r.unfetched -= 1
+                        self._append_token(r, int(tok))
+                self._fetched_at = time.perf_counter()
+
     def _evict(self, victim: GenRequest) -> None:
         """Recompute-style preemption: free the victim's blocks and its
         state slot; it re-enters chunked prefill over its full known
@@ -558,6 +706,7 @@ class DecodeScheduler:
         eng = self._engine
         eng._pool.release(victim.id)
         victim.ncache = 0
+        victim.unfetched = 0  # its step in flight drops its token
         victim.proposal = None  # its draft rows went with its blocks
         if eng.spec_enabled:
             eng._draft_pool.release(victim.id)
@@ -655,18 +804,24 @@ class DecodeScheduler:
             toks, qpos, table, lens, slot = jax.device_put(
                 (toks, qpos, table, lens, slot))
         with Span("serve.dispatch", cat="serve"):
-            g, pages, *hidden = self._get_prefill_fn()(
-                eng._params, toks, qpos, eng._pool.pages, table, lens, slot)
+            g, pages, self._tokens, *hidden = self._get_prefill_fn()(
+                eng._params, toks, qpos, eng._pool.pages, table, lens, slot,
+                self._tokens)
             eng._pool.pages = pages
-        with Span("serve.fetch", cat="serve"):
-            g_np = np.asarray(g)
-            ms = (time.perf_counter() - t0) * 1e3
         with Span("serve.tokens", cat="serve"):
-            r.trace_event(f"prefill.c{C}", dur_s=ms / 1e3)
+            # the chunk that covers every known token emits the first
+            # generated one (TTFT stamps where it is fetched), into the
+            # store as well: the next decode step takes it from there
+            ends = real == r.pending
+            self._dispatched(_Step(f"prefill.c{C}", [(r, r.evictions)], g,
+                                   real - 1, ends, t0, self.batch_index))
+            r.ncache += real
+            r.unfetched += ends
             if tel.enabled:
                 tel.counter("serve/prefill_chunks")
-                tel.observe("serve/prefill_ms", ms)
-                tel.observe(f"serve/prefill_ms.c{C}", ms)
+        if eng.self_draft:
+            # the model's own draft reads the emitted token on the host
+            self._drain()
         if eng.spec_enabled:
             # the draft cache follows the target's chunk schedule so
             # proposing never needs a separate prompt pass
@@ -688,13 +843,6 @@ class DecodeScheduler:
                     tel.observe(f"serve/draft_prefill_ms.c{C}",
                                 (time.perf_counter() - t0) * 1e3)
                 r.draft_ncache += real
-        with Span("serve.tokens", cat="serve"):
-            r.ncache += real
-            if r.pending == 0:
-                # the chunk covered every known token: the last position's
-                # greedy output IS the first generated token (TTFT stamps
-                # here)
-                self._append_token(r, int(g_np[0, real - 1]))
         if eng.self_draft:
             # the model's own draft follows the chunk: position i takes
             # the target's hidden state of i and the token at i + 1 (the
@@ -739,6 +887,8 @@ class DecodeScheduler:
             for r in decoding:
                 if r.pending != 1:
                     continue  # evicted by a neighbor's allocation
+                if len(r.generated) + r.unfetched >= r.max_new:
+                    continue  # the steps in flight bring its last token
                 if len(group) >= eng.config.max_running:
                     break
                 if self._ensure_blocks(r, r.ncache + 1,
@@ -748,28 +898,26 @@ class DecodeScheduler:
             return
         with Span("serve.arrays", cat="serve"):
             bucket = eng.config.bucket_for(len(group))
-            arrays = self._batch_arrays(group, bucket, 1,
-                                        [[r.toks[-1]] for r in group])
+            arrays = self._batch_arrays(
+                group, bucket, 1,
+                [[ON_DEVICE if r.unfetched else r.toks[-1]] for r in group])
         with Span("serve.dispatch", cat="serve"):
             t0 = time.perf_counter()
-            g, pages = self._decode_fn(bucket)(eng._params, arrays[0],
-                                               arrays[1], eng._pool.pages,
-                                               *arrays[2:])
+            g, pages, self._tokens = self._decode_fn(bucket)(
+                eng._params, arrays[0], arrays[1], eng._pool.pages,
+                *arrays[2:], self._tokens)
             eng._pool.pages = pages
-        with Span("serve.fetch", cat="serve"):
-            g_np = np.asarray(g)
-            ms = (time.perf_counter() - t0) * 1e3
         with Span("serve.tokens", cat="serve"):
+            self._dispatched(_Step(f"decode.b{bucket}",
+                                   [(r, r.evictions) for r in group], g, 0,
+                                   True, t0, self.batch_index))
+            for r in group:
+                r.ncache += 1
+                r.unfetched += 1
+                r.proposal = None  # no draft of the model's own followed
             if tel.enabled:
                 tel.counter("serve/decode_steps")
-                tel.observe("serve/decode_ms", ms)
-                tel.observe(f"serve/decode_ms.b{bucket}", ms)
                 tel.observe("serve/batch_occupancy", len(group) / bucket)
-            for i, r in enumerate(group):
-                r.trace_event(f"decode.b{bucket}", dur_s=ms / 1e3)
-                r.ncache += 1
-                r.proposal = None  # no draft of the model's own followed
-                self._append_token(r, int(g_np[i, 0]))
 
     # -- speculative decode from the model's own draft ----------------------
     def _self_spec_round(self, decoding: List[GenRequest]) -> None:
@@ -823,6 +971,8 @@ class DecodeScheduler:
             with Span("serve.tokens", cat="serve"):
                 if tel.enabled:
                     tel.counter("serve/decode_steps")
+                    # fetched before anything more is dispatched
+                    tel.counter("serve/pipeline_drains")
                     tel.observe("serve/verify_ms", ms)
                     tel.observe(f"serve/verify_ms.b{bucket}", ms)
                     tel.observe("serve/batch_occupancy", len(group) / bucket)
@@ -1032,6 +1182,8 @@ class DecodeScheduler:
                 r.trace_event(f"decode.spec.b{bucket}", dur_s=ms / 1e3)
             if tel.enabled:
                 tel.counter("serve/decode_steps")
+                # fetched before anything more is dispatched
+                tel.counter("serve/pipeline_drains")
                 tel.observe("serve/verify_ms", ms)
                 tel.observe(f"serve/verify_ms.b{bucket}", ms)
                 tel.observe("serve/batch_occupancy", len(group) / bucket)
@@ -1270,6 +1422,7 @@ class TokenServingEngine(ServingEngine):
         acct = super().shutdown()
         self.publish_counters()
         self._params = self._draft_params = None
+        self._scheduler._tokens = None
         for pool in (self._pool, self._draft_pool):
             if pool is not None:
                 pool.pages = None

@@ -25,7 +25,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import paddle_tpu as paddle
-from paddle_tpu.inference.serving.decode import _pool_config
+from paddle_tpu.inference.serving.decode import _pool_config, greedy_step
 from paddle_tpu.inference.serving.kv_cache import KVCachePool
 from paddle_tpu.jit.functionalize import get_params
 from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
@@ -164,15 +164,12 @@ def test_a_served_step_writes_no_whole_layer_of_the_pool(
         jax.eval_shape(lambda: KVCachePool(pool).pages))
     assert [a.shape for a in pages["k"]] == [(BLOCKS, BLOCK, WIDTH)] * LAYERS
 
-    def step(params, toks, qpos, cache, tables, kv_lens):  # decode._make_step
-        logits, cache = spec["forward_chunk"](params, toks, qpos, cache,
-                                              tables, kv_lens)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-
+    # the scheduler's own entry: the token store read and written with it
     ints = lambda *shape: shaped(shape, jnp.int32)  # noqa: E731
     compiled = compile_for_the_chip(
-        step, params, ints(rows, tokens), ints(rows, tokens), pages,
-        ints(rows, TABLE), ints(rows), donate_argnums=(3,))
+        greedy_step(spec["forward_chunk"], store=True), params,
+        ints(rows, tokens), ints(rows, tokens), pages, ints(rows, TABLE),
+        ints(rows), ints(rows), ints(BLOCKS), donate_argnums=(3, 7))
     text = compiled.as_text()
     assert "scatter" in text, "the reader would pass an empty module"
     found = pool_sized_writes(text)
@@ -228,15 +225,11 @@ def test_a_latent_step_copies_neither_its_pool_nor_its_weights(
         jax.eval_shape(lambda: KVCachePool(pool).pages))
     assert [a.shape for a in pages["latent"]] == [(blocks, BLOCK, 640)] * 2
 
-    def step(params, toks, qpos, cache, tables, kv_lens, slots):
-        logits, cache = spec["forward_chunk"](params, toks, qpos, cache,
-                                              tables, kv_lens, slots)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-
     ints = lambda *shape: shaped(shape, jnp.int32)  # noqa: E731
     compiled = compile_for_the_chip(
-        step, params, ints(rows, tokens), ints(rows, tokens), pages,
-        ints(rows, table), ints(rows), ints(rows), donate_argnums=(3,))
+        greedy_step(spec["forward_chunk"], store=True), params,
+        ints(rows, tokens), ints(rows, tokens), pages, ints(rows, table),
+        ints(rows), ints(rows), ints(blocks), donate_argnums=(3, 7))
     text = compiled.as_text()
     assert "scatter" in text, "the reader would pass an empty module"
     found = pool_sized_writes(text, blocks, blocks * BLOCK * 640)
@@ -248,3 +241,49 @@ def test_a_latent_step_copies_neither_its_pool_nor_its_weights(
     # sequence and a chunk's scores: far under one layer of the pool
     assert compiled.memory_analysis().temp_size_in_bytes \
         < blocks * BLOCK * 640 * 2
+
+
+# -- the token store beside a hybrid model's gathered contexts -------------------
+
+def test_the_token_store_leaves_the_decode_step_its_fast_memory(
+        one_chip, monkeypatch):
+    """Falcon-H1's served decode step at the configuration, pool (4,096
+    blocks of 16, a table of 96 slots, 64 state slots) and paged tier of
+    ``falcon-h1-34b.serve-closed-chat``, all nine layers (where the v5e's
+    compiler puts a buffer is the whole program's choice), with the token
+    store and without. The store is 16 KB, and so is what it may add to the
+    step's temporaries: where the step took its emitted token by an index
+    from ``kv_lens``, the compiler kept two of the 100 MB gathered contexts
+    out of its fast memory, 97 MB more temporaries and 2.9 ms more a step on
+    the chip (PERF.md section 6)."""
+    import json
+
+    from benchmark.families.falcon_h1_serve import build_model
+
+    monkeypatch.setenv("PADDLE_TPU_ATTN_PAGED_POLICY", "paged_gather")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "falcon-h1-34b.json")) as f:
+        model = build_model(json.load(f), {})  # shapes only: no weights
+    spec = model.decode_spec("bfloat16")
+    blocks, table, rows = 4096, 96, 64
+    shaped = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    ints = lambda *shape: shaped(shape, jnp.int32)  # noqa: E731
+    params = {name: shaped(p.shape, jnp.bfloat16)
+              for name, p in get_params(model).items()}
+    pool = _pool_config(spec, blocks, BLOCK, "bfloat16", rows)
+    pages = jax.tree_util.tree_map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(lambda: KVCachePool(pool).pages))
+    feed = (params, ints(rows, 1), ints(rows, 1), pages, ints(rows, table),
+            ints(rows), ints(rows))
+    plain = compile_for_the_chip(
+        greedy_step(spec["forward_chunk"]), *feed, donate_argnums=(3,))
+    stored = compile_for_the_chip(
+        greedy_step(spec["forward_chunk"], store=True), *feed, ints(blocks),
+        donate_argnums=(3, 7))
+    temps = [c.memory_analysis().temp_size_in_bytes for c in (plain, stored)]
+    assert temps[0] > 50 * 2**20, "the step gathers no context: " \
+        f"{temps}"
+    assert temps[1] - temps[0] < 2**20, temps

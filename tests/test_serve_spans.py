@@ -1,9 +1,11 @@
 """The token scheduler's own spans (``inference/serving/decode.py``): every
 iteration with work in flight opens ``serve.iter`` with its batch index,
 and inside it one phase span is open innermost at every moment, a round
-span holding phases alone. Driven through prefill chunks, decode rounds and
-an eviction, through the speculative round of a draft model and through
-the round of a model's own draft; read back from the flight recorder.
+span holding phases alone; the tokens of the steps in flight are fetched
+in phases of the iteration itself. Driven through prefill chunks, decode
+rounds and an eviction, through the speculative round of a draft model and
+through the round of a model's own draft; read back from the flight
+recorder.
 
 The tokens each run emits are written below as the scheduler gave them
 before it opened any span: the spans change no token and no compile."""
@@ -22,8 +24,10 @@ PHASES = {"serve.admit", "serve.blocks", "serve.arrays", "serve.dispatch",
 ROUNDS = {"serve.prefill_chunk", "serve.decode_round", "serve.verify_round",
           "serve.draft_round"}
 # what an iteration holds between its admission and its retirement: the
-# rounds, and a speculative round's group formed before the tail's decode
-IN_ITER = ROUNDS | {"serve.blocks"}
+# rounds, a speculative round's group formed before the tail's decode, and
+# the fetch of the steps in flight (the iteration before's, after this
+# one's rounds have dispatched; all of them before a speculative round)
+IN_ITER = ROUNDS | {"serve.blocks", "serve.fetch", "serve.tokens"}
 
 # the emitted tokens of each run below, from the scheduler as it was
 # before it opened spans
