@@ -41,7 +41,7 @@ from ..nn import initializer as I
 
 __all__ = ["top_k_gating", "moe_dispatch", "ExpertMLP", "MoELayer",
            "DroplessMoE", "route_top_k", "held_experts_part", "held_load",
-           "publish_moe_stats"]
+           "identity_experts_part", "publish_moe_stats"]
 
 
 def top_k_gating(gate_logits, top_k: int, capacity: int):
@@ -199,7 +199,8 @@ class MoELayer(nn.Layer):
 def route_top_k(scores, select_bias, top_k: int, scale: float,
                 renormalize: bool = True):
     """(chosen experts [T, k], their weights [T, k] in f32) from the
-    router's scores s = sigmoid(logits) [T, E]: the ``top_k`` largest of
+    router's scores s [T, E] (a sigmoid's or a softmax's of its logits,
+    over every column it routes to): the ``top_k`` largest of
     s + ``select_bias`` are chosen (the bias steers the choice only: it is
     moved by a load-balancing rule outside the gradient), and a chosen
     expert weighs ``scale`` * s, over the sum of the chosen s where
@@ -307,6 +308,20 @@ def held_experts_part(x, chosen, weights, w_gate, w_up, w_down, first: int):
         jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0),
         (jnp.sum(here) - pairs).astype(jnp.float32)])
     return y, jax.lax.stop_gradient(stats)
+
+
+def identity_experts_part(x, chosen, weights, first: int):
+    """What the zero-computation experts add to every token: sum over the
+    chosen experts e >= ``first`` (the router's columns past the routed
+    experts) of w_e * x, an identity expert's output being its input.
+    ``x`` [T, h]; ``chosen``, ``weights`` [T, k]. Returns (y [T, h] in f32,
+    int32 pairs routed to them). No exchange: every token's identity
+    experts are computed where the token is; ids below ``first`` (a routed
+    expert, or -1 for a position that is not there) add nothing."""
+    zero = chosen >= first
+    w = jnp.sum(jnp.where(zero, weights.astype(jnp.float32), 0.0), axis=-1)
+    return (w[:, None] * x.astype(jnp.float32),
+            jnp.sum(zero).astype(jnp.int32))
 
 
 def held_load(chosen, first: int, held: int):

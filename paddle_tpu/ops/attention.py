@@ -644,7 +644,8 @@ def _mla_form(T: int) -> str:
 
 
 def mla_paged_attention(q_nope, q_rope, w_kvb, latent_pages, block_tables,
-                        q_positions, kv_lens, v_dim: int, form: str = None):
+                        q_positions, kv_lens, v_dim: int, form: str = None,
+                        rows_per_walk: int = None):
     """Latent attention of a query chunk against a paged latent cache.
 
     Args:
@@ -658,6 +659,11 @@ def mla_paged_attention(q_nope, q_rope, w_kvb, latent_pages, block_tables,
             product slices a row off its lanes.
         block_tables, q_positions, kv_lens: as ``paged_attention``.
         form: 'mla_absorbed' | 'mla_expanded'; None: ``_mla_form``'s rule.
+        rows_per_walk: where the batch holds more rows than this, the rows
+            are sorted by ``kv_lens``, longest first, and walked in groups
+            of this many, each group only as far as its own longest row;
+            None walks every row as far as the longest. A row's result is
+            the same either way: the steps a group skips are masked whole.
 
     Returns [B, T, H, v_dim] in the queries' type. Products take their
     operands as they are stored (bfloat16 pages are never widened) and
@@ -686,7 +692,19 @@ def mla_paged_attention(q_nope, q_rope, w_kvb, latent_pages, block_tables,
     if pad:  # whole groups: the scratch page, which kv_lens masks
         block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
     group = slots * bs
-    steps = (jnp.max(kv_lens).astype(jnp.int32) + group - 1) // group
+    grouped = rows_per_walk is not None and B > rows_per_walk
+    if grouped:
+        # a walk costs its rows times its longest row's length: rows of
+        # like length walk together, and only the longest group walks far
+        order = jnp.argsort(-kv_lens)
+        q_nope, q_rope, block_tables, q_positions, kv_lens = (
+            x[order] for x in (q_nope, q_rope, block_tables, q_positions,
+                               kv_lens))
+
+    def reach(lens):  # the groups of slots that the longest row fills
+        return (jnp.max(lens).astype(jnp.int32) + group - 1) // group
+
+    steps = reach(kv_lens)
     scale = 1.0 / math.sqrt(nope + rope)
     w = w_kvb.reshape(latent, H, nope + v_dim)
     f32 = jnp.float32
@@ -695,8 +713,10 @@ def mla_paged_attention(q_nope, q_rope, w_kvb, latent_pages, block_tables,
                            preferred_element_type=f32)
         qf = (jnp.concatenate([q_lat, q_rope.astype(f32)], axis=-1)
               * scale).astype(q_nope.dtype)
+        queries = (qf,)
 
-        def scores(rows):
+        def scores(queries, rows):
+            qf, = queries
             return jnp.einsum("bthw,bsw->bhts", qf, rows,
                               preferred_element_type=f32), rows[..., :latent]
 
@@ -708,8 +728,10 @@ def mla_paged_attention(q_nope, q_rope, w_kvb, latent_pages, block_tables,
     else:
         qn = (q_nope.astype(f32) * scale).astype(q_nope.dtype)
         qr = (q_rope.astype(f32) * scale).astype(q_rope.dtype)
+        queries = (qn, qr)
 
-        def scores(rows):
+        def scores(queries, rows):
+            qn, qr = queries
             kv = jnp.einsum("bsc,chk->bshk", rows[..., :latent], w,
                             preferred_element_type=f32).astype(rows.dtype)
             s = (jnp.einsum("bthn,bshn->bhts", qn, kv[..., :nope],
@@ -724,31 +746,51 @@ def mla_paged_attention(q_nope, q_rope, w_kvb, latent_pages, block_tables,
 
         out_width = v_dim
 
-    def body(i, carry):
-        acc, m, l = carry
-        pids = jax.lax.dynamic_slice_in_dim(block_tables, i * slots, slots,
-                                            axis=1)
-        rows = latent_pages[pids].reshape(B, group, width)
-        s, values = scores(rows)
-        k_pos = i * group + jnp.arange(group, dtype=jnp.int32)
-        s = jnp.where(_paged_mask(k_pos, q_positions, kv_lens)[:, None], s,
-                      _NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        corr = jnp.exp(m - m_new)
-        return (acc * corr[..., None] + weigh(p, values), m_new,
-                l * corr + p.sum(axis=-1))
+    def walk(queries, block_tables, q_positions, kv_lens, steps):
+        """(acc, l) of the online softmax over ``steps`` groups of slots."""
+        rows_here = block_tables.shape[0]
 
-    acc, _, l = jax.lax.fori_loop(
-        jnp.int32(0), steps, body,
-        (jnp.zeros((B, H, T, out_width), f32),
-         jnp.full((B, H, T), _NEG_INF, f32), jnp.zeros((B, H, T), f32)))
+        def body(i, carry):
+            acc, m, l = carry
+            pids = jax.lax.dynamic_slice_in_dim(block_tables, i * slots,
+                                                slots, axis=1)
+            rows = latent_pages[pids].reshape(rows_here, group, width)
+            s, values = scores(queries, rows)
+            k_pos = i * group + jnp.arange(group, dtype=jnp.int32)
+            s = jnp.where(_paged_mask(k_pos, q_positions, kv_lens)[:, None],
+                          s, _NEG_INF)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            return (acc * corr[..., None] + weigh(p, values), m_new,
+                    l * corr + p.sum(axis=-1))
+
+        acc, _, l = jax.lax.fori_loop(
+            jnp.int32(0), steps, body,
+            (jnp.zeros((rows_here, H, T, out_width), f32),
+             jnp.full((rows_here, H, T), _NEG_INF, f32),
+             jnp.zeros((rows_here, H, T), f32)))
+        return acc, l
+
+    if grouped:
+        parts = []
+        for lo in range(0, B, rows_per_walk):
+            at = slice(lo, lo + rows_per_walk)
+            parts.append(walk(tuple(q[at] for q in queries),
+                              block_tables[at], q_positions[at],
+                              kv_lens[at], reach(kv_lens[at])))
+        acc = jnp.concatenate([a for a, _ in parts])
+        l = jnp.concatenate([l for _, l in parts])
+    else:
+        acc, l = walk(queries, block_tables, q_positions, kv_lens, steps)
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     if form == "mla_absorbed":
         out = jnp.einsum("bhtc,chv->bthv", out.astype(q_nope.dtype),
                          w[..., nope:], preferred_element_type=f32)
     else:
         out = out.transpose(0, 2, 1, 3)
+    if grouped:
+        out = out[jnp.argsort(order)]
     return out.astype(q_nope.dtype)
 
 

@@ -38,3 +38,10 @@ from .pangu_ultra_moe import (  # noqa: F401
     pangu_decode_fns,
     pangu_ultra_moe_tiny,
 )
+from .longcat_flash import (  # noqa: F401
+    LongcatFlashConfig,
+    LongcatFlashForCausalLM,
+    LongcatFlashModel,
+    longcat_decode_fns,
+    longcat_flash_tiny,
+)

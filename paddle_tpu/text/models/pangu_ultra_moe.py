@@ -66,8 +66,8 @@ from paddle_tpu.nn.functional.norm import group_rms
 from paddle_tpu.ops.attention import dot_product_attention
 
 __all__ = ["PanguUltraMoEConfig", "PanguUltraMoEModel",
-           "PanguUltraMoEForCausalLM", "pangu_decode_fns",
-           "pangu_ultra_moe_tiny"]
+           "PanguUltraMoEForCausalLM", "pangu_decode_fns", "cached_mla",
+           "latent_where", "publish_moe_counts", "pangu_ultra_moe_tiny"]
 
 F32 = jnp.float32
 # rows of the served step's counts (``cache['moe_counts']``): a decode step
@@ -158,12 +158,15 @@ def _linear(n_in, n_out, std):
 class PanguMLAttention(nn.Layer):
     """Latent attention with a compressed query and decoupled rotary
     columns; keys and values made a head from the latent (the whole-row
-    form: nothing is cached)."""
+    form: nothing is cached). ``q_scale`` and ``kv_scale`` are
+    ``cached_mla``'s."""
 
-    def __init__(self, config: PanguUltraMoEConfig):
+    def __init__(self, config: PanguUltraMoEConfig, q_scale=None,
+                 kv_scale=None):
         super().__init__()
         c, h, std = config, config.hidden_size, config.initializer_range
         self.config = c
+        self.q_scale, self.kv_scale = q_scale, kv_scale
         heads = c.num_attention_heads
         self.q_a_proj = _linear(h, c.q_lora_rank, std)
         self.q_a_norm = nn.RMSNorm(c.q_lora_rank, c.rms_norm_eps)
@@ -196,8 +199,13 @@ class PanguMLAttention(nn.Layer):
             return o.reshape(b, l, -1)
 
         kva = self.kv_a_proj(u)
-        kvb = self.kv_b_proj(self.kv_a_norm(kva[:, :, :latent]))
+        c_kv = self.kv_a_norm(kva[:, :, :latent])
         q = self.q_b_proj(self.q_a_norm(self.q_a_proj(u)))
+        if self.kv_scale is not None:
+            c_kv = c_kv * self.kv_scale
+        if self.q_scale is not None:
+            q = q * self.q_scale
+        kvb = self.kv_b_proj(c_kv)
         return self.o_proj(apply_op(attend, q, kva, kvb, op_name="mla"))
 
 
@@ -328,18 +336,20 @@ class PanguUltraMoEForCausalLM(nn.Layer):
         return spec
 
 
-def publish_moe_counts(counters: dict, telemetry=None) -> dict:
+def publish_moe_counts(counters: dict, telemetry=None,
+                       names: tuple = COUNTS) -> dict:
     """The served steps' counts since the pool was made, as counters
-    ``moe/<layer_steps|experts_hit|pairs_here>.<decode|chunk>``: expert
-    layers run, held experts that received at least one pair, pairs routed
-    to held experts. The caller's fetch, made when it asks."""
+    ``moe/<name>.<decode|chunk>`` for each of ``names``, the columns of
+    ``moe_counts`` (here: expert layers run, held experts that received at
+    least one pair, pairs routed to held experts). The caller's fetch,
+    made when it asks."""
     from paddle_tpu.profiler.telemetry import get_telemetry
 
     tel = telemetry or get_telemetry()
     values = np.asarray(counters["moe_counts"])
     out = {}
     for kind, row in zip(KINDS, values):
-        for name, v in zip(COUNTS, row):
+        for name, v in zip(names, row):
             key = f"moe/{name}.{kind}"
             # counters only rise: publish what was added since the last look
             tel.counter(key, max(0, int(v) - tel.counter_value(key)))
@@ -348,6 +358,74 @@ def publish_moe_counts(counters: dict, telemetry=None) -> dict:
 
 
 # -- the cached form ------------------------------------------------------------
+
+def latent_where(cache, q_positions, block_tables, kv_lens):
+    """Where a chunk's rows go in the latent pages, and which positions are
+    there (``q_position < kv_len``; the others write to the scratch page):
+    ``((q_positions, block_tables, kv_lens, page, slot in page), valid)``,
+    the first what ``cached_mla`` takes as ``where``."""
+    bs = cache["latent"][0].shape[1]
+    valid = q_positions < kv_lens[:, None]
+    page_idx = jnp.take_along_axis(
+        block_tables, jnp.clip(q_positions // bs, 0,
+                               block_tables.shape[1] - 1), axis=1)
+    where = (q_positions, block_tables, kv_lens,
+             jnp.where(valid, page_idx, 0), q_positions % bs)
+    return where, valid
+
+
+def cached_mla(c, w, u, pages, where, q_scale=None, kv_scale=None,
+               rows_per_walk=None):
+    """One latent attention over a layer's latent pages, the cached form
+    every served MLA model calls: (output [B, T, h], the pages with the
+    chunk's rows written). ``c`` holds the widths (``num_attention_heads``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``kv_lora_rank``,
+    ``v_head_dim``, ``rope_theta``, ``rms_norm_eps``, ``latent_width``,
+    ``cache_width``); ``w(name)`` gives the layer's ``q_a_proj.weight``,
+    ``q_a_norm.weight``, ``q_b_proj.weight``, ``kv_a_proj.weight``,
+    ``kv_a_norm.weight``, ``kv_b_proj.weight``, ``o_proj.weight``.
+
+    ``q_scale`` multiplies the query after W_qb, ``kv_scale`` the normed
+    latent before W_kvb (LongCat-Flash's ``mla_scale_q_lora`` and
+    ``mla_scale_kv_lora``): the latent is stored scaled, so that both the
+    absorbed and the expanded walk meet what W_kvb would have been given.
+    None leaves the program without either operation. ``rows_per_walk``
+    is ``mla_paged_attention``'s: rows sorted by length and walked in
+    groups of that many; None walks them all to the longest."""
+    from paddle_tpu.ops.attention import mla_paged_attention
+
+    q_positions, block_tables, kv_lens, page_idx, slot_in_page = where
+    B, T, _ = u.shape
+    heads, nope, rope, latent = (c.num_attention_heads, c.qk_nope_head_dim,
+                                 c.qk_rope_head_dim, c.kv_lora_rank)
+    eps = c.rms_norm_eps
+    # the barrier keeps the split by heads on the activation: without
+    # it the compiler lays W_qb out anew (a head's 192 columns apart)
+    # in every step, 75 MB a layer at the served size
+    q = jax.lax.optimization_barrier(
+        group_rms(u @ w("q_a_proj.weight"), w("q_a_norm.weight"), eps, 1)
+        @ w("q_b_proj.weight")).reshape(B, T, heads, nope + rope)
+    if q_scale is not None:
+        q = q * jnp.asarray(q_scale, q.dtype)
+    kva = u @ w("kv_a_proj.weight")
+    gain = w("kv_a_norm.weight")
+    if kv_scale is not None:  # one rounding: into the f32 gain
+        gain = gain.astype(F32) * kv_scale
+    row = jnp.concatenate([
+        group_rms(kva[..., :latent], gain, eps, 1),
+        rotary_embedding(kva[:, :, None, latent:], q_positions,
+                         c.rope_theta)[:, :, 0],
+        jnp.zeros((B, T, c.cache_width - c.latent_width), kva.dtype)],
+        axis=-1)
+    pages = pages.at[page_idx, slot_in_page].set(row.astype(pages.dtype))
+    with jax.named_scope("mla"):
+        o = mla_paged_attention(
+            q[..., :nope], rotary_embedding(q[..., nope:], q_positions,
+                                            c.rope_theta),
+            w("kv_b_proj.weight"), pages, block_tables, q_positions,
+            kv_lens, v_dim=c.v_head_dim, rows_per_walk=rows_per_walk)
+    return o.reshape(B, T, heads * c.v_head_dim) @ w("o_proj.weight"), pages
+
 
 def pangu_decode_fns(config: PanguUltraMoEConfig, kv_dtype: str = "float32"):
     """Pure cached forwards for token-level serving, the twins of
@@ -365,46 +443,15 @@ def pangu_decode_fns(config: PanguUltraMoEConfig, kv_dtype: str = "float32"):
     ``cache['moe_counts']`` int32 ``[2, 3]`` (``KINDS`` by ``COUNTS``). A
     position is there where ``q_position < kv_len``; the others write to
     the scratch page and are routed to no expert."""
-    from paddle_tpu.ops.attention import mla_paged_attention
-
     c = config
     if kv_dtype == "int8":
         raise ValueError("pangu_decode_fns: latent pages have no int8 form")
-    store = jnp.dtype(kv_dtype)
     eps = c.rms_norm_eps
-    heads, nope, rope, latent = (c.num_attention_heads, c.qk_nope_head_dim,
-                                 c.qk_rope_head_dim, c.kv_lora_rank)
     first, held = c.experts_held.start, len(c.experts_held)
     no_bias = jnp.zeros((c.n_routed_experts,), F32)
 
     def rms(x, w):
         return group_rms(x, w, eps, 1)
-
-    def attend(p, u, pages, where):
-        q_positions, block_tables, kv_lens, page_idx, slot_in_page = where
-        B, T, _ = u.shape
-        # the barrier keeps the split by heads on the activation: without
-        # it the compiler lays W_qb out anew (a head's 192 columns apart)
-        # in every step, 75 MB a layer at the served size
-        q = jax.lax.optimization_barrier(
-            rms(u @ p("attn.q_a_proj.weight"), p("attn.q_a_norm.weight"))
-            @ p("attn.q_b_proj.weight")).reshape(B, T, heads, nope + rope)
-        kva = u @ p("attn.kv_a_proj.weight")
-        row = jnp.concatenate([
-            rms(kva[..., :latent], p("attn.kv_a_norm.weight")),
-            rotary_embedding(kva[:, :, None, latent:], q_positions,
-                    c.rope_theta)[:, :, 0],
-            jnp.zeros((B, T, c.cache_width - c.latent_width), kva.dtype)],
-            axis=-1)
-        pages = pages.at[page_idx, slot_in_page].set(row.astype(store))
-        with jax.named_scope("mla"):
-            o = mla_paged_attention(
-                q[..., :nope], rotary_embedding(q[..., nope:], q_positions,
-                                       c.rope_theta),
-                p("attn.kv_b_proj.weight"), pages, block_tables, q_positions,
-                kv_lens, v_dim=c.v_head_dim)
-        return o.reshape(B, T, heads * c.v_head_dim) \
-            @ p("attn.o_proj.weight"), pages
 
     def gated(g, p, at):
         return (jax.nn.silu(g @ p(at + "gate_proj.weight"))
@@ -434,8 +481,9 @@ def pangu_decode_fns(config: PanguUltraMoEConfig, kv_dtype: str = "float32"):
     def layer(params, at, kind, x, pages, where, valid):
         p = lambda name: params[at + name]  # noqa: E731
         with jax.named_scope("self_attn"):
-            a, pages = attend(p, rms(x, p("input_layernorm.weight")), pages,
-                              where)
+            a, pages = cached_mla(c, lambda n: p("attn." + n),
+                                  rms(x, p("input_layernorm.weight")), pages,
+                                  where)
             x = x + rms(a, p("post_attention_layernorm.weight"))
         with jax.named_scope("mlp"):
             g = rms(x, p("pre_mlp_layernorm.weight"))
@@ -446,20 +494,11 @@ def pangu_decode_fns(config: PanguUltraMoEConfig, kv_dtype: str = "float32"):
             x = x + rms(m, p("post_mlp_layernorm.weight"))
         return x, pages, load
 
-    def placed(cache, q_positions, block_tables, kv_lens):
-        bs = cache["latent"][0].shape[1]
-        valid = q_positions < kv_lens[:, None]
-        page_idx = jnp.take_along_axis(
-            block_tables, jnp.clip(q_positions // bs, 0,
-                                   block_tables.shape[1] - 1), axis=1)
-        where = (q_positions, block_tables, kv_lens,
-                 jnp.where(valid, page_idx, 0), q_positions % bs)
-        return where, valid
-
     def trunk(params, tokens, q_positions, cache, block_tables, kv_lens):
         cache = dict(cache)
         pages = list(cache["latent"])
-        where, valid = placed(cache, q_positions, block_tables, kv_lens)
+        where, valid = latent_where(cache, q_positions, block_tables,
+                                    kv_lens)
         with jax.named_scope("embed"):
             x = params["model.embed.weight"][tokens]
         loads = []
@@ -496,7 +535,8 @@ def pangu_decode_fns(config: PanguUltraMoEConfig, kv_dtype: str = "float32"):
                       block_tables, kv_lens, slots):
         cache = dict(cache)
         pages = list(cache["latent"])
-        where, valid = placed(cache, q_positions, block_tables, kv_lens)
+        where, valid = latent_where(cache, q_positions, block_tables,
+                                    kv_lens)
         e = params["model.embed.weight"][tokens]
         both = jnp.concatenate([rms(e, params["nextn.enorm.weight"]),
                                 rms(hidden, params["nextn.hnorm.weight"])],

@@ -256,14 +256,14 @@ def dense_latent(c):
     return out
 
 
-def run_form(c, form, group=None, monkeypatch=None):
+def run_form(c, form, group=None, monkeypatch=None, rows_per_walk=None):
     if group:
         monkeypatch.setattr(att, "_MLA_GROUP", dict.fromkeys(att._MLA_GROUP,
                                                              group))
     return np.asarray(att.mla_paged_attention(
         *map(jnp.asarray, (c["q_nope"], c["q_rope"], c["w_kvb"], c["pages"],
                            c["tables"], c["qpos"], c["lens"])),
-        v_dim=c["v"], form=form))
+        v_dim=c["v"], form=form, rows_per_walk=rows_per_walk))
 
 
 @pytest.mark.parametrize("group", [8, 16, 512])
@@ -275,6 +275,28 @@ def test_each_form_matches_dense_latent_attention(form, group, monkeypatch):
     c = latent_case()
     np.testing.assert_allclose(run_form(c, form, group, monkeypatch),
                                dense_latent(c), atol=2e-5)
+
+
+@pytest.mark.parametrize("rows_per_walk", [1, 2, 4, 6])
+@pytest.mark.parametrize("T", [1, 3])
+@pytest.mark.parametrize("form", ["mla_absorbed", "mla_expanded"])
+def test_rows_walked_in_groups_by_length_match_one_walk(
+        form, T, rows_per_walk, monkeypatch):
+    """Six rows of unequal length, one of them padding (length 0), sorted
+    by length and walked in groups of 1, 2 and 4 rows (the last group of
+    4 holds two), groups of 2 table slots: the longest group walks 5
+    steps, the shortest live row 1. Each live row reads what one walk of all six
+    gives it, and the dense attention; 6 rows a walk is one walk."""
+    c = latent_case(B=6, T=T, lens=(19, 7, 33, 0, 3, 28), seed=9)
+    whole = run_form(c, form, 8, monkeypatch)
+    grouped = run_form(c, form, 8, monkeypatch, rows_per_walk)
+    live = c["lens"] > 0
+    # the same operations on each row; a product over fewer rows may sum
+    # in another order on the CPU, which float32 shows in its last digits
+    np.testing.assert_allclose(grouped[live], whole[live], rtol=0,
+                               atol=1e-6)
+    np.testing.assert_allclose(grouped[live], dense_latent(c)[live],
+                               atol=2e-5)
 
 
 @pytest.mark.parametrize("T", [1, 2, 6])

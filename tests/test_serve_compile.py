@@ -243,6 +243,55 @@ def test_a_latent_step_copies_neither_its_pool_nor_its_weights(
         < blocks * BLOCK * 640 * 2
 
 
+# -- LongCat's decode step: the latent walks of rows grouped by length ----------
+
+@pytest.mark.parametrize("entry, rows, tokens, walks", [
+    ("serve.decode.b128", 128, 1, 8 * 4), ("serve.prefill.c512", 1, 512, 8)])
+def test_longcats_rows_walk_the_latent_pages_in_groups_by_length(
+        one_chip, entry, rows, tokens, walks):
+    """LongCat-Flash's served step at the size of
+    ``longcat-flash-chat.serve-closed-chat`` (6,144 wide, 64 heads, 16 of
+    512 experts, four double layers of two attentions, 16,384 blocks of 16
+    rows stored 640 wide, a table of 192 slots). A decode step of 128 rows
+    walks each attention's pages in four groups of 32 rows sorted by
+    length (``longcat_flash.WALK_ROWS``), each only as far as its own
+    longest row: 32 loops, where one walk of all the rows an attention
+    made 8 and a step cost as much as its one longest row asked. A prefill
+    chunk of one row walks once. Nothing writes a layer of the pool outside
+    its scatter."""
+    from paddle_tpu.text.models.longcat_flash import (
+        WALK_ROWS, LongcatFlashConfig, LongcatFlashForCausalLM)
+
+    assert WALK_ROWS == 32
+    blocks, table = 16384, 192
+    shaped = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    with paddle.LazyGuard():  # shapes only: the weights are never made
+        model = LongcatFlashForCausalLM(LongcatFlashConfig(
+            vocab_rows_held=16384, layers_held=4, experts_held=range(16)))
+    spec = model.decode_spec("bfloat16")
+    params = {name: shaped(p.shape, jnp.bfloat16)
+              for name, p in get_params(model).items()}
+    pool = _pool_config(spec, blocks, BLOCK, "bfloat16", 0)
+    pages = jax.tree_util.tree_map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(lambda: KVCachePool(pool).pages))
+    assert [a.shape for a in pages["latent"]] == [(blocks, BLOCK, 640)] * 8
+
+    ints = lambda *shape: shaped(shape, jnp.int32)  # noqa: E731
+    compiled = compile_for_the_chip(
+        greedy_step(spec["forward_chunk"], store=True), params,
+        ints(rows, tokens), ints(rows, tokens), pages, ints(rows, table),
+        ints(rows), ints(rows), ints(blocks), donate_argnums=(3, 7))
+    text = compiled.as_text()
+    found = [op for *_, op, _, _ in instructions(text) if op == "while"]
+    assert len(found) == walks, entry
+    found = pool_sized_writes(text, blocks, blocks * BLOCK * 640)
+    assert not found, f"{entry}:\n" + "\n".join(found)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < blocks * BLOCK * 640 * 2
+
+
 # -- the token store beside a hybrid model's gathered contexts -------------------
 
 def test_the_token_store_leaves_the_decode_step_its_fast_memory(
