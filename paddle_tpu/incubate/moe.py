@@ -3,8 +3,9 @@
 Two layers. ``DroplessMoE`` is the one models use (``text/models/
 kimi_linear.py``): it is told which of the routed experts it holds,
 scores all of them, drops nothing, computes its own experts' part of the
-result through one grouped matrix product a projection
-(``jax.lax.ragged_dot`` over the token-expert pairs sorted by expert) and
+result (``held_experts_part``: one grouped matrix product a projection,
+``jax.lax.ragged_dot`` over the token-expert pairs sorted by expert, or at
+a decode step's few rows one batched product over the held experts) and
 adds a shared expert. The GShard dense-dispatch functions below it
 (``top_k_gating``, ``moe_dispatch``, ``ExpertMLP``, ``MoELayer``:
 capacity dropping, softmax gate, GELU experts, [T, E, C] one-hot tensors)
@@ -270,6 +271,13 @@ def _sort_by_group(key, groups: int):
     return order, inverse, sizes
 
 
+# The v5e's ridge point: 197e12 bf16 operations a second over 819e9 bytes
+# a second of HBM is 240 operations a byte. A held expert's three products
+# over T rows do 2 T operations for every 2-byte weight they read, so at T
+# rows or fewer they are bound by the stacks' bytes, not by the MXU.
+_RIDGE_ROWS = 240
+
+
 def held_experts_part(x, chosen, weights, w_gate, w_up, w_down, first: int):
     """What the experts held here add to every token: sum over the chosen
     experts e in [first, first + E_held) of w_e * down_e(silu(gate_e x) *
@@ -278,12 +286,41 @@ def held_experts_part(x, chosen, weights, w_gate, w_up, w_down, first: int):
     f32, stats f32[3] = the share of all T * k pairs that is routed here,
     the fullest held expert's load over the mean load, pairs dropped).
 
-    All T * k token-expert pairs are sorted by expert, those of absent
-    experts last; the held ones go through one ``jax.lax.ragged_dot`` a
-    projection, which works on the rows its groups cover and leaves the
-    rest alone, and come back by the inverse permutation to be weighed
-    and summed a token. The pair buffer has a row for every pair, so
-    nothing is ever dropped, whatever the router does."""
+    The form of the products is chosen from T, when the shape is traced,
+    and published as ``gauge/moe/form.r<T>`` (1 batched, 0 grouped): at
+    ``_RIDGE_ROWS`` rows or fewer (a decode step) ``_batched_part``, every
+    held expert over every row, which reads each stack once; above
+    (a prefill chunk, a training step) ``_grouped_part``, whose grouped
+    products do the work of the pairs alone. Both round alike: the three
+    products' operands and outputs in x's type, the weighing and the sum
+    in f32. Neither drops a pair, whatever the router does."""
+    from ..profiler.telemetry import get_telemetry
+
+    batched = x.shape[0] <= _RIDGE_ROWS
+    get_telemetry().gauge(f"moe/form.r{x.shape[0]}", int(batched))
+    form = _batched_part if batched else _grouped_part
+    return form(x, chosen, weights, w_gate, w_up, w_down, first)
+
+
+def _part_stats(sizes, t: int, k: int, here):
+    """``held_experts_part``'s stats from ``sizes``, int32 [E_held]: the
+    pairs each held expert got."""
+    load = sizes.astype(jnp.float32)
+    pairs = jnp.sum(sizes)
+    stats = jnp.stack([
+        pairs.astype(jnp.float32) / (t * k),
+        jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0),
+        (jnp.sum(here) - pairs).astype(jnp.float32)])
+    return jax.lax.stop_gradient(stats)
+
+
+def _grouped_part(x, chosen, weights, w_gate, w_up, w_down, first: int):
+    """``held_experts_part`` through the pairs: all T * k token-expert
+    pairs are sorted by expert, those of absent experts last; the held ones
+    go through one ``jax.lax.ragged_dot`` a projection, which works on the
+    rows its groups cover and leaves the rest alone, and come back by the
+    inverse permutation to be weighed and summed a token. The pair buffer
+    has a row for every pair."""
     t, k = chosen.shape
     held = w_gate.shape[0]
     local = chosen.astype(jnp.int32) - first
@@ -301,13 +338,36 @@ def held_experts_part(x, chosen, weights, w_gate, w_up, w_down, first: int):
                      _from_pairs(ys, order, inverse).reshape(t, k, -1), 0)
     y = jnp.sum(back.astype(jnp.float32)
                 * jnp.where(here, weights, 0.0)[..., None], axis=1)
-    load = sizes.astype(jnp.float32)
-    pairs = jnp.sum(sizes)
-    stats = jnp.stack([
-        pairs.astype(jnp.float32) / (t * k),
-        jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0),
-        (jnp.sum(here) - pairs).astype(jnp.float32)])
-    return y, jax.lax.stop_gradient(stats)
+    return y, _part_stats(sizes, t, k, here)
+
+
+def _batched_part(x, chosen, weights, w_gate, w_up, w_down, first: int):
+    """``held_experts_part`` as one batched product a projection, the held
+    experts its batch axis: [E_held, T, h] x [E_held, h, f] for gate and
+    up, [E_held, T, f] x [E_held, f, h] for down, each stack read where it
+    lies. Every expert runs over every row, and a row's output is weighed
+    by the router's weight for that (row, expert), 0 where the row did not
+    choose it. Top-k picks distinct experts, so a row meets a held expert
+    once at most: no sort, no buffer of pairs, nothing to drop."""
+    t, k = chosen.shape
+    held = w_gate.shape[0]
+    local = chosen.astype(jnp.int32) - first
+    here = (local >= 0) & (local < held)
+    hit = local[..., None] == jnp.arange(held, dtype=jnp.int32)  # [T, k, E]
+    weight = jnp.sum(jnp.where(hit, weights[..., None].astype(jnp.float32),
+                               0.0), axis=1).T                   # [E, T]
+    chose = jnp.any(hit, axis=1).T                               # [E, T]
+    batched = lambda a, w: jax.lax.dot_general(  # noqa: E731
+        a, w.astype(a.dtype), (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=a.dtype)
+    xs = jnp.broadcast_to(x, (held,) + x.shape)
+    ys = batched(jax.nn.silu(batched(xs, w_gate)) * batched(xs, w_up),
+                 w_down)
+    # a row's product with an expert it did not choose is never read
+    y = jnp.sum(jnp.where(chose[..., None], ys.astype(jnp.float32)
+                          * weight[..., None], 0.0), axis=0)
+    return y, _part_stats(jnp.sum(chose, axis=1, dtype=jnp.int32), t, k,
+                          here)
 
 
 def identity_experts_part(x, chosen, weights, first: int):

@@ -243,6 +243,98 @@ def test_a_latent_step_copies_neither_its_pool_nor_its_weights(
         < blocks * BLOCK * 640 * 2
 
 
+# -- the held experts of a served step: batched at a decode step's rows ---------
+
+def outermost_calls(text):
+    """Name of a fused computation -> the line of the instruction that
+    calls it from outside every fusion: the one a trace's event is named
+    by, and whose ``op_name`` says its scope."""
+    instrs = list(instructions(text))
+    caller = {}
+    for comp, _, _, _, rest, _ in instrs:
+        for called in re.findall(r"calls=%([^,\s]+)", rest):
+            caller[called] = (comp, rest)
+    out = {}
+    for called in caller:
+        comp, rest = caller[called]
+        while comp in caller:
+            comp, rest = caller[comp]
+        out[called] = rest
+    return out
+
+
+@pytest.mark.parametrize("model, entry, rows, tokens", [
+    ("openpangu", "serve.decode.b64", 64, 1),
+    ("openpangu", "serve.prefill.c512", 1, 512),
+    ("longcat", "serve.decode.b128", 128, 1),
+    ("longcat", "serve.prefill.c512", 1, 512)])
+def test_a_decode_steps_held_experts_are_one_batched_product_a_projection(
+        one_chip, model, entry, rows, tokens):
+    """The expert layer of ``openpangu-ultra-moe-718b.serve-closed-reason``
+    (7,680 wide, 16 held stacks of 2,048) and of
+    ``longcat-flash-chat.serve-closed-chat`` (6,144 wide, 16 of 2,048),
+    one expert layer each. At a decode step's 64 or 128 rows the held
+    experts run as three batched products over the 16 stacks, each stack
+    read where it lies: no grouped product (``ragged-dot``, a 512-row tile
+    a group) and no copy of a stack. Their fusions' ``op_name`` lies under
+    ``moe/``, so the benchmark's ``moe_decode_ms.serve`` books them. A
+    prefill chunk of 512 keeps the grouped products."""
+    shaped = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    blocks = 16384
+    with paddle.LazyGuard():  # shapes only: the weights are never made
+        if model == "openpangu":
+            from paddle_tpu.text.models.pangu_ultra_moe import (
+                PanguUltraMoEConfig, PanguUltraMoEForCausalLM)
+
+            net = PanguUltraMoEForCausalLM(PanguUltraMoEConfig(
+                vocab_rows_held=19200, layers_held=2, dense_layers_held=1,
+                experts_held=range(16), nextn_held=0))
+            h, table = 7680, 320
+        else:
+            from paddle_tpu.text.models.longcat_flash import (
+                LongcatFlashConfig, LongcatFlashForCausalLM)
+
+            net = LongcatFlashForCausalLM(LongcatFlashConfig(
+                vocab_rows_held=16384, layers_held=1, experts_held=range(16)))
+            h, table = 6144, 192
+    spec = net.decode_spec("bfloat16")
+    params = {name: shaped(p.shape, jnp.bfloat16)
+              for name, p in get_params(net).items()}
+    stacks = {name: p.shape for name, p in params.items()
+              if re.search(r"w_(gate|up|down)$", name)}
+    assert sorted(set(stacks.values())) == [(16, 2048, h), (16, h, 2048)]
+    pool = _pool_config(spec, blocks, BLOCK, "bfloat16", 0)
+    pages = jax.tree_util.tree_map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(lambda: KVCachePool(pool).pages))
+
+    ints = lambda *shape: shaped(shape, jnp.int32)  # noqa: E731
+    text = compile_for_the_chip(
+        greedy_step(spec["forward_chunk"], store=True), params,
+        ints(rows, tokens), ints(rows, tokens), pages, ints(rows, table),
+        ints(rows), ints(rows), ints(blocks),
+        donate_argnums=(3, 7)).as_text()
+    if tokens > 1:
+        assert "ragged-dot" in text, entry
+        return
+    assert "ragged-dot" not in text, entry
+    copies = [f"%{name} = {typ}" for _, name, typ, op, _, _ in
+              instructions(text) if op == "copy"
+              and re.match(rf"bf16\[16,({h},2048|2048,{h})\]", typ)]
+    assert not copies, "\n".join(copies)
+    # the three products: [16, rows, h] x [16, h, 2048] twice, and
+    # [16, rows, 2048] x [16, 2048, h]
+    products = [(comp, rest) for comp, _, typ, op, rest, _ in
+                instructions(text) if op in ("convolution", "dot")
+                and re.match(rf"bf16\[16,{rows},(2048|{h})\]", typ)]
+    assert len(products) == 3, products
+    calls = outermost_calls(text)
+    for comp, rest in products:
+        named = calls.get(comp, rest)
+        assert re.search(r'op_name="[^"]*/moe/', named), named
+
+
 # -- LongCat's decode step: the latent walks of rows grouped by length ----------
 
 @pytest.mark.parametrize("entry, rows, tokens, walks", [
